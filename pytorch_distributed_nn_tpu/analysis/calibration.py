@@ -1,21 +1,22 @@
 """Roofline calibration: per-family ceilings, persisted profiles, fitting.
 
-The hand-built roofline in PERF.md measured this chip's real ceilings
-(dense matmul 118.7 TFLOP/s of the 197 nominal, HBM ~690 of ~819 GB/s,
-stage-1 convs structurally capped near 60 TFLOP/s); this module turns that
-knowledge into data the planner (``analysis/planner.py``) and the live MFU
-telemetry consume:
+The peaks and ceilings of each device the repo has a record for live in
+ONE table here, keyed by the ``device_kind`` jax reports; the planner
+(``analysis/planner.py``) and the live MFU telemetry consume it:
 
 - :class:`CalibrationProfile` — per-family compute ceilings + HBM/ICI
   bandwidths + the nominal peak (the MFU denominator), JSON round-trip
   (``calibration.json``).
-- ``default_profile(backend)`` — the checked-in defaults: the PERF.md
-  TPU-v5e numbers, and an explicitly-labelled CPU fallback so MFU is a
-  meaningful (relative) signal on hosts with no published peak. The CPU
-  profile sets ``shared_substrate=True``: virtual CPU devices share the
-  host's cores, so the planner charges a candidate mesh the *global*
-  FLOPs, not per-device — which is also what makes CPU plan validation
-  honest (more virtual devices never speed a single core up).
+- ``default_profile(backend, device_kind)`` — the checked-in table: the
+  TPU v5e entry under its ``device_kind``, and an explicitly-labelled CPU
+  planning entry so MFU is a meaningful (relative) signal on hosts with no
+  published peak. An accelerator whose ``device_kind`` is not in the table
+  is an error, never a default: a utilization against somebody else's
+  peak is worse than none. The CPU profile sets
+  ``shared_substrate=True``: virtual CPU devices share the host's cores,
+  so the planner charges a candidate mesh the *global* FLOPs, not
+  per-device — which is also what makes CPU plan validation honest (more
+  virtual devices never speed a single core up).
 - ``fit_from_trace`` — calibrate ceilings from an xplane trace: per-family
   achieved FLOP/s = static family FLOPs x steps / measured family device
   time (the shared ``op_family`` classifier guarantees the two sides
@@ -23,7 +24,7 @@ telemetry consume:
 - ``fit_microbench`` — bounded on-device microbenches (one dense matmul,
   one large copy) for hosts without a trace.
 
-Everything except ``fit_microbench`` is jax-free.
+Everything except ``fit_microbench`` and ``live_profile`` is jax-free.
 """
 
 from __future__ import annotations
@@ -36,25 +37,6 @@ from typing import Dict, Optional
 from pytorch_distributed_nn_tpu.utils.profiling import FAMILIES, op_family
 
 CALIBRATION_BASENAME = "calibration.json"
-
-#: nominal per-device peak FLOP/s by backend/device kind — the MFU
-#: denominator. The CPU entry is a documented PLANNING DEFAULT (no
-#: meaningful published peak for "whatever core the CI box has"): CPU MFU
-#: is a relative, trend-able signal, not an absolute one.
-PEAK_FLOPS_PER_DEVICE = {
-    "tpu": 197e12,   # v5e bf16 (PERF.md roofline)
-    "gpu": 100e12,   # generic planning default
-    "cpu": 5e10,     # planning default — see docstring
-}
-
-
-def peak_flops_per_device(backend: str, device_kind: str = "") -> float:
-    kind = (device_kind or "").lower()
-    if "v5" in kind or "v5e" in kind or "v5 lite" in kind:
-        return 197e12
-    return PEAK_FLOPS_PER_DEVICE.get(
-        (backend or "cpu").lower(), PEAK_FLOPS_PER_DEVICE["cpu"]
-    )
 
 
 @dataclasses.dataclass
@@ -101,15 +83,22 @@ class CalibrationProfile:
         return prof
 
 
-#: the checked-in default profiles. The v5e numbers are PERF.md's measured
-#: roofline: multiply_add at the measured dense-chain 118.7 TFLOP/s,
-#: convert_reduce at the blended forward-conv rate (~60 TFLOP/s — the
-#: stage-1 lane-underfill analysis), elementwise effectively
-#: bandwidth-bound (ceiling = nominal peak so the HBM term dominates),
-#: HBM 690 measured / 819 nominal GB/s. ICI is a one-link planning
-#: default — calibrate on real hardware before trusting pod plans.
+#: what jax reports as ``device_kind`` for one TPU v5e chip
+TPU_V5E = "TPU v5 lite"
+
+#: the checked-in profiles, keyed by ``device_kind`` ("cpu" for the host
+#: backend). TPU v5e nominal peaks: 197 TFLOP/s bf16, 819 GB/s HBM
+#: (source: Cloud TPU v5e documentation). Its achieved ceilings —
+#: multiply_add 118.7 TFLOP/s (dense chain), convert_reduce ~60 TFLOP/s
+#: (blended forward conv, stage-1 lane underfill), HBM 690 GB/s — were
+#: measured in July 2026 on an environment that no longer exists and have
+#: not been re-measured; elementwise is bandwidth-bound (ceiling = nominal
+#: peak so the HBM term dominates). ICI is a one-link planning default —
+#: calibrate on real hardware before trusting pod plans. The CPU entry is
+#: a PLANNING DEFAULT (no meaningful published peak for "whatever core
+#: the CI box has"): CPU MFU is a relative, trend-able signal only.
 DEFAULT_PROFILES = {
-    "tpu": CalibrationProfile(
+    TPU_V5E: CalibrationProfile(
         name="tpu_v5e",
         backend="tpu",
         peak_flops_per_s=197e12,
@@ -124,7 +113,7 @@ DEFAULT_PROFILES = {
         ici_bytes_per_s=9e10,
     ),
     "cpu": CalibrationProfile(
-        name="cpu_fallback",
+        name="cpu_planning",
         backend="cpu",
         peak_flops_per_s=5e10,
         compute_ceilings={f: 5e10 for f in FAMILIES},
@@ -135,24 +124,37 @@ DEFAULT_PROFILES = {
         ici_bytes_per_s=1e10,
         shared_substrate=True,
     ),
-    "gpu": CalibrationProfile(
-        name="gpu_generic",
-        backend="gpu",
-        peak_flops_per_s=100e12,
-        compute_ceilings={f: 60e12 for f in FAMILIES},
-        hbm_bytes_per_s=1.5e12,
-        hbm_peak_bytes_per_s=2e12,
-        ici_bytes_per_s=2e11,
-    ),
 }
 
 
-def default_profile(backend: str) -> CalibrationProfile:
-    prof = DEFAULT_PROFILES.get(
-        (backend or "cpu").lower(), DEFAULT_PROFILES["cpu"]
-    )
+def default_profile(backend: str, device_kind: str = "") -> CalibrationProfile:
+    """The checked-in profile for one device: the CPU planning entry for
+    the host backend, otherwise the entry for ``device_kind``. Raises for
+    an accelerator the table does not hold."""
+    key = "cpu" if (backend or "cpu").lower() == "cpu" else device_kind
+    if key not in DEFAULT_PROFILES:
+        raise ValueError(
+            f"no calibration profile for {backend} device_kind "
+            f"{device_kind!r}: add its published peaks (with their source) "
+            "to analysis/calibration.DEFAULT_PROFILES — an unknown "
+            "accelerator never borrows another device's peak"
+        )
     # defensive copy: callers mutate ceilings when fitting
-    return CalibrationProfile.from_dict(prof.to_dict())
+    return CalibrationProfile.from_dict(DEFAULT_PROFILES[key].to_dict())
+
+
+def live_profile() -> CalibrationProfile:
+    """``default_profile`` for the device this process runs on."""
+    import jax
+
+    return default_profile(
+        jax.default_backend(), jax.devices()[0].device_kind
+    )
+
+
+def peak_flops_per_device(backend: str, device_kind: str = "") -> float:
+    """Nominal per-device peak FLOP/s — the MFU denominator."""
+    return default_profile(backend, device_kind).peak_flops_per_s
 
 
 # ---------------------------------------------------------------------------
@@ -220,7 +222,7 @@ def fit_from_trace(
     trace_dir: str,
     cost: dict,
     steps: int,
-    base: Optional[CalibrationProfile] = None,
+    base: CalibrationProfile,
 ) -> CalibrationProfile:
     """Fit per-family ceilings from a captured xplane trace.
 
@@ -244,7 +246,7 @@ def fit_from_trace(
             f"no device planes with XLA op events under {trace_dir} — "
             "CPU-only captures cannot calibrate; use --microbench"
         )
-    prof = base or default_profile("tpu")
+    prof = base
     fams = family_summary(summary)
     cost_fams = cost.get("families") or {}
     for fam in FAMILIES:
@@ -288,7 +290,7 @@ def fit_microbench(
     import jax.numpy as jnp
 
     backend = jax.default_backend()
-    prof = base or default_profile(backend)
+    prof = base or live_profile()
 
     @jax.jit
     def chain(a, b):

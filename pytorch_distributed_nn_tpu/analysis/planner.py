@@ -39,7 +39,7 @@ from typing import Dict, List, Optional, Tuple
 
 from pytorch_distributed_nn_tpu.analysis.calibration import (
     CalibrationProfile,
-    default_profile,
+    live_profile,
     predict_step_ms,
 )
 
@@ -200,7 +200,7 @@ def plan(
     model_name = MODEL_ALIASES.get(model, model)
     text = is_text_model(model_name)
     if profile is None:
-        profile = default_profile(jax.default_backend())
+        profile = live_profile()
     model_kw = dict(model_kw or {})
     batch = batch_size or 2 * devices
     opt = build_optimizer(optimizer, 1e-3)

@@ -191,7 +191,9 @@ def _add_common_train_flags(p: argparse.ArgumentParser):
 
 def _trainer_from_args(args, sync_mode: str, num_workers):
     from pytorch_distributed_nn_tpu.training.trainer import TrainConfig, Trainer
+    from pytorch_distributed_nn_tpu.utils import compile_cache
 
+    compile_cache.configure()
     cfg = TrainConfig(
         network=args.network,
         dataset=args.dataset,
@@ -404,7 +406,9 @@ def main_evaluator(argv=None) -> int:
     )
     from pytorch_distributed_nn_tpu.training.evaluator import Evaluator
     from pytorch_distributed_nn_tpu.training.train_step import create_train_state
+    from pytorch_distributed_nn_tpu.utils import compile_cache
 
+    compile_cache.configure()
     mesh = make_mesh()
     n = num_workers(mesh)
     num_classes = 100 if args.dataset == "Cifar100" else 10
@@ -1227,12 +1231,9 @@ def _decode_cost_block(args, model_name):
 
     if not is_generative_model(model_name):
         return None
-    import jax
     import numpy as np
 
-    from pytorch_distributed_nn_tpu.analysis.calibration import (
-        default_profile,
-    )
+    from pytorch_distributed_nn_tpu.analysis.calibration import live_profile
     from pytorch_distributed_nn_tpu.analysis.costmodel import (
         decode_phase_cost,
     )
@@ -1254,7 +1255,7 @@ def _decode_cost_block(args, model_name):
         weight_bytes_per_param=4,
         kv_bytes_per_elem=np.dtype(cfg.dtype).itemsize,
     )
-    prof = default_profile(jax.default_backend())
+    prof = live_profile()
     pred = dc.predicted_tokens_per_s(
         prof.peak_flops_per_s, prof.hbm_peak_bytes_per_s
     )
@@ -1366,11 +1367,9 @@ def _run_plan(args) -> int:
 
 def _run_calibrate(args, num_data, num_model, num_seq) -> int:
     """``cli analyze --calibrate``: fit + persist a calibration.json."""
-    import jax
-
     from pytorch_distributed_nn_tpu.analysis import calibration
 
-    prof = calibration.default_profile(jax.default_backend())
+    prof = calibration.live_profile()
     if args.trace:
         from pytorch_distributed_nn_tpu import analysis
 
@@ -2195,6 +2194,11 @@ def main_serve(argv=None) -> int:
 
     if args.cmd == "frontend":
         return _main_serve_frontend(args)
+
+    # every other serve command takes the device in this process
+    from pytorch_distributed_nn_tpu.utils import compile_cache
+
+    compile_cache.configure()
 
     if args.cmd == "smoke":
         from pytorch_distributed_nn_tpu.serving.loadgen import smoke

@@ -303,9 +303,9 @@ class DataLoader(_IndexedLoader):
 class DeviceDataLoader(_IndexedLoader):
     """Device-resident batch source: the whole dataset lives in HBM.
 
-    The host loader ships ~13 MB of f32 pixels per b1024 CIFAR step; on a
-    remote-attached TPU (and, less dramatically, on any host-bound input
-    pipeline) that transfer dominates the 30 ms step. The reference's own
+    The host loader ships ~13 MB of f32 pixels per b1024 CIFAR step, and
+    a host-bound input pipeline puts that transfer on the step's critical
+    path. The reference's own
     design keeps the full dataset on every node ("we don't pass data among
     nodes to maintain data locality", reference README.md:24) — the
     TPU-native version of that is the dataset resident in HBM: uint8

@@ -2,9 +2,8 @@
 
 Sibling trials of one sweep — and a migrated trial's re-dispatch — keep
 re-deriving the same expensive host-side facts: the planner's ranked mesh
-for (model, device count), a host family's calibration profile, XLA's
-compiled executables. This cache gives them one shared, crash-safe home
-under ``<sweep_dir>/cache/``:
+for (model, device count), a host family's calibration profile. This cache
+gives them one shared, crash-safe home under ``<sweep_dir>/cache/``:
 
 - **content-addressed entries**: a key is the SHA-256 of the entry's
   canonical identity — ``kind`` plus the (model, mesh/devices, jax
@@ -18,11 +17,10 @@ under ``<sweep_dir>/cache/``:
 - **verified reads**: each entry stores its identity alongside its
   value; a hash collision or a hand-edited file is detected and treated
   as a miss, never trusted.
-- ``xla_cache_dir()`` — a shared ``JAX_COMPILATION_CACHE_DIR`` the
-  scheduler hands to every trial via the agent's env relay, so trials
-  that lower the same (model, mesh, jax version) skip recompilation
-  entirely (jax's persistent cache keys compilations itself; this just
-  gives the fleet one directory to agree on).
+
+XLA's compiled executables are NOT kept here: a directory under the sweep
+dir moves with the sweep, and the directory is part of the compile cache's
+key — utils/compile_cache.py places that cache.
 
 The cache is jax-free: the jax *version* comes from package metadata
 (``importlib.metadata``), never from importing jax — the orchestrator's
@@ -79,12 +77,6 @@ class FleetCache:
         return os.path.join(
             self.root, f"{kind}-{cache_key(kind, **ident)}.json"
         )
-
-    def xla_cache_dir(self) -> str:
-        """The fleet-shared XLA persistent-compilation-cache directory."""
-        path = os.path.join(self.root, "xla")
-        os.makedirs(path, exist_ok=True)
-        return path
 
     def get(self, kind: str, **ident) -> Optional[dict]:
         path = self._path(kind, ident)

@@ -64,6 +64,7 @@ from pytorch_distributed_nn_tpu.experiments.runner import (
     _Running,
 )
 from pytorch_distributed_nn_tpu.observability import tracing
+from pytorch_distributed_nn_tpu.utils import compile_cache
 
 logger = logging.getLogger(__name__)
 
@@ -453,13 +454,13 @@ class FleetScheduler(SweepRunner):
             cfg, host, cache=self.cache, plan=plan,
         ))
         env = {}
-        if c.trial_main_name == "default" and self.cache is not None:
-            # fleet-shared XLA persistent compilation cache: siblings and
-            # re-dispatched trials skip recompiling identical programs
-            env["JAX_COMPILATION_CACHE_DIR"] = self.cache.xla_cache_dir()
-            env.setdefault(
-                "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0"
-            )
+        outer_cache = os.environ.get(compile_cache.ENV_VAR)
+        if outer_cache:
+            # relay the operator's compile-cache directory to the trial
+            # (agents may have been started without it); never name
+            # another — with it unset the trial places its own cache
+            # (utils/compile_cache.py)
+            env[compile_cache.ENV_VAR] = outer_cache
         # trace relay over the wire: the agent applies this env before the
         # trial spawn, so the trial's manifest derives its child span from
         # the attempt's — orchestrator -> agent -> trial lineage, with the

@@ -89,7 +89,9 @@ def default_trial_main(trial_dir: str, cfg: dict) -> None:
         TrainConfig,
         Trainer,
     )
+    from pytorch_distributed_nn_tpu.utils import compile_cache
 
+    compile_cache.configure()
     cfg = dict(cfg)
     cfg["kill_ranks"] = tuple(cfg.get("kill_ranks") or ())
     trainer = Trainer(TrainConfig(**cfg))

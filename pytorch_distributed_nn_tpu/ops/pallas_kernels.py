@@ -51,11 +51,7 @@ _NEG_INF = -1e30
 # Streamed flash grids: (batch*head, output block, streamed block). The
 # first two dims are independent programs; the innermost dim carries the
 # running state in scratch and must execute sequentially ("arbitrary").
-# jax <= 0.4.x spells the params class TPUCompilerParams.
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-_STREAM_PARAMS = _CompilerParams(
+_STREAM_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"),
 )
 
@@ -954,7 +950,7 @@ def dequantize_int8(q: jnp.ndarray, scale) -> jnp.ndarray:
 # each direction in ONE VMEM pass: stats accumulate in f32 regardless of
 # input dtype, the normalized output is written directly in the requested
 # out_dtype (no separate f32 materialization), and the backward emits dx
-# plus per-tile dgamma/dbeta partials in the same sweep. Reference
+# plus the dgamma/dbeta sums in the same sweep. Reference
 # counterpart: none — LN itself is torch's ATen (SURVEY.md §2.3); the
 # *fusion* is the TPU-side perf mechanism.
 
@@ -991,8 +987,18 @@ def _ln_bwd_kernel(x_ref, g_ref, mu_ref, rs_ref, dy_ref,
     m1 = jnp.mean(dxhat, axis=1, keepdims=True)
     m2 = jnp.mean(dxhat * xhat, axis=1, keepdims=True)
     dx_ref[...] = (rs_ref[...] * (dxhat - m1 - xhat * m2)).astype(dx_ref.dtype)
-    dg_ref[...] = jnp.sum(dy * xhat, axis=0, keepdims=True)
-    db_ref[...] = jnp.sum(dy, axis=0, keepdims=True)
+
+    # dgamma/dbeta are ONE (1, D) block revisited by every grid step
+    # (Mosaic rejects a (1, D) block of a (G, D) partials array: a
+    # sublane block of 1 is neither a multiple of 8 nor the extent), so
+    # they accumulate across the sequential row-block sweep.
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        dg_ref[...] = jnp.zeros_like(dg_ref)
+        db_ref[...] = jnp.zeros_like(db_ref)
+
+    dg_ref[...] += jnp.sum(dy * xhat, axis=0, keepdims=True)
+    db_ref[...] += jnp.sum(dy, axis=0, keepdims=True)
 
 
 def _ln_geometry(N, D):
@@ -1063,15 +1069,14 @@ def _ln_bwd_call(x2, gamma, mu, rs, dy2, x_dtype):
         # regardless of the padded mu/rs values
         rs = jnp.pad(rs, ((0, pad), (0, 0)))
     Np = N + pad
-    G = Np // BN
     dx, dg, db = pl.pallas_call(
         _ln_bwd_kernel,
         out_shape=(
             jax.ShapeDtypeStruct((Np, D), x_dtype),
-            jax.ShapeDtypeStruct((G, D), jnp.float32),
-            jax.ShapeDtypeStruct((G, D), jnp.float32),
+            jax.ShapeDtypeStruct((1, D), jnp.float32),
+            jax.ShapeDtypeStruct((1, D), jnp.float32),
         ),
-        grid=(G,),
+        grid=(Np // BN,),
         in_specs=[
             pl.BlockSpec((BN, D), lambda i: (i, 0)),
             pl.BlockSpec((1, D), lambda i: (0, 0)),
@@ -1081,12 +1086,16 @@ def _ln_bwd_call(x2, gamma, mu, rs, dy2, x_dtype):
         ],
         out_specs=(
             pl.BlockSpec((BN, D), lambda i: (i, 0)),
-            pl.BlockSpec((1, D), lambda i: (i, 0)),
-            pl.BlockSpec((1, D), lambda i: (i, 0)),
+            pl.BlockSpec((1, D), lambda i: (0, 0)),
+            pl.BlockSpec((1, D), lambda i: (0, 0)),
+        ),
+        # the dg/db accumulators carry state from one row block to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
         ),
         interpret=_interpret(),
     )(x2, gamma.reshape(1, -1), mu, rs, dy2)
-    return dx[:N], dg.sum(axis=0), db.sum(axis=0)
+    return dx[:N], dg[0], db[0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

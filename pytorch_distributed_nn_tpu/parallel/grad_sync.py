@@ -37,8 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from pytorch_distributed_nn_tpu import compat
-
 from pytorch_distributed_nn_tpu.ops import compression as C
 from pytorch_distributed_nn_tpu.parallel.mesh import DATA_AXIS
 
@@ -163,7 +161,7 @@ class GradSync:
         straggler kill list (killed workers never arrive).
         """
         cfg = self.config
-        n = compat.axis_size(cfg.axis_name)
+        n = lax.axis_size(cfg.axis_name)
         alive = self._alive_mask()
         if cfg.num_aggregate is None or cfg.num_aggregate >= n:
             return alive

@@ -34,7 +34,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from pytorch_distributed_nn_tpu import compat
 from pytorch_distributed_nn_tpu.parallel.mesh import SEQ_AXIS
 
 _NEG_INF = -1e30
@@ -62,7 +61,7 @@ def _block_update(q, k, v, kv_mask, q_pos, k_pos, causal, o, m, l):
 
 def _ring_forward(q, k, v, mask, causal, axis_name):
     """Ring forward pass; returns (out, lse) with lse = m + log l (B,H,Lc)."""
-    S = compat.axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     B, Lc, H, D = q.shape
     q_pos = rank * Lc + jnp.arange(Lc)
@@ -136,7 +135,7 @@ def _ring_backward(q, k, v, mask, out, lse, g, causal, axis_name):
     mode autodiff through the forward fori_loop, which saved every hop's
     (B,H,Lc,Lc) probability block — O(S·Lc²) — as scan residuals.
     """
-    S = compat.axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     B, Lc, H, D = q.shape
     q_pos = rank * Lc + jnp.arange(Lc)
@@ -185,21 +184,6 @@ _RING_CACHE = {}
 
 
 def _make_ring(causal: bool, axis_name: str):
-    if not compat.SUPPORTS_COLLECTIVES_IN_CUSTOM_VJP:
-        # jax 0.4.x: a collective/axis_index inside a custom_vjp body is
-        # only rewritten for shard_map on the DIFFERENTIATED path (where
-        # partial-eval inlines fwd/bwd); the inference path keeps the
-        # closed jaxpr and lowers axis_index to a bare partition-id that
-        # the SPMD partitioner rejects. Fall back to plain autodiff
-        # through the forward loop — same math, O(S·Lc²) residuals
-        # instead of O(Lc·D) (fine at CPU-test scale; TPU runs use the
-        # new API and keep the memory-lean custom VJP).
-        def ring_plain(q, k, v, mask):
-            out, _ = _ring_forward(q, k, v, mask, causal, axis_name)
-            return out
-
-        return ring_plain
-
     @jax.custom_vjp
     def ring(q, k, v, mask):
         out, _ = _ring_forward(q, k, v, mask, causal, axis_name)
@@ -260,7 +244,7 @@ def ulysses_attention(
     across sequence shards is NOT assumed — it is all-gathered (it is (B, Lc),
     tiny next to activations).
     """
-    S = compat.axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     B, Lc, H, D = q.shape
     if H % S:
         raise ValueError(f"num_heads={H} not divisible by seq axis size {S}")
@@ -314,18 +298,19 @@ def _make_sharded_attn(mesh: Mesh, inner, seq_axis):
         if mask is None:
             mask = jnp.ones(q.shape[:2], jnp.float32)
 
-        if DATA_AXIS in compat.manual_axis_names():
+        ambient = jax.sharding.get_abstract_mesh()
+        if DATA_AXIS in ambient.manual_axes:
             qkv_spec = P(None, seq_axis, MODEL_AXIS, None)
             mask_spec = P(None, seq_axis)
             manual = {a for a in (seq_axis, MODEL_AXIS) if a is not None}
-            sm_kw = {"mesh": compat.ambient_mesh(mesh), "axis_names": manual}
+            sm_kw = {"mesh": ambient, "axis_names": manual}
         else:
             qkv_spec = P(DATA_AXIS, seq_axis, MODEL_AXIS, None)
             mask_spec = P(DATA_AXIS, seq_axis)
             sm_kw = {"mesh": mesh}
 
         @partial(
-            compat.shard_map,
+            jax.shard_map,
             in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
             out_specs=qkv_spec,
             check_vma=False,

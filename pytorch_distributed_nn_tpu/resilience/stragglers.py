@@ -36,8 +36,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from pytorch_distributed_nn_tpu import compat
-
 # Dropped-rank bitmask is reported while every rank index fits exact f32
 # integer arithmetic through the metrics pmean (2^24); past that only the
 # count/skew scalars are reported.
@@ -109,7 +107,7 @@ class StragglerSim:
         - ``straggler_arrival_max``: that rank's arrival time (seconds),
           so the margin to the deadline is reconstructable per step.
         """
-        n = compat.axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
         rank = jax.lax.axis_index(axis_name)
         t = self.times(key, step, n)
         # Deadline keep-set, floored by the fastest min_keep arrivals.

@@ -478,9 +478,9 @@ def collect_host_shards(state) -> Tuple[dict, dict]:
     """Snapshot this process's addressable replica-0 shards to host arrays.
 
     Returns ``(shards, shapes)``: the ``{leaf_key|index_key: np.ndarray}``
-    payload of this process's ``shards_p<N>.npz`` (the device→host fetch —
-    the expensive half on a remote-attached chip, which is why the async
-    pipeline runs it on the writer thread), and the global leaf-shape map
+    payload of this process's ``shards_p<N>.npz`` (the device→host fetch,
+    which the async pipeline runs on the writer thread), and the global
+    leaf-shape map
     for meta.json. Pure per-process work: NO collectives, so it is safe to
     call off the main thread (training/async_ckpt.py relies on this).
     """

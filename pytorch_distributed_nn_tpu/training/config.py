@@ -82,9 +82,8 @@ class TrainConfig:
     # periodic checkpoints snapshot on-device (async dispatch) and
     # serialize/compress/publish on a background writer thread, so the
     # step loop pays milliseconds instead of the full device->host fetch
-    # + write (seconds for ResNet-18, tens of seconds for a BERT-base
-    # Adam state on a remote-attached chip). Bytes are identical to the
-    # sync path; emergency saves are ALWAYS synchronous. Default on.
+    # + write of the state. Bytes are identical to the sync path;
+    # emergency saves are ALWAYS synchronous. Default on.
     async_ckpt: bool = True
     # Retention: after every successful publish, delete verified
     # checkpoints older than the newest N (never the resume target,

@@ -22,9 +22,9 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import optax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from pytorch_distributed_nn_tpu.compat import shard_map
 from pytorch_distributed_nn_tpu.ops.metrics import (
     masked_cross_entropy,
     mlm_metrics,

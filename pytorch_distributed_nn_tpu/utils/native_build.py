@@ -22,19 +22,34 @@ _lock = threading.Lock()
 _failed: Dict[str, bool] = {}
 
 
+def _fresh(so_path: str) -> bool:
+    """True when the library exists and is not older than its source.
+
+    ``native/*.so`` are git-ignored build outputs that can outlive the
+    source they were built from (a copied checkout carries them along),
+    so an existing file is not trusted on sight. native/Makefile builds
+    ``libpdtn_<name>.so`` from ``<name>.cpp``.
+    """
+    name = os.path.basename(so_path)[len("libpdtn_"):-len(".so")]
+    src = os.path.join(os.path.dirname(so_path), name + ".cpp")
+    try:
+        return os.path.getmtime(so_path) >= os.path.getmtime(src)
+    except OSError:
+        return False
+
+
 def ensure_built(so_path: str, timeout: float = 120.0) -> bool:
-    """Make sure ``so_path`` exists, building its make target if needed.
+    """Make sure ``so_path`` is built from the current sources, running
+    its make target if it is missing or stale.
 
     Returns False (and remembers the failure) when the build cannot be
-    done here; True when the library file exists.
+    done here; True when an up-to-date library file exists.
     """
-    if os.path.exists(so_path):
+    if _fresh(so_path):
         return True
     with _lock:
         if _failed.get(so_path):
             return False
-        if os.path.exists(so_path):
-            return True
         native_dir = os.path.dirname(so_path)
         target = os.path.basename(so_path)
         lock_path = so_path + ".lock"
@@ -44,7 +59,7 @@ def ensure_built(so_path: str, timeout: float = 120.0) -> bool:
             with open(lock_path, "w") as lf:
                 fcntl.flock(lf, fcntl.LOCK_EX)
                 try:
-                    if not os.path.exists(so_path):
+                    if not _fresh(so_path):
                         subprocess.run(
                             ["make", "-s", target], cwd=native_dir,
                             check=True, capture_output=True, timeout=timeout,
@@ -54,7 +69,7 @@ def ensure_built(so_path: str, timeout: float = 120.0) -> bool:
         except Exception:
             _failed[so_path] = True
             return False
-        ok = os.path.exists(so_path)
+        ok = _fresh(so_path)
         if not ok:
             _failed[so_path] = True
         return ok

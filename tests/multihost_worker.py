@@ -50,10 +50,9 @@ def main() -> int:
     train_dir = sys.argv[4]
     mode = sys.argv[5] if len(sys.argv) > 5 else "dp"
 
-    # Fresh subprocess: the env route works on every jax version; the
-    # config option only exists from jax 0.5. The parent test harness
-    # exports an 8-device flag, so REPLACE any inherited count — each of
-    # the 2 processes must own exactly 2 virtual devices.
+    # The parent test harness exports an 8-device flag, so REPLACE any
+    # inherited count — each of the 2 processes must own exactly 2
+    # virtual devices.
     os.environ["JAX_PLATFORMS"] = "cpu"
     flags = [
         f for f in os.environ.get("XLA_FLAGS", "").split()
@@ -64,9 +63,6 @@ def main() -> int:
 
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
-    if hasattr(jax.config, "jax_num_cpu_devices"):
-        jax.config.update("jax_num_cpu_devices", 2)
     jax.distributed.initialize(
         coordinator_address=f"localhost:{port}",
         num_processes=nprocs,

@@ -29,7 +29,7 @@ from pytorch_distributed_nn_tpu.analysis.testing import (
     assert_rules_absent,
     assert_rules_fired,
 )
-from pytorch_distributed_nn_tpu.compat import shard_map
+from jax import shard_map
 from pytorch_distributed_nn_tpu.models.transformer import bert_tiny
 from pytorch_distributed_nn_tpu.optim import build_optimizer
 from pytorch_distributed_nn_tpu.parallel import (
@@ -83,11 +83,9 @@ class TestPlantedStepDefects:
     def test_sl003_fires_on_planted_f64(self, devices):
         """A strong float64 constant in the step promotes the datapath to
         f64 — the auditor must see f64 results in the optimized HLO."""
-        from jax.experimental import enable_x64
-
         mesh = make_mesh(8, 1, 1)
 
-        with enable_x64():
+        with jax.enable_x64(True):
             @jax.jit
             def step(x):
                 poison = jnp.asarray(np.float64(1.5))  # strong f64

@@ -19,8 +19,10 @@ import pytest
 from pytorch_distributed_nn_tpu.analysis import costmodel
 from pytorch_distributed_nn_tpu.analysis.calibration import (
     CalibrationProfile,
+    TPU_V5E,
     default_profile,
     fit_from_trace,
+    peak_flops_per_device,
     predict_step_ms,
 )
 from pytorch_distributed_nn_tpu.analysis import planner
@@ -158,7 +160,7 @@ class TestCostWalk:
 
 class TestCalibration:
     def test_default_profiles_and_roundtrip(self, tmp_path):
-        prof = default_profile("tpu")
+        prof = default_profile("tpu", TPU_V5E)
         assert prof.peak_flops_per_s == pytest.approx(197e12)
         assert prof.compute_ceilings["multiply_add_fusion"] == (
             pytest.approx(118.7e12)
@@ -172,6 +174,20 @@ class TestCalibration:
         assert loaded.compute_ceilings == prof.compute_ceilings
         assert loaded.hbm_bytes_per_s == prof.hbm_bytes_per_s
         assert loaded.source == "file"
+
+    def test_peak_table_is_keyed_by_device_kind(self):
+        """The v5e reports "TPU v5 lite"; an accelerator the table does
+        not hold is an error where MFU is computed, never v5e's peak."""
+        assert peak_flops_per_device("tpu", "TPU v5 lite") == 197e12
+        assert peak_flops_per_device("cpu", "whatever") == (
+            default_profile("cpu").peak_flops_per_s
+        )
+        for backend, kind in (("tpu", "TPU v9 imaginary"), ("tpu", ""),
+                              ("gpu", "NVIDIA H100")):
+            with pytest.raises(ValueError, match="no calibration profile"):
+                peak_flops_per_device(backend, kind)
+            with pytest.raises(ValueError, match="no calibration profile"):
+                default_profile(backend, kind)
 
     def _xspace(self, op_ms):
         meta = {i: NS(name=name) for i, (name, _) in enumerate(op_ms)}
@@ -210,7 +226,7 @@ class TestCalibration:
             },
         }
         prof = fit_from_trace("unused", cost, steps=4,
-                              base=default_profile("tpu"))
+                              base=default_profile("tpu", TPU_V5E))
         assert prof.source == "trace"
         assert prof.compute_ceilings["convert_reduce_fusion"] == (
             pytest.approx(1e9 * 4 / 0.010)
@@ -224,7 +240,7 @@ class TestCalibration:
         assert prof.ici_bytes_per_s == pytest.approx(1e6 * 4 / 0.002)
         # zero-flop family keeps the base ceiling, never div-by-zero
         assert prof.compute_ceilings["other"] == (
-            default_profile("tpu").compute_ceilings["other"]
+            default_profile("tpu", TPU_V5E).compute_ceilings["other"]
         )
         path = str(tmp_path / "calibration.json")
         prof.save(path)
@@ -245,14 +261,14 @@ class TestPlannerScoring:
         }
 
     def test_more_ici_bytes_never_wins(self):
-        prof = default_profile("tpu")
+        prof = default_profile("tpu", TPU_V5E)
         lo = predict_step_ms(self._cost(ici=1e6), prof)
         hi = predict_step_ms(self._cost(ici=2e6), prof)
         assert hi["predicted_ms"] > lo["predicted_ms"]
 
     def test_slower_link_never_wins(self):
-        fast = default_profile("tpu")
-        slow = default_profile("tpu")
+        fast = default_profile("tpu", TPU_V5E)
+        slow = default_profile("tpu", TPU_V5E)
         slow.ici_bytes_per_s = fast.ici_bytes_per_s / 4
         cost = self._cost(ici=1e6)
         assert (
@@ -263,8 +279,8 @@ class TestPlannerScoring:
     def test_ranking_monotone_in_ici(self):
         """A candidate with identical compute but more ICI bytes on a
         slower link ranks strictly worse — the acceptance invariant."""
-        fast = default_profile("tpu")
-        slow = default_profile("tpu")
+        fast = default_profile("tpu", TPU_V5E)
+        slow = default_profile("tpu", TPU_V5E)
         slow.ici_bytes_per_s = fast.ici_bytes_per_s / 10
         light, heavy = self._cost(ici=1e6), self._cost(ici=8e6)
         scores = sorted(

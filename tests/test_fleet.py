@@ -597,3 +597,42 @@ def test_fleet_real_trial_migrates_and_elastically_resumes(tmp_path):
     ev = [e for e in rs.events if e.get("type") == "elastic_resume"]
     assert ev and ev[0]["old"]["devices"] == 4
     assert ev[0]["new"]["devices"] == 2
+
+
+def test_trial_env_defers_to_outer_compile_cache_dir(tmp_path, monkeypatch):
+    """jax reads JAX_COMPILATION_CACHE_DIR itself: where the operator set
+    it the scheduler relays exactly that directory to every trial, and
+    where it is unset the scheduler names none (a directory under the
+    sweep dir moves with the sweep, so it never hit across sweeps)."""
+
+    class Recording(LocalTransport):
+        envs = []
+
+        def call(self, agent_id, op, **kw):
+            if op == "assign":
+                self.envs.append(dict(kw["env"]))
+            return super().call(agent_id, op, **kw)
+
+    def run(tag):
+        Recording.envs = []
+        sdir = str(tmp_path / tag)
+        fs = FleetScheduler(
+            SweepSpec.parse("lr=0.1,0.2"), dict(SYNTH_BASE),
+            FleetConfig(sweep_dir=sdir, max_steps=2, lease=1.5,
+                        call_timeout=0.5, trial_main_name="synthetic"),
+            transport=Recording(
+                fleet_dir=os.path.join(sdir, "fleet"), agents=1,
+                devices=[1], capacity=2, lease=1.5, call_timeout=0.5,
+            ),
+        )
+        assert fs.run()["failed"] == []
+        assert len(Recording.envs) == 2
+        return Recording.envs
+
+    var = "JAX_COMPILATION_CACHE_DIR"
+    monkeypatch.setenv(var, "/some/dir")
+    assert [e[var] for e in run("set")] == ["/some/dir"] * 2
+    monkeypatch.delenv(var)
+    for env in run("unset"):
+        assert var not in env
+        assert not any(str(tmp_path) in str(v) for v in env.values())

@@ -13,7 +13,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from pytorch_distributed_nn_tpu.ops import compression as C
-from pytorch_distributed_nn_tpu.compat import shard_map
+from jax import shard_map
 from pytorch_distributed_nn_tpu.parallel import make_grad_sync, make_mesh
 
 
@@ -203,7 +203,7 @@ def test_ps_topk_convergence_matches_allreduce():
     def run(**kw):
         # lr 0.005 / 160 steps, not 0.01 / 40: EF re-delivers dropped
         # mass in bursts (num_aggregate=1 of 2 ≈ 2x effective step), and
-        # on the 0.4.x stack lr 0.01 sits past the oscillation edge —
+        # lr 0.01 sat past the oscillation edge on an earlier jaxlib —
         # the property pinned below is EF convergence, not the knee
         # position, so test inside the stable region on every stack.
         cfg = TrainConfig(

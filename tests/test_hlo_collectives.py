@@ -15,9 +15,7 @@ rule is deliberately broken, planted f64, etc.) lives in
 tests/test_analysis.py.
 """
 
-import pytest
-
-from pytorch_distributed_nn_tpu import analysis, compat
+from pytorch_distributed_nn_tpu import analysis
 from pytorch_distributed_nn_tpu.analysis.testing import (
     assert_collectives,
     assert_rules_absent,
@@ -104,11 +102,6 @@ def test_tp_flash_step_collectives():
     assert_rules_absent(report, ("SL001", "SL003", "SL005"))
 
 
-@pytest.mark.skipif(
-    not compat.SUPPORTS_NESTED_PARTIAL_MANUAL,
-    reason="int8 GSPMD sync nests a partial-manual shard_map inside the "
-           "manual(data) region — needs the post-0.4 shard_map API",
-)
 def test_gspmd_int8_rides_integer_collective():
     """compression='int8' on the dp×tp×sp path: the data-parallel gradient
     sync must move the QUANTIZED payload — an all-reduce over an integer

@@ -21,9 +21,6 @@ import subprocess
 import sys
 import time
 
-import pytest
-
-from pytorch_distributed_nn_tpu import compat
 from pytorch_distributed_nn_tpu.training import checkpoint as ckpt
 
 
@@ -102,10 +99,6 @@ def _run_workers(train_dir: str, mode: str, expect_start: int = 4,
     return outs
 
 
-@pytest.mark.skipif(
-    not compat.SUPPORTS_MULTIPROCESS_CPU,
-    reason="jax 0.4.x CPU backend has no cross-process collectives",
-)
 def test_two_process_train_checkpoint_resume(tmp_path):
     train_dir = str(tmp_path / "train")
     os.makedirs(train_dir)
@@ -119,10 +112,6 @@ def test_two_process_train_checkpoint_resume(tmp_path):
     assert "Checkpointed" not in outs[1]
 
 
-@pytest.mark.skipif(
-    not compat.SUPPORTS_MULTIPROCESS_CPU,
-    reason="jax 0.4.x CPU backend has no cross-process collectives",
-)
 def test_two_process_gspmd_sharded_checkpoint_resume(tmp_path):
     """The pod checkpoint scenario end-to-end: 2 jax.distributed processes
     with tensor_parallel=4 (model axis across processes). Each process
@@ -152,10 +141,6 @@ def test_two_process_gspmd_sharded_checkpoint_resume(tmp_path):
                 )
 
 
-@pytest.mark.skipif(
-    not compat.SUPPORTS_MULTIPROCESS_CPU,
-    reason="jax 0.4.x CPU backend has no cross-process collectives",
-)
 def test_two_process_warm_start(tmp_path):
     """Vocabulary-curriculum warm start inside a REAL 2-process runtime:
     both processes read the same source FILE checkpoint and materialize
@@ -169,10 +154,6 @@ def test_two_process_warm_start(tmp_path):
     _run_workers(train_dir, "warm", expect_start=0, timeout=1500)
 
 
-@pytest.mark.skipif(
-    not compat.SUPPORTS_MULTIPROCESS_CPU,
-    reason="jax 0.4.x CPU backend has no cross-process collectives",
-)
 def test_two_process_warm_start_gspmd(tmp_path):
     """Curriculum warm start INTO a GSPMD run: the vocab=32 source trains
     dp (full-file checkpoint, the realistic curriculum source), then the

@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from pytorch_distributed_nn_tpu.compat import shard_map
+from jax import shard_map
 from pytorch_distributed_nn_tpu.parallel import make_grad_sync, make_mesh
 from pytorch_distributed_nn_tpu.resilience import (
     FaultPlan,

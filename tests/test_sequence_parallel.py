@@ -10,13 +10,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from pytorch_distributed_nn_tpu.models.transformer import (
     full_attention,
 )
-from pytorch_distributed_nn_tpu import compat
-from pytorch_distributed_nn_tpu.compat import shard_map
 from pytorch_distributed_nn_tpu.parallel import (
     DATA_AXIS,
     SEQ_AXIS,
@@ -109,11 +108,6 @@ class TestRingAttention:
         for a, b in zip(g_full, g_ring):
             np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
-    @pytest.mark.skipif(
-        not compat.SUPPORTS_COLLECTIVES_IN_CUSTOM_VJP,
-        reason="jax 0.4.x falls back to autodiff-through-the-loop "
-               "(no memory-lean custom VJP to pin)",
-    )
     def test_ring_backward_residuals_stay_linear(self):
         """The custom-VJP ring backward recomputes per-hop probabilities
         instead of storing them: the grad jaxpr must hold NO scan-stacked
@@ -237,15 +231,11 @@ class TestSpmdTraining:
         assert int(state.step) == 8
 
     def test_tp_matches_dp(self):
-        """Same seeds: dp=2/tp=2 training == dp=4 training (numerics).
-        0.4.x jaxlib fuses the bf16 matmul reductions differently enough
-        that 8 training steps drift ~1e-3 relative; the strict pin holds
-        on the current-API stack."""
+        """Same seeds: dp=2/tp=2 training == dp=4 training (numerics)."""
         _, m_tp = self._train(2, 2, 1)
         _, m_dp = self._train(4, 1, 1)
-        rtol = 2e-4 if compat.SUPPORTS_COLLECTIVES_IN_CUSTOM_VJP else 2e-3
         np.testing.assert_allclose(
-            float(m_tp["loss"]), float(m_dp["loss"]), rtol=rtol
+            float(m_tp["loss"]), float(m_dp["loss"]), rtol=2e-4
         )
 
     @pytest.mark.parametrize("impl", ["ring", "ulysses"])
@@ -291,12 +281,6 @@ class TestSpmdTraining:
             float(m_flash["loss"]), float(m_dense["loss"]), rtol=2e-4
         )
 
-    @pytest.mark.skipif(
-        not compat.SUPPORTS_NESTED_PARTIAL_MANUAL,
-        reason="int8 GSPMD sync nests a partial-manual shard_map "
-               "inside the manual(data) region — needs the post-0.4 "
-               "shard_map API",
-    )
     @pytest.mark.parametrize("impl", ["ring", "ulysses"])
     def test_int8_first_step_matches_dense(self, impl):
         """The int8-compressed GSPMD step computes the SAME global masked
@@ -310,12 +294,6 @@ class TestSpmdTraining:
             float(m8["loss"]), float(md["loss"]), rtol=1e-5
         )
 
-    @pytest.mark.skipif(
-        not compat.SUPPORTS_NESTED_PARTIAL_MANUAL,
-        reason="int8 GSPMD sync nests a partial-manual shard_map "
-               "inside the manual(data) region — needs the post-0.4 "
-               "shard_map API",
-    )
     def test_int8_trains_dp_tp_sp(self):
         """Quantized dp sync composed with tp/sp optimizes LIKE THE DENSE
         PATH does on the identical stream.
@@ -368,12 +346,6 @@ class TestSpmdTraining:
         assert np.isfinite(float(m["loss"]))
         assert int(state.step) == 4
 
-    @pytest.mark.skipif(
-        not compat.SUPPORTS_NESTED_PARTIAL_MANUAL,
-        reason="int8 GSPMD sync nests a partial-manual shard_map "
-               "inside the manual(data) region — needs the post-0.4 "
-               "shard_map API",
-    )
     def test_int8_trainer_wiring(self, tmp_path):
         """--compress-grad int8 composes with tp/sp through the Trainer
         (the round-3 rejection narrowed; topk still rejected)."""

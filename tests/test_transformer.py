@@ -521,8 +521,8 @@ def test_mlm_grad_accum_matches_full_batch():
         for a, b in zip(
             jax.tree.leaves(s1.params), jax.tree.leaves(sk.params)
         ):
-            # atol 5e-6 not 2e-6: 0.4.x jaxlib fuses the scan-accumulated
-            # grad sums in a different order; worst leaf drift measured
+            # atol 5e-6 not 2e-6: XLA may fuse the scan-accumulated grad
+            # sums in a different order; worst leaf drift measured
             # 2.9e-6 on one element — accumulation order, not bias.
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), atol=5e-6
