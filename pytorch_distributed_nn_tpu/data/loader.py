@@ -34,7 +34,6 @@ from __future__ import annotations
 import multiprocessing as mp
 import queue
 import threading
-import time
 from collections import deque
 from multiprocessing import shared_memory
 from typing import Iterator, Optional, Tuple
@@ -46,6 +45,7 @@ from pytorch_distributed_nn_tpu.data.datasets import (
     _normalize,
     augment_batch,
 )
+from pytorch_distributed_nn_tpu.observability.spans import span
 
 Batch = Tuple[np.ndarray, np.ndarray]
 
@@ -166,8 +166,12 @@ class DataLoader(_IndexedLoader):
         self._pending: deque = deque()
         self._aug_counter = 0
         # input-wait accounting (docs/observability.md): how long the
-        # LAST next_batch() blocked the caller — near zero when the
-        # prefetch thread/pool kept up, the full fetch when it didn't.
+        # LAST next_batch() blocked the caller on host work — its
+        # input/produce span. Near zero when the prefetch thread/pool kept
+        # up, the full fetch when it didn't; the dispatch to the device
+        # (input/put) is not in it: that blocks when the runtime's launch
+        # queue is full, which is the device being busy, not the loader
+        # being slow.
         self.last_wait_ms = 0.0
 
     def _to_device(self, x: np.ndarray, y: np.ndarray) -> Batch:
@@ -178,12 +182,15 @@ class DataLoader(_IndexedLoader):
             y = jax.device_put(y, self.sharding)
         return x, y
 
-    def _make_batch(self, idx: np.ndarray) -> Batch:
+    def _host_batch(self, idx: np.ndarray) -> Batch:
         x = self.dataset.images[idx]
         y = self.dataset.labels[idx]
         if self.dataset.augment:
             x = augment_batch(x, self._rng)
-        return self._to_device(x, y)
+        return x, y
+
+    def _make_batch(self, idx: np.ndarray) -> Batch:
+        return self._to_device(*self._host_batch(idx))
 
     def _produce(self):
         while not self._stop.is_set():
@@ -227,6 +234,7 @@ class DataLoader(_IndexedLoader):
         self._pending.append(self._pool.apply_async(_pool_make_batch, args))
 
     def _pool_next(self) -> Batch:
+        """The next host batch from the worker pool."""
         first = self._pool is None
         self._ensure_pool()
         depth = max(self.prefetch, self.workers)
@@ -248,27 +256,28 @@ class DataLoader(_IndexedLoader):
                 "worker process likely died (OOM-killed or crashed); rerun "
                 "with workers=0 to use the in-process loader"
             ) from None
-        return self._to_device(x, y)
+        return x, y
 
     def next_batch(self) -> Batch:
         """Stateful batch fetch, wrapping across epochs.
 
         (parity: `DataLoader.next_batch`, my_data_loader.py:318)
         """
-        t0 = time.perf_counter()
-        try:
-            if self.workers > 0:
-                return self._pool_next()
-            if self.prefetch == 0:
-                return self._sync_next()
+        if self.workers == 0 and self.prefetch > 0:
+            # the prefetch thread has put the batch on the device already
             self._ensure_thread()
-            return self._queue.get()
-        finally:
-            self.last_wait_ms = (time.perf_counter() - t0) * 1000
-
-    # synchronous fallback path (prefetch=0), also used by __iter__
-    def _sync_next(self) -> Batch:
-        return self._make_batch(self._next_idx())
+            with span("input/produce") as produce:
+                batch = self._queue.get()
+            self.last_wait_ms = produce.seconds * 1000
+            return batch
+        with span("input/produce") as produce:
+            if self.workers > 0:
+                x, y = self._pool_next()
+            else:  # the synchronous path (prefetch=0)
+                x, y = self._host_batch(self._next_idx())
+        self.last_wait_ms = produce.seconds * 1000
+        with span("input/put"):
+            return self._to_device(x, y)
 
     def epoch_batches(self) -> Iterator[Batch]:
         """One full epoch, in order (used by the evaluator / eval loops)."""
@@ -397,16 +406,21 @@ class DeviceDataLoader(_IndexedLoader):
         self._counter += 1
         return idx_dev, jax.random.fold_in(self._key, self._counter)
 
+    def _produce_idx(self) -> np.ndarray:
+        """The next index batch, as the ``input/produce`` span that
+        ``last_wait_ms`` reports: the loader's host work."""
+        with span("input/produce") as produce:
+            idx = self._next_idx()
+        self.last_wait_ms = produce.seconds * 1000
+        return idx
+
     def next_indices(self):
         """(idx_device, prng_key) for one batch — the fused-step path:
         the Trainer passes these (plus .images/.labels/.prep_fn) into one
         jitted program that builds the batch AND takes the train step."""
-        import time
-
-        t0 = time.perf_counter()
-        out = self._idx_key(self._next_idx())
-        self.last_wait_ms = (time.perf_counter() - t0) * 1000
-        return out
+        idx = self._produce_idx()
+        with span("input/put"):
+            return self._idx_key(idx)
 
     def _batch_for(self, idx: np.ndarray) -> Batch:
         import jax
@@ -423,12 +437,9 @@ class DeviceDataLoader(_IndexedLoader):
         return batch
 
     def next_batch(self) -> Batch:
-        import time
-
-        t0 = time.perf_counter()
-        out = self._batch_for(self._next_idx())
-        self.last_wait_ms = (time.perf_counter() - t0) * 1000
-        return out
+        idx = self._produce_idx()
+        with span("input/put"):
+            return self._batch_for(idx)
 
     def epoch_batches(self) -> Iterator[Batch]:
         for idx in self._epoch_index_slices(self._epoch_order()):

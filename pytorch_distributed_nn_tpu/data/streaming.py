@@ -47,11 +47,12 @@ import os
 import queue
 import struct
 import threading
-import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Iterator, List, Optional, Tuple
 
 import numpy as np
+
+from pytorch_distributed_nn_tpu.observability.spans import span
 
 MAGIC = b"PDSR"
 VERSION = 1
@@ -617,19 +618,25 @@ class StreamingLoader:
     # -- public surface ----------------------------------------------------
 
     def next_batch(self) -> Batch:
-        t0 = time.perf_counter()
+        # last_wait_ms is the input/produce span, as in data/loader.py:
+        # the read, the transform or the wait on the pipeline — not the
+        # dispatch to the device (the pipeline's thread has done it)
         if self.prefetch == 0:
-            index, raw, state = self._next_raw()
-            batch = self._to_device(self._transform(raw, index))
+            with span("input/produce") as produce:
+                index, raw, state = self._next_raw()
+                batch = self._transform(raw, index)
+            with span("input/put"):
+                batch = self._to_device(batch)
         else:
             self._ensure_pipeline()
-            batch, state = self._ready.get()
+            with span("input/produce") as produce:
+                batch, state = self._ready.get()
             if isinstance(batch, Exception):
                 raise RuntimeError(
                     f"streaming pipeline failed: {batch!r}"
                 ) from batch
         self._last_state = state
-        self.last_wait_ms = (time.perf_counter() - t0) * 1000
+        self.last_wait_ms = produce.seconds * 1000
         return batch
 
     def epoch_batches(self) -> Iterator[Batch]:

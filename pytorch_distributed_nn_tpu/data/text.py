@@ -18,6 +18,7 @@ from typing import Iterator, Optional, Tuple
 
 import numpy as np
 
+from pytorch_distributed_nn_tpu.observability.spans import span
 from pytorch_distributed_nn_tpu.ops.metrics import IGNORE_INDEX
 
 PAD_ID, CLS_ID, SEP_ID, MASK_ID = 0, 1, 2, 3
@@ -270,15 +271,14 @@ class MLMLoader:
         return jax.device_put(arr, self._sharding)
 
     def next_batch(self):
-        import time
-
-        t0 = time.perf_counter()
-        x, y = next(self._batches)
-        out = self._put(x), self._put(y)
         # input-wait accounting (docs/observability.md): this loader
-        # generates on the calling thread, so the whole fetch is wait
-        self.last_wait_ms = (time.perf_counter() - t0) * 1000
-        return out
+        # generates on the calling thread, so the whole generation is
+        # wait; the dispatch to the device (input/put) is not
+        with span("input/produce") as produce:
+            x, y = next(self._batches)
+        self.last_wait_ms = produce.seconds * 1000
+        with span("input/put"):
+            return self._put(x), self._put(y)
 
     def epoch_batches(self):
         # The eval set stays device-resident for the loader's lifetime

@@ -600,16 +600,20 @@ class Telemetry:
         record's wall time then yields achieved FLOP/s, **MFU** and the
         bandwidth-utilization gauges — derived HERE so the live registry
         and an ``obs export`` replay (which routes through this same
-        method) can never disagree. Streams without a step cost (pre-
-        efficiency runs, serving streams) skip silently — the absent-
-        family contract `obs summary`/`compare` rely on.
+        method) can never disagree. The wall time is the record's
+        ``wall_ms`` (fetch to fetch, nothing subtracted); a stream from
+        before that field falls back to ``step_time``, which leaves out
+        the data phase and so reads high on a device-bound run. Streams
+        without a step cost (pre-efficiency runs, serving streams) skip
+        silently — the absent-family contract `obs summary`/`compare`
+        rely on.
         """
         sc = (self.manifest or {}).get("step_cost")
-        st = rec.get("step_time")
-        if not sc or not st:
+        wall_ms, st = rec.get("wall_ms"), rec.get("step_time")
+        if not sc or not (wall_ms or st):
             return
         try:
-            st = float(st)
+            st = float(wall_ms) / 1000.0 if wall_ms else float(st)
             if st <= 0:
                 return
             reg = self.registry

@@ -476,9 +476,14 @@ def efficiency_summary(rs: RunStream, skip: int = 1) -> Optional[dict]:
     if not flops:
         return None
     timed = rs.steps[skip:] if len(rs.steps) > skip else rs.steps
+    # the clock Telemetry._derive_efficiency uses: the record's wall_ms
+    # (fetch to fetch, nothing subtracted), step_time in older streams
     times = [
-        float(r["step_time"]) for r in timed
-        if r.get("step_time") and float(r["step_time"]) > 0
+        t for t in (
+            float(r["wall_ms"]) / 1000.0 if r.get("wall_ms")
+            else float(r.get("step_time") or 0.0)
+            for r in timed
+        ) if t > 0
     ]
     if not times:
         return None

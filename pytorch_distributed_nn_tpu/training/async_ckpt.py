@@ -77,6 +77,7 @@ import jax
 import jax.numpy as jnp
 
 from pytorch_distributed_nn_tpu.observability.core import get_telemetry
+from pytorch_distributed_nn_tpu.observability.spans import span
 from pytorch_distributed_nn_tpu.training import checkpoint as ckpt
 
 logger = logging.getLogger(__name__)
@@ -193,8 +194,10 @@ class AsyncCheckpointer:
         self._raise_pending()
         if step is None:
             step = int(state.step)
+        with span("ckpt/snapshot"):
+            snapshot = self._clone(state)
         handle = SaveHandle(
-            int(step), self._clone(state), fault_plan=fault_plan,
+            int(step), snapshot, fault_plan=fault_plan,
             retain_device_state=retain_device_state, data_state=data_state,
         )
         handle.stall_ms = (time.perf_counter() - t0) * 1000
@@ -256,10 +259,10 @@ class AsyncCheckpointer:
             if self._in_flight is None:
                 return 0.0
             blocked_on = self._in_flight.step
-            t0 = time.perf_counter()
-            while self._in_flight is not None:
-                self._cv.wait()
-            waited_ms = (time.perf_counter() - t0) * 1000
+            with span("ckpt/backpressure") as waited:
+                while self._in_flight is not None:
+                    self._cv.wait()
+            waited_ms = waited.seconds * 1000
         if emit:
             # never a silent drop: the new save WAITED for the slow one
             get_telemetry().emit(
@@ -310,7 +313,10 @@ class AsyncCheckpointer:
             if item is _STOP:
                 return
             try:
-                self._process(item)
+                # one span over everything this thread does for the save:
+                # whatever is not under a child span is its self time
+                with span("ckpt/write"):
+                    self._process(item)
             except BaseException as e:  # surfaced at the next wait point
                 logger.exception(
                     "async checkpoint of step %d failed", item.step
@@ -361,7 +367,8 @@ class AsyncCheckpointer:
                 )
             item.path = final
             return
-        host = jax.device_get(dev_state)
+        with span("ckpt/fetch"):
+            host = jax.device_get(dev_state)
         fetch_ms = (time.perf_counter() - t_run) * 1000
         if not item.retain_device_state:
             item.dev_state = None

@@ -49,6 +49,7 @@ import numpy as np
 from flax import serialization
 
 from pytorch_distributed_nn_tpu.observability.core import get_telemetry
+from pytorch_distributed_nn_tpu.observability.spans import span
 from pytorch_distributed_nn_tpu.resilience.retry import retry_call
 from pytorch_distributed_nn_tpu.training.train_step import TrainState
 
@@ -227,10 +228,12 @@ def save_checkpoint(
                 "FILE checkpoints — use a fresh --train-dir or the "
                 "matching parallelism config"
             )
-    payload = serialization.to_bytes(state)
+    with span("ckpt/serialize"):
+        payload = serialization.to_bytes(state)
     codec = _codec() if compress else None
     if codec is not None:
-        blob = _MAGIC_LZ + codec.compress(payload)
+        with span("ckpt/compress"):
+            blob = _MAGIC_LZ + codec.compress(payload)
     else:
         blob = _MAGIC_RAW + payload
 
@@ -252,11 +255,12 @@ def save_checkpoint(
         # atomic: the polling evaluator never sees a torn file
         os.replace(tmp, path)
 
-    retry_call(_publish, attempts=3, base_delay=0.05, retry_on=(OSError,),
-               label=f"checkpoint write {path}")
-    _write_file_meta(path, step, blob, geometry=geometry)
-    if data_state is not None:
-        save_data_state(path, data_state)
+    with span("ckpt/file"):
+        retry_call(_publish, attempts=3, base_delay=0.05,
+                   retry_on=(OSError,), label=f"checkpoint write {path}")
+        _write_file_meta(path, step, blob, geometry=geometry)
+        if data_state is not None:
+            save_data_state(path, data_state)
     if fault_plan is not None and fault_plan.should_tear(step):
         _tear_file(path)
         get_telemetry().emit(
@@ -486,18 +490,19 @@ def collect_host_shards(state) -> Tuple[dict, dict]:
     """
     pidx = jax.process_index()
     shards = {}
-    for key, arr in _flat_with_keys(state):
-        if not isinstance(arr, jax.Array):
-            if pidx == 0:  # host scalars: one copy, process 0
-                shards[f"{key}|"] = np.asarray(arr)
-            continue
-        for shard in arr.addressable_shards:
-            if shard.replica_id != 0:
+    with span("ckpt/fetch"):
+        for key, arr in _flat_with_keys(state):
+            if not isinstance(arr, jax.Array):
+                if pidx == 0:  # host scalars: one copy, process 0
+                    shards[f"{key}|"] = np.asarray(arr)
                 continue
-            ikey = _index_key(shard.index, arr.shape)
-            skey = f"{key}|{ikey}"
-            if skey not in shards:  # two local devices may own one region
-                shards[skey] = np.asarray(shard.data)
+            for shard in arr.addressable_shards:
+                if shard.replica_id != 0:
+                    continue
+                ikey = _index_key(shard.index, arr.shape)
+                skey = f"{key}|{ikey}"
+                if skey not in shards:  # two local devices may own one region
+                    shards[skey] = np.asarray(shard.data)
     shapes = {
         key: list(np.shape(leaf)) for key, leaf in _flat_with_keys(state)
     }
@@ -511,9 +516,10 @@ def write_sharded_local(tmp: str, shards: dict) -> str:
     concurrent creates on a shared FS are idempotent, and the async writer
     thread cannot participate in collectives.
     """
-    os.makedirs(tmp, exist_ok=True)
-    out = os.path.join(tmp, f"shards_p{jax.process_index():05d}.npz")
-    np.savez(out, **shards)
+    with span("ckpt/file"):
+        os.makedirs(tmp, exist_ok=True)
+        out = os.path.join(tmp, f"shards_p{jax.process_index():05d}.npz")
+        np.savez(out, **shards)
     return out
 
 
@@ -532,27 +538,28 @@ def publish_sharded(
     for an integrity manifest; disable by policy at pod scale if the
     re-read ever shows up in the checkpoint phase timer.
     """
-    crcs = {}
-    for fname in sorted(os.listdir(tmp)):
-        if fname.startswith("shards_p") and fname.endswith(".npz"):
-            with open(os.path.join(tmp, fname), "rb") as f:
-                crcs[fname] = zlib.crc32(f.read()) & 0xFFFFFFFF
-    with open(os.path.join(tmp, "meta.json"), "w") as f:
-        json.dump(
-            {
-                "format": _SHARDED_FORMAT,
-                "step": step,
-                "processes": jax.process_count(),
-                "crc32": crcs,
-                # global leaf shapes: restore validates the template
-                # against these so a config-mismatched restore fails
-                # loudly instead of zero-padding
-                "shapes": shapes,
-                "geometry": geometry or _default_geometry(),
-            },
-            f,
-        )
-    os.replace(tmp, final)
+    with span("ckpt/file"):
+        crcs = {}
+        for fname in sorted(os.listdir(tmp)):
+            if fname.startswith("shards_p") and fname.endswith(".npz"):
+                with open(os.path.join(tmp, fname), "rb") as f:
+                    crcs[fname] = zlib.crc32(f.read()) & 0xFFFFFFFF
+        with open(os.path.join(tmp, "meta.json"), "w") as f:
+            json.dump(
+                {
+                    "format": _SHARDED_FORMAT,
+                    "step": step,
+                    "processes": jax.process_count(),
+                    "crc32": crcs,
+                    # global leaf shapes: restore validates the template
+                    # against these so a config-mismatched restore fails
+                    # loudly instead of zero-padding
+                    "shapes": shapes,
+                    "geometry": geometry or _default_geometry(),
+                },
+                f,
+            )
+        os.replace(tmp, final)
 
 
 def save_sharded(

@@ -39,6 +39,7 @@ from pytorch_distributed_nn_tpu.parallel import (
     replicated_sharding,
 )
 from pytorch_distributed_nn_tpu.observability import core as obs
+from pytorch_distributed_nn_tpu.observability.spans import span
 from pytorch_distributed_nn_tpu.resilience.faults import (
     FaultPlan,
     InjectedCrash,
@@ -1136,24 +1137,9 @@ class Trainer:
         profile_at = self.start_step + 1 if c.profile_steps > 0 else None
         profile_stop = None
 
-        def flush():
-            """Fetch pending device metrics and finalize their records.
-
-            The device_get is a synchronous fetch that closes the timing
-            window: the metrics cannot reach the host before the steps
-            that produced them have run, so it is a correct completion
-            point, and it bounds how far dispatch runs ahead of the
-            device. Cost: one blocking fetch per log_every window.
-            """
-            nonlocal window_t0, window_data
-            if not pending:
-                return
-            fetched = jax.device_get([r.pop("_metrics") for r in pending])
-            step_time = max(
-                (time.perf_counter() - window_t0 - window_data)
-                / len(pending),
-                1e-9,
-            )
+        def publish(fetched, step_time, wall_ms):
+            """Finalize the window's records: the stream write, the
+            derived events, the parity log line and the rate gauges."""
             for record, m in zip(pending, fetched):
                 record.update(
                     loss=float(m["loss"]),
@@ -1161,6 +1147,7 @@ class Trainer:
                     acc5=float(m["acc5"]),
                     step_time=step_time,
                     imgs_per_sec=c.batch_size / step_time,
+                    wall_ms=wall_ms,
                 )
                 # resilience extras ride along: straggler_dropped[_mask]/
                 # straggler_skew (grad_sync report) and skipped_nonfinite
@@ -1226,7 +1213,7 @@ class Trainer:
             # step-rate / ETA gauges: exported via metrics.prom on every
             # heartbeat tick and carried in heartbeat.json itself, so an
             # external babysitter reads progress without parsing the stream
-            rate = 1.0 / step_time
+            rate = 1000.0 / wall_ms
             eta = max(total_steps - last["step"], 0) / rate
             reg = self.telemetry.registry
             reg.gauge("step_rate", help="steps/s over the last log window") \
@@ -1237,9 +1224,40 @@ class Trainer:
                 sup.extra.update(
                     step_rate=round(rate, 4), eta_seconds=round(eta, 2)
                 )
-            pending.clear()
-            window_t0 = time.perf_counter()
-            window_data = 0.0
+
+        def flush():
+            """Fetch pending device metrics and finalize their records.
+
+            The device_get is a synchronous fetch that closes the timing
+            window: the metrics cannot reach the host before the steps
+            that produced them have run, so it is a correct completion
+            point, and it bounds how far dispatch runs ahead of the
+            device. Cost: one blocking fetch per log_every window.
+            """
+            nonlocal window_t0, window_data, wall_t0
+            if not pending:
+                return
+            with span("train/flush"):
+                with span("train/flush_fetch"):
+                    fetched = jax.device_get(
+                        [r.pop("_metrics") for r in pending]
+                    )
+                now = time.perf_counter()
+                # the wall clock: fetch to fetch, nothing subtracted, not
+                # restarted by a save (step_time below leaves out the data
+                # phase, where the dispatch thread blocks once the
+                # runtime's launch queue is full, and restarts after saves)
+                wall_ms = (now - wall_t0) * 1000.0 / len(pending)
+                wall_t0 = now
+                step_time = max(
+                    (now - window_t0 - window_data) / len(pending),
+                    1e-9,
+                )
+                with span("train/flush_publish"):
+                    publish(fetched, step_time, wall_ms)
+                pending.clear()
+                window_t0 = time.perf_counter()
+                window_data = 0.0
 
         import contextlib
 
@@ -1283,96 +1301,105 @@ class Trainer:
 
         ok = False  # set only when the loop body completes
         step = self.start_step - 1  # last completed step when the loop is empty
+        wall_t0 = time.perf_counter()  # the first window's wall_ms starts here
         try:
           with (sup if sup is not None else contextlib.nullcontext()):
             for step in range(self.start_step, total_steps):
-                if plan is not None:
-                    # 1-indexed fault steps; delay entries become real
-                    # host sleeps only when no straggler simulator is
-                    # consuming them as simulated arrival time
-                    plan.pre_step(
-                        step + 1, sleep_delays=self._straggler_sim is None
-                    )
-                if sup is not None and sup.should_stop:
-                    preempt_exit(step)
-                    break
-                if profile_at is not None and step == profile_at:
-                    pdir = c.profile_dir or f"{c.train_dir}/profile"
-                    jax.profiler.start_trace(pdir)
-                    profile_stop = step + c.profile_steps
-                    logger.info(
-                        "Profiling steps %d..%d to %s",
-                        step + 1, profile_stop, pdir,
-                    )
-                timer.reset()
-                if self._fused_step is not None:
-                    with timer.phase("data"):
-                        idx, key = self.train_loader.next_indices()
-                    window_data += timer.durations["data"]
-                    self.state, m = self._fused_step(
-                        self.state, self.train_loader.images,
-                        self.train_loader.labels, idx, key, rng,
-                    )
-                else:
-                    with timer.phase("data"):
-                        batch = self.train_loader.next_batch()
-                    window_data += timer.durations["data"]
+                with span("train/step"):
                     if plan is not None:
-                        batch = plan.poison_batch(step + 1, batch)
-                    self.state, m = self.train_step(self.state, batch, rng)
-                if step == self.start_step and self._async_ckpt is not None:
-                    # Warm the snapshot clone on the POST-step state: its
-                    # avals/shardings are what every save sees (the init
-                    # state's signature differs, so warming there would
-                    # compile a program no save ever uses and the first
-                    # checkpoint would still pay the ~100 ms retrace).
-                    # Rides the compile step, off every timed window.
-                    self._async_ckpt.warmup(self.state)
-                # input-wait accounting: how long the loop actually
-                # BLOCKED on the loader (its own measurement — near zero
-                # when prefetch kept up); loaders without the attribute
-                # bill the whole data phase, which for them IS the wait.
-                wait_ms = getattr(self.train_loader, "last_wait_ms", None)
-                if wait_ms is None:
-                    wait_ms = timer.durations.get("data", 0.0) * 1000.0
-                pending.append({
-                    "step": step + 1,
-                    "epoch": step // max(steps_per_epoch, 1),
-                    "_metrics": m,
-                    "data_time": timer.durations.get("data", 0.0),
-                    "input_wait_ms": round(wait_ms, 3),
-                })
-                if (step + 1) % c.log_every == 0:
-                    flush()
-                if profile_stop is not None and step + 1 >= profile_stop:
-                    flush()  # force completion so the trace has real steps
-                    jax.profiler.stop_trace()
-                    profile_stop = profile_at = None
-                if c.eval_freq and (step + 1) % c.eval_freq == 0:
-                    flush()  # checkpoint below reads the live state
-                    self._save_periodic(step + 1, plan, timer)
-                    # don't bill the checkpoint blockage to the next
-                    # window's step_time. Sync: the blockage is the full
-                    # write; async: only the snapshot/backpressure stall —
-                    # either way stall_ms on the checkpoint_write event is
-                    # what the loop actually lost (the write itself
-                    # overlaps the following steps and shows up, if at
-                    # all, as their own wall time).
-                    window_t0 = time.perf_counter()
-                if self._flightrec is not None:
-                    # step boundary: finish a due capture window / open a
-                    # pending one. The recorder never nests a trace inside
-                    # a user --profile span (two jax traces cannot nest).
-                    self._flightrec.tick(
-                        step + 1, trace_ok=profile_stop is None
-                    )
-                if sup is not None:
-                    sup.beat(step + 1)
-                    # a signal that landed DURING the step exits here, so
-                    # the grace window is one step + checkpoint, not two
-                    if sup.should_stop:
-                        preempt_exit(step + 1)
+                        # 1-indexed fault steps; delay entries become real
+                        # host sleeps only when no straggler simulator is
+                        # consuming them as simulated arrival time
+                        plan.pre_step(
+                            step + 1, sleep_delays=self._straggler_sim is None
+                        )
+                    if sup is not None and sup.should_stop:
+                        preempt_exit(step)
                         break
+                    if profile_at is not None and step == profile_at:
+                        pdir = c.profile_dir or f"{c.train_dir}/profile"
+                        jax.profiler.start_trace(pdir)
+                        profile_stop = step + c.profile_steps
+                        logger.info(
+                            "Profiling steps %d..%d to %s",
+                            step + 1, profile_stop, pdir,
+                        )
+                    timer.reset()
+                    if self._fused_step is not None:
+                        with timer.phase("train/data"):
+                            idx, key = self.train_loader.next_indices()
+                        window_data += timer.durations["train/data"]
+                        with span("train/dispatch"):
+                            self.state, m = self._fused_step(
+                                self.state, self.train_loader.images,
+                                self.train_loader.labels, idx, key, rng,
+                            )
+                    else:
+                        with timer.phase("train/data"):
+                            batch = self.train_loader.next_batch()
+                        window_data += timer.durations["train/data"]
+                        if plan is not None:
+                            batch = plan.poison_batch(step + 1, batch)
+                        with span("train/dispatch"):
+                            self.state, m = self.train_step(
+                                self.state, batch, rng
+                            )
+                    if step == self.start_step and self._async_ckpt is not None:
+                        # Warm the snapshot clone on the POST-step state: its
+                        # avals/shardings are what every save sees (the init
+                        # state's signature differs, so warming there would
+                        # compile a program no save ever uses and the first
+                        # checkpoint would still pay the ~100 ms retrace).
+                        # Rides the compile step, off every timed window.
+                        self._async_ckpt.warmup(self.state)
+                    # input-wait accounting: how long the loop actually
+                    # BLOCKED on the loader (its own measurement: the
+                    # input/produce span, without the dispatch to the
+                    # device — near zero when prefetch kept up); loaders
+                    # without the attribute bill the whole data phase.
+                    data_time = timer.durations.get("train/data", 0.0)
+                    wait_ms = getattr(self.train_loader, "last_wait_ms", None)
+                    if wait_ms is None:
+                        wait_ms = data_time * 1000.0
+                    pending.append({
+                        "step": step + 1,
+                        "epoch": step // max(steps_per_epoch, 1),
+                        "_metrics": m,
+                        "data_time": data_time,
+                        "input_wait_ms": round(wait_ms, 3),
+                    })
+                    if (step + 1) % c.log_every == 0:
+                        flush()
+                    if profile_stop is not None and step + 1 >= profile_stop:
+                        flush()  # force completion so the trace has real steps
+                        jax.profiler.stop_trace()
+                        profile_stop = profile_at = None
+                    if c.eval_freq and (step + 1) % c.eval_freq == 0:
+                        flush()  # checkpoint below reads the live state
+                        with span("ckpt/save"):
+                            self._save_periodic(step + 1, plan)
+                        # don't bill the checkpoint blockage to the next
+                        # window's step_time. Sync: the blockage is the full
+                        # write; async: only the snapshot/backpressure stall —
+                        # either way stall_ms on the checkpoint_write event is
+                        # what the loop actually lost (the write itself
+                        # overlaps the following steps and shows up, if at
+                        # all, as their own wall time).
+                        window_t0 = time.perf_counter()
+                    if self._flightrec is not None:
+                        # step boundary: finish a due capture window / open a
+                        # pending one. The recorder never nests a trace inside
+                        # a user --profile span (two jax traces cannot nest).
+                        self._flightrec.tick(
+                            step + 1, trace_ok=profile_stop is None
+                        )
+                    if sup is not None:
+                        sup.beat(step + 1)
+                        # a signal that landed DURING the step exits here, so
+                        # the grace window is one step + checkpoint, not two
+                        if sup.should_stop:
+                            preempt_exit(step + 1)
+                            break
             ok = True
         except InjectedCrash:
             # An abrupt injected failure: persist what we have (the state
@@ -1456,7 +1483,7 @@ class Trainer:
             logger.exception("loader state capture failed (non-fatal)")
             return None
 
-    def _save_periodic(self, step: int, plan, timer) -> None:
+    def _save_periodic(self, step: int, plan) -> None:
         """One periodic checkpoint at ``step`` (the --eval-freq path).
 
         Async (default): on-device snapshot + enqueue to the background
@@ -1474,12 +1501,11 @@ class Trainer:
             # its own shard fetch.
             if not self.use_spmd and jax.process_index() != 0:
                 return
-            with timer.phase("checkpoint"):
-                handle = self._async_ckpt.save(
-                    self.state, step=step, fault_plan=plan,
-                    retain_device_state=c.overlap_eval,
-                    data_state=data_state,
-                )
+            handle = self._async_ckpt.save(
+                self.state, step=step, fault_plan=plan,
+                retain_device_state=c.overlap_eval,
+                data_state=data_state,
+            )
             logger.info(
                 "Checkpoint step %d handed to the async writer "
                 "(loop stalled %.1f ms)", step, handle.stall_ms,
@@ -1491,10 +1517,9 @@ class Trainer:
             # Sharded save: collective — every process writes its
             # own shards; nobody gathers the full state
             # (checkpoint.save_sharded).
-            with timer.phase("checkpoint"):
-                path = ckpt.save_sharded(c.train_dir, self.state, step=step,
-                                         data_state=data_state,
-                                         geometry=self._geometry)
+            path = ckpt.save_sharded(c.train_dir, self.state, step=step,
+                                     data_state=data_state,
+                                     geometry=self._geometry)
             if jax.process_index() == 0:
                 if c.keep_last is not None:
                     ckpt.gc_checkpoints(c.train_dir, c.keep_last)
@@ -1507,12 +1532,13 @@ class Trainer:
             # reference's NFS race (all workers race-writing the
             # same model_step_<N> path,
             # src/distributed_worker.py:304-307).
-            with timer.phase("checkpoint"):
-                path = ckpt.save_checkpoint(
-                    c.train_dir, self._host_state(), step=step,
-                    fault_plan=plan, data_state=data_state,
-                    geometry=self._geometry,
-                )
+            with span("ckpt/fetch"):
+                host = self._host_state()
+            path = ckpt.save_checkpoint(
+                c.train_dir, host, step=step,
+                fault_plan=plan, data_state=data_state,
+                geometry=self._geometry,
+            )
             if c.keep_last is not None:
                 ckpt.gc_checkpoints(c.train_dir, c.keep_last)
             logger.info("Checkpointed step %d to %s", step, path)

@@ -7,9 +7,11 @@ unified telemetry layer landed they are veneers over
 
 - :class:`PhaseTimer` still accumulates named wall-clock phases per
   iteration (reference: src/distributed_worker.py:146-173 — fetch-weights /
-  forward / backward / comm); given a registry it ALSO feeds each phase
-  into the ``phase_seconds{phase=...}`` histogram, so phases show up in
-  the Prometheus exposition without a second timing source.
+  forward / backward / comm). Each phase is a span
+  (``observability/spans.py``): an event in a profiler trace while one is
+  being collected, and an observation of the
+  ``phase_seconds{phase=...}`` histogram, so phases show up in the
+  Prometheus exposition without a second timing source.
 - :class:`MetricsLogger` still appends one JSONL record per step, but the
   stream is now a telemetry stream: a run-manifest header record first,
   ``kind``-tagged records after (observability/core.TelemetrySink). Passing
@@ -19,13 +21,17 @@ unified telemetry layer landed they are veneers over
 
 from __future__ import annotations
 
-import time
 from contextlib import contextmanager
 from typing import Dict, Optional
 
+from pytorch_distributed_nn_tpu.observability.spans import Span
+
 
 class PhaseTimer:
-    """Accumulates named wall-clock phases for one iteration."""
+    """Accumulates named wall-clock phases for one iteration.
+
+    ``registry`` receives the ``phase_seconds`` observations; without one
+    they go to the installed telemetry's."""
 
     def __init__(self, registry=None):
         self.durations: Dict[str, float] = {}
@@ -33,17 +39,12 @@ class PhaseTimer:
 
     @contextmanager
     def phase(self, name: str):
-        t0 = time.perf_counter()
+        s = Span(name, self._registry)
         try:
-            yield
+            with s:
+                yield
         finally:
-            dt = time.perf_counter() - t0
-            self.durations[name] = self.durations.get(name, 0.0) + dt
-            if self._registry is not None:
-                self._registry.histogram(
-                    "phase_seconds", help="wall-clock per phase",
-                    labels={"phase": name},
-                ).observe(dt)
+            self.durations[name] = self.durations.get(name, 0.0) + s.seconds
 
     def reset(self):
         self.durations = {}
