@@ -127,10 +127,10 @@ def _add_common_train_flags(p: argparse.ArgumentParser):
                         "builds batches on-device (4 KB/step host traffic); "
                         "'host' is the prefetch-thread loader")
     p.add_argument("--loader-workers", type=int, default=0,
-                   help="host layout: loader worker PROCESSES sharing the "
-                        "uint8 dataset via shared memory (0 = prefetch "
-                        "thread); the reference's fork-worker loader. With "
-                        "--data-path: the streaming loader's decode THREADS")
+                   help="host layout and --data-path: threads that prepare "
+                        "(gather, normalize or decode, augment) batches "
+                        "ahead of the loop (0 = one prefetch thread); the "
+                        "reference's fork-worker loader capability")
     p.add_argument("--data-path", default=None, metavar="DIR",
                    help="sharded streaming input (docs/data.md): read the "
                         "TRAINING stream from this shard directory "

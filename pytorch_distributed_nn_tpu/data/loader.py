@@ -9,33 +9,23 @@ stacks) upcoming batches into a bounded queue and optionally
 `jax.device_put`s them with the target sharding so host→HBM transfer
 overlaps compute — the TPU equivalent of pinned-memory prefetch (:56-75).
 
-``workers=N`` additionally enables a true multi-process pool (the
-reference's :37-53 capability): N spawned processes share the uint8
-dataset through POSIX shared memory (no per-worker copy of the pixels,
-and no full-dataset float32 materialization at all — each batch is
-normalized from uint8 inside the worker), gather + normalize + augment
-in parallel, and stream completed float32 batches back to the parent,
-which `device_put`s them. This is the path for datasets too large for
-the HBM-resident `DeviceDataLoader` (trainer.py's ~2 GB budget): device
-upload still happens once per batch, but all CPU work scales with N.
-`spawn` (not fork) is used deliberately — forking a process with a live
-multi-threaded JAX runtime can deadlock.
-
-Measured honesty: on this repo's 1-vCPU CI host the pool is SLOWER than
-the thread (95 ms vs 6.7 ms per b1024 CIFAR batch — IPC cost with no
-cores to parallelize over; the thread path already runs the C++ augment
-engine at 150k img/s there). The pool's win requires a multi-core host
-(real TPU-VMs expose 96+ vCPUs), which this environment cannot measure;
-default stays workers=0.
+``workers=N`` prepares batches on N worker threads, the mechanism the
+streaming loader uses (data/streaming.py): each batch is gathered from the
+uint8 dataset, normalized and augmented on a thread of its own (no
+full-dataset float32 materialization), ``max(prefetch, workers)`` batches
+ahead, and handed to the caller in submission order. The work runs off the
+interpreter lock — large numpy operations and the C++ augment engine — so
+threads spread it over the host's cores. This is the path for datasets too
+large for the HBM-resident `DeviceDataLoader` (trainer.py's ~2 GB budget):
+device upload still happens once per batch. Default stays workers=0.
 """
 
 from __future__ import annotations
 
-import multiprocessing as mp
 import queue
 import threading
 from collections import deque
-from multiprocessing import shared_memory
+from concurrent.futures import ThreadPoolExecutor
 from typing import Iterator, Optional, Tuple
 
 import numpy as np
@@ -48,33 +38,6 @@ from pytorch_distributed_nn_tpu.data.datasets import (
 from pytorch_distributed_nn_tpu.observability.spans import span
 
 Batch = Tuple[np.ndarray, np.ndarray]
-
-# --- worker-pool plumbing (module-level for spawn picklability) -----------
-
-_POOL_STATE = None  # set in each worker by _pool_init
-
-
-def _pool_init(shm_name, shape, labels, mean, std, augment):
-    """Worker initializer: attach the shared uint8 pixel block."""
-    global _POOL_STATE
-    shm = shared_memory.SharedMemory(name=shm_name)
-    raw = np.ndarray(shape, dtype=np.uint8, buffer=shm.buf)
-    _POOL_STATE = (shm, raw, labels, mean, std, augment)
-
-
-def _pool_make_batch(idx, aug_seed):
-    """One batch in a worker: uint8 gather -> normalize -> augment.
-
-    ``aug_seed`` is the (loader_seed, batch_counter) pair — per-batch
-    seeding (workers cannot share the thread path's sequential rng
-    stream) that still honors the loader's seed: different --seed runs
-    draw different augmentations.
-    """
-    _, raw, labels, mean, std, augment = _POOL_STATE
-    x = _normalize(raw[idx], mean, std)
-    if augment:
-        x = augment_batch(x, np.random.RandomState(list(aug_seed)))
-    return x, labels[idx]
 
 
 class _IndexedLoader:
@@ -161,17 +124,16 @@ class DataLoader(_IndexedLoader):
         self._queue: Optional[queue.Queue] = None
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
-        self._pool = None
-        self._shm = None
+        self._pool: Optional[ThreadPoolExecutor] = None
         self._pending: deque = deque()
         self._aug_counter = 0
         # input-wait accounting (docs/observability.md): how long the
         # LAST next_batch() blocked the caller on host work — its
-        # input/produce span. Near zero when the prefetch thread/pool kept
-        # up, the full fetch when it didn't; the dispatch to the device
-        # (input/put) is not in it: that blocks when the runtime's launch
-        # queue is full, which is the device being busy, not the loader
-        # being slow.
+        # input/produce span. Near zero when the prefetch thread or the
+        # workers kept up, the full fetch when they didn't; the dispatch
+        # to the device (input/put) is not in it: that blocks when the
+        # runtime's launch queue is full, which is the device being busy,
+        # not the loader being slow.
         self.last_wait_ms = 0.0
 
     def _to_device(self, x: np.ndarray, y: np.ndarray) -> Batch:
@@ -212,51 +174,33 @@ class DataLoader(_IndexedLoader):
             self._thread = threading.Thread(target=self._produce, daemon=True)
             self._thread.start()
 
-    # --- multi-process pool path (workers > 0) -------------------------
+    # --- worker-thread path (workers > 0) ------------------------------
 
-    def _ensure_pool(self):
-        if self._pool is not None:
-            return
-        raw = self.dataset.raw_images
-        self._shm = shared_memory.SharedMemory(create=True, size=raw.nbytes)
-        np.ndarray(raw.shape, dtype=np.uint8, buffer=self._shm.buf)[:] = raw
-        self._pool = mp.get_context("spawn").Pool(
-            self.workers,
-            initializer=_pool_init,
-            initargs=(self._shm.name, raw.shape, self.dataset.labels,
-                      self.dataset.mean, self.dataset.std,
-                      self.dataset.augment),
-        )
-
-    def _submit_one(self):
-        self._aug_counter += 1
-        args = (self._next_idx(), (self._seed, self._aug_counter))
-        self._pending.append(self._pool.apply_async(_pool_make_batch, args))
+    def _worker_batch(self, idx: np.ndarray, counter: int) -> Batch:
+        """One batch on a worker thread: uint8 gather -> normalize ->
+        augment. Seeded per batch by (loader seed, batch counter): workers
+        cannot share the thread path's sequential rng stream, and a
+        different --seed still draws different augmentations."""
+        ds = self.dataset
+        x = _normalize(ds.raw_images[idx], ds.mean, ds.std)
+        if ds.augment:
+            x = augment_batch(x, np.random.RandomState([self._seed, counter]))
+        return x, ds.labels[idx]
 
     def _pool_next(self) -> Batch:
-        """The next host batch from the worker pool."""
-        first = self._pool is None
-        self._ensure_pool()
+        """The next host batch from the worker threads, in submission
+        order; a worker's exception is raised here."""
+        if self._pool is None:
+            self._pool = ThreadPoolExecutor(
+                self.workers, thread_name_prefix="pdtn-loader-worker"
+            )
         depth = max(self.prefetch, self.workers)
         while len(self._pending) < depth:
-            self._submit_one()
-        # The first batch also pays pool startup: spawning N fresh
-        # interpreters (each re-importing numpy) plus the shared-memory
-        # dataset copy — on a loaded/swapping host that alone can exceed
-        # the steady-state bound, so give it a much longer leash.
-        timeout = 600 if first else 120
-        try:
-            # mp.Pool never fails a lost task's AsyncResult if a worker
-            # dies (OOM-kill, native-extension segfault) — without a
-            # timeout training would freeze silently.
-            x, y = self._pending.popleft().get(timeout=timeout)
-        except mp.TimeoutError:
-            raise RuntimeError(
-                f"loader worker pool produced no batch for {timeout}s — a "
-                "worker process likely died (OOM-killed or crashed); rerun "
-                "with workers=0 to use the in-process loader"
-            ) from None
-        return x, y
+            self._aug_counter += 1
+            self._pending.append(self._pool.submit(
+                self._worker_batch, self._next_idx(), self._aug_counter
+            ))
+        return self._pending.popleft().result()
 
     def next_batch(self) -> Batch:
         """Stateful batch fetch, wrapping across epochs.
@@ -290,17 +234,11 @@ class DataLoader(_IndexedLoader):
             self._thread.join(timeout=2.0)
             self._thread = None
         if self._pool is not None:
-            self._pool.terminate()
-            self._pool.join()
+            # nothing is killed, so nothing dies holding a lock: queued
+            # batches are cancelled, the ones running finish
+            self._pool.shutdown(wait=True, cancel_futures=True)
             self._pool = None
             self._pending.clear()
-        if self._shm is not None:
-            self._shm.close()
-            try:
-                self._shm.unlink()
-            except FileNotFoundError:
-                pass
-            self._shm = None
 
     def __del__(self):
         try:

@@ -449,8 +449,15 @@ def run_eval_pass(eval_step, state, loader) -> dict:
     # per batch is 3 blocking device->host fetches x batches, each of
     # which drains the dispatch queue.
     totals, n = None, 0
+    # The intra-process multi-device CPU backend can deadlock its
+    # collective rendezvous with several eval steps in flight (XLA then
+    # aborts the process after 40 s); finishing each one first serializes
+    # them, as DeviceDataLoader._batch_for does. TPU keeps the overlap.
+    serialize = jax.default_backend() == "cpu"
     for batch in loader.epoch_batches():
         m = eval_step(state, batch)
+        if serialize:
+            jax.block_until_ready(m)
         totals = m if totals is None else jax.tree.map(jnp.add, totals, m)
         n += 1
     if n == 0:

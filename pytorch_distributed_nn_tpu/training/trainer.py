@@ -770,7 +770,7 @@ class Trainer:
                         "--loader-workers %d ignored: data_layout resolved "
                         "to 'device' (batches are built on-chip; there is "
                         "no host loader to parallelize). Pass "
-                        "--data-layout host to use the worker pool.",
+                        "--data-layout host to use the worker threads.",
                         c.loader_workers,
                     )
                 from pytorch_distributed_nn_tpu.data.loader import (

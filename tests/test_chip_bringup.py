@@ -82,8 +82,7 @@ def test_compile_cache_fixed_path_on_an_accelerator(monkeypatch):
 
 
 @pytest.mark.skipif(
-    subprocess.run(["sh", "-c", "command -v make && command -v g++"],
-                   capture_output=True).returncode != 0,
+    not (shutil.which("make") and shutil.which("g++")),
     reason="needs make and g++",
 )
 def test_ensure_built_rebuilds_a_library_older_than_its_source(tmp_path):

@@ -231,53 +231,91 @@ def test_loader_prefetch_thread():
         loader.close()
 
 
-def test_loader_worker_pool_matches_sync_path():
+def _take(loader, n):
+    try:
+        return [loader.next_batch() for _ in range(n)]
+    finally:
+        loader.close()
+
+
+@pytest.mark.parametrize(
+    "name,batch,n,kw_a,kw_b,same",
+    [
+        # unaugmented: byte-identical to the in-process path, across epoch
+        # wrap-around (7 batches > 2 epochs of 3)
+        ("MNIST", 32, 7, dict(shuffle=False, workers=2),
+         dict(shuffle=False, prefetch=0), True),
+        # augmented + shuffled: two loaders with one seed give one stream
+        ("Cifar10", 64, 3, dict(seed=3, workers=2), dict(seed=3, workers=2),
+         True),
+        # the loader seed reaches the workers' augment stream: a different
+        # --seed draws different crops/flips (and a different shuffle)
+        ("Cifar10", 64, 3, dict(seed=3, workers=2), dict(seed=4, workers=2),
+         False),
+    ],
+    ids=["equals_sync_path", "one_seed_one_stream", "other_seed_other_stream"],
+)
+def test_loader_workers(name, batch, n, kw_a, kw_b, same):
     """workers=N (the reference's fork-worker loader capability,
-    my_data_loader.py:37-53): spawned processes share the uint8 pixels
-    via POSIX shared memory and must produce byte-identical batches to
-    the in-process path on an unaugmented dataset (MNIST), including
-    epoch wrap-around, and an identical stream across two pool loaders
-    with the same seed (per-batch augment seeding)."""
-    ds = load_dataset("MNIST", train=False, synthetic_size=96)
-    a = DataLoader(ds, batch_size=32, shuffle=False, workers=2)
-    b = DataLoader(ds, batch_size=32, shuffle=False, prefetch=0)
-    try:
-        for _ in range(7):  # > 2 epochs of 3 batches
-            xa, ya = a.next_batch()
-            xb, yb = b.next_batch()
-            np.testing.assert_array_equal(xa, xb)
-            np.testing.assert_array_equal(ya, yb)
-    finally:
-        a.close()
-        b.close()
+    my_data_loader.py:37-53): worker threads gather, normalize and augment
+    from the uint8 pixels, per-batch seeded by (loader seed, batch
+    counter). Each case builds its own loaders."""
+    ds = load_dataset(name, train=True, synthetic_size=96)
+    assert ds.augment == (name != "MNIST")
+    got_a = _take(DataLoader(ds, batch_size=batch, **kw_a), n)
+    got_b = _take(DataLoader(ds, batch_size=batch, **kw_b), n)
+    assert got_a[0][0].shape == (batch, *ds.raw_images.shape[1:])
+    assert got_a[0][0].dtype == np.float32
+    if not same:
+        assert not np.array_equal(got_a[0][0], got_b[0][0])
+        return
+    for (xa, ya), (xb, yb) in zip(got_a, got_b):
+        np.testing.assert_array_equal(xa, xb)
+        np.testing.assert_array_equal(ya, yb)
 
-    # augmented + shuffled: two pool loaders with one seed agree exactly
+
+def test_loader_close_with_batches_in_flight_returns():
+    """close() while the batches submitted ahead are still being made
+    returns promptly, every time, and leaves no worker behind. (Before
+    PR 25 the workers were processes and close() terminated them with
+    results in flight: on a loaded host one died holding the result
+    pipe's lock and close() never returned.)"""
+    import multiprocessing
+    import threading
+    import time
+
+    cds = load_dataset("Cifar10", train=True, synthetic_size=256)
+    for seed in range(20):
+        loader = DataLoader(cds, batch_size=64, seed=seed, workers=2, prefetch=4)
+        loader.next_batch()  # leaves prefetch - 1 batches submitted
+        assert len(loader._pending) == 3
+        t0 = time.monotonic()
+        loader.close()
+        assert time.monotonic() - t0 < 5.0
+        assert not loader._pending
+    assert multiprocessing.active_children() == []
+    assert not [
+        t.name for t in threading.enumerate()
+        if t.name.startswith("pdtn-loader-worker")
+    ]
+
+
+def test_loader_worker_exception_reaches_next_batch(monkeypatch):
+    """A worker thread that raises delivers its exception to the caller of
+    next_batch() (Future.result), not a wait."""
+    from pytorch_distributed_nn_tpu.data import loader as loader_mod
+
+    def boom(images, rng):
+        raise ValueError("augment failed")
+
+    monkeypatch.setattr(loader_mod, "augment_batch", boom)
     cds = load_dataset("Cifar10", train=True, synthetic_size=128)
-    assert cds.augment
-    c = DataLoader(cds, batch_size=64, shuffle=True, seed=3, workers=2)
-    d = DataLoader(cds, batch_size=64, shuffle=True, seed=3, workers=2)
-    first = None
+    loader = DataLoader(cds, batch_size=64, workers=2)
     try:
-        for _ in range(3):
-            xc, yc = c.next_batch()
-            xd, yd = d.next_batch()
-            if first is None:
-                first = xc
-            np.testing.assert_array_equal(xc, xd)
-            np.testing.assert_array_equal(yc, yd)
-            assert xc.shape == (64, 32, 32, 3) and xc.dtype == np.float32
+        with pytest.raises(ValueError, match="augment failed"):
+            loader.next_batch()
     finally:
-        c.close()
-        d.close()
-
-    # the loader seed reaches the pool's augment stream: a different
-    # --seed must draw different crops/flips (and a different shuffle)
-    e = DataLoader(cds, batch_size=64, shuffle=True, seed=4, workers=2)
-    try:
-        xe, _ = e.next_batch()
-        assert not np.array_equal(xe, first)
-    finally:
-        e.close()
+        loader.close()
 
 
 def test_loader_epoch_batches_covers_dataset():

@@ -872,7 +872,8 @@ class TestFrontendE2E:
             t.start()
             time.sleep(1.0)
             fe.kill_replica("r0")
-            t.join()
+            t.join(timeout=60)  # 4 s of load + 5 s request timeouts
+            assert not t.is_alive()
             assert holder["res"]["failed"] == 0
             assert holder["res"]["ok"] == holder["res"]["submitted"]
             fe.restart_replica("r0")

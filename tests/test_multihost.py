@@ -90,7 +90,7 @@ def _run_workers(train_dir: str, mode: str, expect_start: int = 4,
                 p.kill()
         for p in procs:
             if p.returncode is None:
-                p.wait()
+                p.wait(timeout=30)
     for pid, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"proc {pid} failed:\n{out[-4000:]}"
         assert f"WORKER_OK {pid} start_step={expect_start}" in out, (
