@@ -1,0 +1,164 @@
+"""What only the chip's compiler can show, checked with no chip: programs of
+the main path lowered for a described TPU v5e (libtpu is installed here; the
+devices are described, not attached) at the sizes the benchmark's cells run,
+and the optimised module read for what a CPU run cannot see.
+
+The topology is described inside a fixture, never at import, and every
+test that needs it lives in this one file: one process at a time may load
+libtpu, and pytest-xdist hands a file to one worker.
+"""
+
+import math
+import os
+import re
+
+import numpy as np
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+N_IMAGES, BATCH = 50_000, 4096  # the resnet18_b4096 cells' shapes
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip_mesh(topo):
+    from pytorch_distributed_nn_tpu.parallel import make_mesh
+
+    return make_mesh(1, devices=topo.devices[:1])
+
+
+@pytest.fixture(scope="module")
+def cifar_prep():
+    """(prep_fn, the per-image shape of what the loader holds resident,
+    (H, W, C)) of a throw-away CIFAR-10 loader on the CPU: the loader wants
+    real devices, its prep function is pure."""
+    import jax
+
+    from pytorch_distributed_nn_tpu.data import load_dataset
+    from pytorch_distributed_nn_tpu.data.loader import DeviceDataLoader
+    from pytorch_distributed_nn_tpu.parallel import make_mesh
+
+    ds = load_dataset("Cifar10", train=True, synthetic_size=8)
+    loader = DeviceDataLoader(
+        ds, 8, make_mesh(1, devices=jax.devices("cpu")[:1]))
+    return loader.prep_fn, loader.images.shape[1:], ds.raw_images.shape[1:]
+
+
+def _loader_args(mesh, held_shape):
+    """Shapes of (images, labels, idx, key) as the trainer passes them."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pytorch_distributed_nn_tpu.parallel.mesh import DATA_AXIS
+
+    rep = NamedSharding(mesh, P())
+    split = NamedSharding(mesh, P(DATA_AXIS))
+    return (
+        jax.ShapeDtypeStruct((N_IMAGES, *held_shape), np.uint8, sharding=rep),
+        jax.ShapeDtypeStruct((N_IMAGES,), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((BATCH,), jnp.int32, sharding=split),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep),
+    )
+
+
+_INSTRUCTION = re.compile(
+    r"^(?:ROOT )?%\S+ = (?P<dtype>\w+)\[(?P<dims>[\d,]*)\]\S* "
+    r"(?P<opcode>[\w-]+)\(")
+
+
+def _entry_instructions(compiled):
+    """(dtype, element count, opcode, line) of each array-valued
+    instruction of the optimised module's entry computation."""
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    out = []
+    for line in map(str.strip, entry.splitlines()):
+        m = _INSTRUCTION.match(line)
+        if m:
+            dims = [int(d) for d in m["dims"].split(",") if d]
+            out.append((m["dtype"], math.prod(dims), m["opcode"], line))
+    return out
+
+
+def _assert_prep_is_cheap_on_the_chip(compiled, image_shape):
+    """The three things that made the loader's crop/flip a seventh of the
+    ResNet-18 step unseen (ledger, PR 25): a relayout of the whole resident
+    data set on every step, per-image gathers over batch-sized arrays, and
+    the reflect pad's reversals."""
+    instructions = _entry_instructions(compiled)
+    assert len(instructions) > 20, "the entry computation was not parsed"
+    per_image = math.prod(image_shape)
+    data_set_copies = [
+        line for _, count, opcode, line in instructions
+        if opcode == "copy" and count == N_IMAGES * per_image]
+    assert not data_set_copies, data_set_copies
+    # one gather may touch the images: the batch's rows out of the resident
+    # set. (The step's other kCustom fusions gather B labels and B logits.)
+    batch_gathers = [
+        (dtype, line) for dtype, count, opcode, line in instructions
+        if opcode == "fusion" and "kind=kCustom" in line
+        and count >= BATCH * per_image]
+    assert len(batch_gathers) <= 1, batch_gathers
+    assert all(dtype == "u8" for dtype, _ in batch_gathers), batch_gathers
+    reversals = [line for *_, opcode, line in instructions
+                 if opcode == "reverse"]
+    assert not reversals, reversals
+
+
+def test_device_loader_prep_compiles_lean_for_v5e(one_chip_mesh, cifar_prep):
+    import jax
+
+    prep, held_shape, image_shape = cifar_prep
+    compiled = jax.jit(prep).lower(
+        *_loader_args(one_chip_mesh, held_shape)).compile()
+    _assert_prep_is_cheap_on_the_chip(compiled, image_shape)
+    # and nothing data-set-sized among its temporaries (the parent's padded
+    # copy was 614 MB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 300e6
+
+
+def test_fused_resnet18_step_compiles_lean_for_v5e(one_chip_mesh, cifar_prep):
+    """The step the resnet18_b4096 cells run, built as Trainer builds it:
+    the loader's prep inlined into the bf16 ResNet-18 SGD step."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pytorch_distributed_nn_tpu.models import build_model
+    from pytorch_distributed_nn_tpu.optim import build_optimizer
+    from pytorch_distributed_nn_tpu.parallel import make_grad_sync
+    from pytorch_distributed_nn_tpu.training.train_step import (
+        build_train_step,
+        create_train_state,
+    )
+
+    prep, held_shape, image_shape = cifar_prep
+    mesh = one_chip_mesh
+    rep = NamedSharding(mesh, P())
+    model = build_model("ResNet18", 10, dtype=jnp.bfloat16)
+    optimizer = build_optimizer("sgd", 0.1, momentum=0.9)
+    sync = make_grad_sync("allreduce")
+    state = jax.eval_shape(lambda: create_train_state(
+        model, optimizer, sync, jax.random.PRNGKey(0), image_shape))
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep), state)
+    inner = build_train_step(model, optimizer, sync, mesh, donate=False)
+    fused = jax.jit(
+        lambda st, images, labels, idx, key, rng: inner(
+            st, prep(images, labels, idx, key), rng),
+        donate_argnums=(0,))
+    images, labels, idx, key = _loader_args(mesh, held_shape)
+    compiled = fused.lower(state, images, labels, idx, key, key).compile()
+    _assert_prep_is_cheap_on_the_chip(compiled, image_shape)

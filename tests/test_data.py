@@ -384,3 +384,162 @@ def test_device_loader_epochs_and_sharding():
         assert x.shape == (32, 28, 28, 1)
     # output is sharded over the mesh's data axis
     assert "data" in str(x.sharding.spec)
+
+
+# --- DeviceDataLoader.prep: bit identity with the prep it replaced ---------
+
+
+def _parent_prep(dataset, draws=None):
+    """The plain reference: ``DeviceDataLoader.prep`` as it stood before it
+    selected by index (PR 29's parent, loader.py:305-330), frozen here. It
+    gathers float32 pixels, reflect-pads the batch, crops with two
+    ``take_along_axis`` gathers and flips with a ``where`` over the
+    reversed copy. ``draws(idx, key) -> (dy, dx, flip)`` stands in for its
+    three PRNG draws where a test has to force them."""
+    import jax
+    import jax.numpy as jnp
+
+    mean = jnp.asarray(dataset.mean, jnp.float32) * 255.0
+    std = jnp.asarray(dataset.std, jnp.float32) * 255.0
+    augment = dataset.augment
+    H, W = dataset.raw_images.shape[1:3]
+
+    def prep(images, labels, idx, key):
+        x = images[idx].astype(jnp.float32)  # (B,H,W,C) device gather
+        y = labels[idx]
+        if augment:
+            kc1, kc2, kf = jax.random.split(key, 3)
+            padded = jnp.pad(
+                x, ((0, 0), (4, 4), (4, 4), (0, 0)), mode="reflect"
+            )
+            dy = jax.random.randint(kc1, (idx.shape[0],), 0, 9)
+            dx = jax.random.randint(kc2, (idx.shape[0],), 0, 9)
+            flip = jax.random.bernoulli(kf, 0.5, (idx.shape[0],))
+            if draws is not None:
+                dy, dx, flip = draws(idx, key)
+            ii = dy[:, None] + jnp.arange(H)  # (B, H)
+            jj = dx[:, None] + jnp.arange(W)  # (B, W)
+            x = jnp.take_along_axis(
+                padded, ii[:, :, None, None], axis=1
+            )  # (B, H, W+8, C)
+            x = jnp.take_along_axis(
+                x, jj[:, None, :, None], axis=2
+            )  # (B, H, W, C)
+            x = jnp.where(flip[:, None, None, None], x[:, :, ::-1, :], x)
+        x = (x - mean) / std
+        return x, y
+
+    return jax.jit(prep)
+
+
+def _assert_prep_is_the_parents(ds, batch, mesh, draws=None, seeds=(0, 11)):
+    import jax
+
+    from pytorch_distributed_nn_tpu.data.loader import DeviceDataLoader
+
+    loader = DeviceDataLoader(ds, batch, mesh, shuffle=True, seed=5)
+    reference = _parent_prep(ds, draws)
+    labels = ds.labels.astype(np.int32)
+    rng = np.random.RandomState(batch)
+    for seed in seeds:
+        idx = rng.randint(0, len(ds), size=batch)
+        idx[-1] = idx[0]  # an index that repeats
+        loader._key = jax.random.PRNGKey(seed)
+        idx_dev, key = loader._idx_key(idx)
+        x, y = loader._prep(loader.images, loader.labels, idx_dev, key)
+        xr, yr = reference(ds.raw_images, labels, idx.astype(np.int32), key)
+        assert x.dtype == np.float32 and x.shape == xr.shape
+        assert np.array_equal(np.asarray(x), np.asarray(xr))
+        assert np.array_equal(np.asarray(y), np.asarray(yr))
+    return loader, x
+
+
+@pytest.mark.parametrize("batch", [1, 7, 64])
+@pytest.mark.parametrize("name", ["Cifar10", "Cifar100", "SVHN", "MNIST"])
+def test_device_prep_bit_identical_to_parent(name, batch):
+    from pytorch_distributed_nn_tpu.parallel import make_mesh
+
+    ds = load_dataset(name, train=True, synthetic_size=96)
+    assert ds.augment == (name != "MNIST")
+    _assert_prep_is_the_parents(ds, batch, make_mesh(1))
+
+
+@pytest.mark.parametrize("name", ["Cifar10", "Cifar100", "SVHN", "MNIST"])
+def test_device_prep_bit_identical_to_parent_sharded_idx(name):
+    """Two devices on the data axis: idx sharded, the data set replicated,
+    the batch sharded as it comes out."""
+    from pytorch_distributed_nn_tpu.parallel import make_mesh
+
+    ds = load_dataset(name, train=True, synthetic_size=96)
+    _, x = _assert_prep_is_the_parents(ds, 64, make_mesh(2))
+    assert "data" in str(x.sharding.spec)
+    assert len(x.sharding.device_set) == 2
+
+
+def test_device_prep_bit_identical_at_every_corner(monkeypatch):
+    """Crops at every corner, edge and the centre of the padded image
+    (dy, dx in {0, 4, 8}), each flipped and not: the reflect pad and the
+    flip are folded into the source indices, and this is where a fold that
+    is off by one shows."""
+    import jax.numpy as jnp
+
+    from pytorch_distributed_nn_tpu.data import loader as loader_mod
+    from pytorch_distributed_nn_tpu.parallel import make_mesh
+
+    corners = [(dy, dx, f) for dy in (0, 4, 8) for dx in (0, 4, 8)
+               for f in (False, True)]
+    dy, dx, flip = (jnp.asarray(c) for c in zip(*corners))
+    monkeypatch.setattr(
+        loader_mod, "crop_flip_draws", lambda key, batch: (dy, dx, flip))
+    ds = load_dataset("Cifar10", train=True, synthetic_size=96)
+    _assert_prep_is_the_parents(
+        ds, len(corners), make_mesh(1), draws=lambda idx, key: (dy, dx, flip))
+
+
+def test_device_prep_is_the_host_transform_pixel_for_pixel():
+    """The loader's own draws fed to the host path's numpy transform give
+    the loader's batch exactly: one transform, two implementations."""
+    import jax
+
+    from pytorch_distributed_nn_tpu.data.datasets import _augment_numpy
+    from pytorch_distributed_nn_tpu.data.loader import (
+        DeviceDataLoader,
+        crop_flip_draws,
+    )
+    from pytorch_distributed_nn_tpu.parallel import make_mesh
+
+    ds = load_dataset("SVHN", train=True, synthetic_size=96)
+    loader = DeviceDataLoader(ds, 32, make_mesh(1), seed=2)
+    idx = loader._next_idx()
+    idx_dev, key = loader._idx_key(idx)
+    x, _ = loader._prep(loader.images, loader.labels, idx_dev, key)
+    dy, dx, flip = (np.asarray(d) for d in crop_flip_draws(key, len(idx)))
+    assert len({*dy}) > 1 and len({*dx}) > 1 and 0 < flip.sum() < len(idx)
+    moved = _augment_numpy(
+        ds.raw_images[idx].astype(np.float32), dy, dx, flip)
+    # the same float32 normalisation, compiled as the loader's is (XLA
+    # folds a division by a constant; numpy's differs in the last bit)
+    mean = np.asarray(ds.mean, np.float32) * np.float32(255.0)
+    std = np.asarray(ds.std, np.float32) * np.float32(255.0)
+    normalise = jax.jit(lambda m: (m - mean) / std)
+    assert np.array_equal(np.asarray(x), np.asarray(normalise(moved)))
+
+
+def test_device_prep_fn_takes_the_nhwc_data_set_too():
+    """``.images`` holds flat rows; ``prep_fn`` also lowers from the
+    (N, H, W, C) uint8 array (benchmark/compile_for_chip.py hands it one)."""
+    import jax
+
+    from pytorch_distributed_nn_tpu.data.loader import DeviceDataLoader
+    from pytorch_distributed_nn_tpu.parallel import make_mesh
+
+    ds = load_dataset("Cifar10", train=True, synthetic_size=96)
+    loader = DeviceDataLoader(ds, 16, make_mesh(1), seed=1)
+    assert loader.images.shape == (96, 32 * 32 * 3)
+    assert loader.images.dtype == np.uint8
+    idx_dev, key = loader._idx_key(loader._next_idx())
+    prep = jax.jit(loader.prep_fn)
+    flat = prep(loader.images, loader.labels, idx_dev, key)
+    nhwc = prep(ds.raw_images, loader.labels, idx_dev, key)
+    assert np.array_equal(np.asarray(flat[0]), np.asarray(nhwc[0]))
+    assert np.array_equal(np.asarray(flat[1]), np.asarray(nhwc[1]))
