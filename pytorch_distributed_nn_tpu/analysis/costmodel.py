@@ -332,8 +332,8 @@ class DecodeCost:
     cache, against a few FLOPs per weight — arithmetic intensity of
     O(batch) FLOP/byte, far left of any ridge point. The model here is
     the planning twin of :class:`StepCost`: closed-form from the decoder
-    config, checkable against measured tokens/s
-    (``bench.py --only decode``, PERF.md round 13).
+    config. No benchmark cell serves a decoder yet, so its prediction has
+    not been checked against tokens/s measured on the chip.
     """
 
     flops_per_token: float          # matmul + attention FLOPs, one token

@@ -157,7 +157,8 @@ def _add_common_train_flags(p: argparse.ArgumentParser):
                         "models; MLM uses --remat)")
     p.add_argument("--profile", type=int, default=0, metavar="N",
                    help="trace N training steps with jax.profiler "
-                        "(summarize with tools/xplane_summary.py)")
+                        "(summarize with python -m pytorch_distributed_"
+                        "nn_tpu.observability.xplane <profile-dir>)")
     p.add_argument("--profile-dir", default=None,
                    help="trace output dir (default: <train-dir>/profile)")
     p.add_argument("--faults", default=None, metavar="SPEC",
@@ -1221,9 +1222,9 @@ def _decode_cost_block(args, model_name):
     """The decode-phase roofline of ``analyze --cost`` for causal
     decoders (docs/analysis.md "Decode roofline"): per-token FLOPs +
     KV-cache HBM bytes from the closed-form model, plus the calibrated
-    backend's predicted tokens/s — the number ``bench.py --only
-    decode`` checks against measurement. Returns the dict (for --json)
-    or None for non-generative models."""
+    backend's predicted tokens/s (a prediction: no benchmark cell
+    measures decode yet). Returns the dict (for --json) or None for
+    non-generative models."""
     from pytorch_distributed_nn_tpu.models import (
         build_model,
         is_generative_model,

@@ -327,9 +327,9 @@ class StreamingLoader:
       stream — epochs only mark shard-order reshuffles.
 
     ``prefetch=0`` runs everything synchronously on the caller's thread
-    (the "cold" configuration ``bench.py --only input_stall`` measures);
-    ``prefetch>0`` starts the reader/worker/output pipeline and keeps up
-    to ``prefetch`` ready (device-put) batches ahead of the trainer.
+    (the "cold" configuration); ``prefetch>0`` starts the
+    reader/worker/output pipeline and keeps up to ``prefetch`` ready
+    (device-put) batches ahead of the trainer.
     """
 
     def __init__(

@@ -13,9 +13,10 @@ layer grown up on top of everything the repo already has:
   folded back into per-trial state for ``--resume``.
 - :mod:`.scheduler` — full-grid baseline plus an ASHA-style successive-
   halving rung scheduler; promotions are pure functions of the journal.
-- :mod:`.runner`    — N trials as spawned subprocesses (the bench.py
-  isolation pattern) under a bounded worker pool, per-trial timeout +
-  retry-with-backoff, every trial a ``--supervise``-style telemetry run.
+- :mod:`.runner`    — N trials as spawned subprocesses (one process per
+  trial, never a shared interpreter) under a bounded worker pool,
+  per-trial timeout + retry-with-backoff, every trial a
+  ``--supervise``-style telemetry run.
 - :mod:`.report`    — ranked leaderboard (trailing loss / step rate / MFU
   pulled from the trial telemetry streams, never from logs).
 

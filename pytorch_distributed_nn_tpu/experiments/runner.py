@@ -2,7 +2,7 @@
 
 Execution model (docs/experiments.md):
 
-- **Subprocess isolation** (the bench.py lesson): every trial attempt runs
+- **Subprocess isolation**: every trial attempt runs
   in a freshly SPAWNED process — three Trainers sharing one interpreter
   contaminate each other's allocator/GC behavior, and a diverged trial
   must never poison its siblings' runtime. The parent never initializes a

@@ -25,8 +25,8 @@ straggler dicts, xplane traces, bare ``logger.info`` lines):
 - ``slo``        — SLO objectives: spec grammar, multi-window burn-rate
                    evaluation over the live bus AND offline streams,
                    error-budget gauges, edge-triggered breach events.
-- ``xplane``     — device-trace summarization (the promoted
-                   tools/xplane_summary.py) + incident report generation.
+- ``xplane``     — the per-op device-time table of a trace (``python -m
+                   ...observability.xplane``) + incident report generation.
 - ``obs_cli``    — the ``cli obs`` command family: summary / tail /
                    compare [--by-version] / trace / slo / export /
                    incidents (+ ``summary --selftest`` and

@@ -60,8 +60,7 @@ logger = logging.getLogger(__name__)
 INCIDENT_DIRNAME = "incidents"
 
 #: environment variables captured into env.json (prefix match)
-_ENV_PREFIXES = ("XLA_", "JAX_", "TPU_", "LIBTPU_", "TF_", "CUDA_",
-                 "PROTOCOL_BUFFERS_")
+_ENV_PREFIXES = ("XLA_", "JAX_", "TPU_", "LIBTPU_", "TF_", "CUDA_")
 
 
 def incidents_dir(train_dir: str) -> str:
@@ -286,8 +285,8 @@ class FlightRecorder:
         cap, self._capture = self._capture, None
         if cap is None:
             return
-        # cooldown opens BEFORE any slow finalization below: the report
-        # generator's first run imports the xplane protos (seconds), and a
+        # cooldown opens BEFORE any slow finalization below: stopping the
+        # trace and parsing it for the report can take seconds, and a
         # watchdog stall convicted during that window must land in the
         # cooldown, not open a fresh capture of our own report generation
         self._cooldown_until = step + self.spec.cooldown

@@ -28,10 +28,6 @@ analysis/*.ipynb) for good:
   orphan spans flagged (``reader.assemble_trace``). ``<id>`` is a
   request id or a 32-hex trace id. ``--selftest`` verifies the
   assembly invariants on a synthetic frontend run.
-- ``obs bench-trend [--dir D]`` — fold the repo's ``BENCH_r*.json``
-  round journals into per-section metric trajectories, flagging moves
-  against the prior round; partial/failed rounds (probe timeouts,
-  backend init errors) summarize instead of erroring. Always exits 0.
 - ``obs slo status|check <run> --slo SPEC`` — multi-window burn-rate
   evaluation of a stream against an SLO spec (observability/slo.py);
   ``check`` exits 1 on any breach — the canary/CI surface, like
@@ -224,185 +220,6 @@ def cmd_trace(args) -> int:
         print(tracing.render_trace(entries[0]["record"]))
         return 0
     print(tracing.render_assembled_trace(asm))
-    return 0
-
-
-def _recover_bench_sections(tail: str) -> dict:
-    """Best-effort section recovery from a TORN bench tail: the result
-    line can be longer than the journal's tail window, so its head
-    (``{"metric": ...``) is often cut off while whole per-section
-    objects survive. Scan for ``"name": {...}`` fragments with balanced
-    braces and parse each independently — partial data beats none in a
-    trend table."""
-    import re
-
-    out = {}
-    pos = 0
-    for m in re.finditer(r'"([A-Za-z0-9_]+)":\s*\{', tail):
-        if m.start() < pos:
-            continue  # inside a fragment already consumed
-        start = m.end() - 1
-        depth = 0
-        end = -1
-        for i in range(start, len(tail)):
-            if tail[i] == "{":
-                depth += 1
-            elif tail[i] == "}":
-                depth -= 1
-                if depth == 0:
-                    end = i + 1
-                    break
-        if end < 0:
-            continue
-        try:
-            obj = json.loads(tail[start:end])
-        except ValueError:
-            continue
-        if isinstance(obj, dict) and obj:
-            out[m.group(1)] = obj
-            pos = end
-    return out
-
-
-def cmd_bench_trend(args) -> int:
-    """Fold the repo's ``BENCH_r*.json`` round journals into one
-    per-section trajectory table. Diagnostic, not a gate: partial and
-    failed rounds are summarized (probe timeouts, backend init
-    failures), never a nonzero exit."""
-    paths = sorted(
-        __import__("glob").glob(os.path.join(args.dir, "BENCH_r*.json"))
-    )
-    if not paths:
-        print(f"obs: no BENCH_r*.json under {args.dir}")
-        return 0
-    rounds = []
-    for p in paths:
-        name = os.path.basename(p)[len("BENCH_"):-len(".json")]
-        entry = {"round": name, "rc": None, "outcome": "unreadable",
-                 "parsed": None}
-        try:
-            with open(p) as f:
-                doc = json.load(f)
-        except (ValueError, OSError) as e:
-            entry["outcome"] = f"unreadable ({e})"
-            rounds.append(entry)
-            continue
-        entry["rc"] = doc.get("rc")
-        tail = doc.get("tail") or ""
-        parsed = doc.get("parsed")
-        if parsed is None:
-            # a round can exit 0 with the result line buried in the
-            # tail (harness missed it): recover the last JSON line
-            for line in reversed(tail.splitlines()):
-                line = line.strip()
-                if line.startswith("{"):
-                    try:
-                        parsed = json.loads(line)
-                        break
-                    except ValueError:
-                        continue
-        recovered = False
-        if not isinstance(parsed, dict):
-            # the result line was longer than the tail window: its head
-            # is gone, but whole sections usually survive — fold what
-            # parses
-            sections = _recover_bench_sections(tail)
-            parsed = {"extra": sections} if sections else None
-            recovered = bool(sections)
-        entry["parsed"] = parsed if isinstance(parsed, dict) else None
-        if "accelerator backend unavailable" in tail \
-                or "probe timed out" in tail:
-            entry["outcome"] = "probe-timeout"
-        elif "Unable to initialize backend" in tail:
-            entry["outcome"] = "backend-init-failed"
-        elif recovered:
-            entry["outcome"] = f"partial (rc={doc.get('rc')})"
-        elif entry["parsed"] is not None:
-            entry["outcome"] = "ok" if doc.get("rc") == 0 else (
-                f"ok-but-rc={doc.get('rc')}"
-            )
-        else:
-            entry["outcome"] = f"no-result (rc={doc.get('rc')})"
-        rounds.append(entry)
-
-    print(f"bench trend over {len(rounds)} round(s) under {args.dir}:")
-    print(f"  {'round':<6} {'rc':>3}  {'outcome':<20} "
-          f"{'headline':<42} {'vs_baseline':>11}")
-    for r in rounds:
-        parsed = r["parsed"] or {}
-        head = "-"
-        if parsed.get("metric") is not None:
-            head = (f"{parsed['metric']} = {parsed.get('value')} "
-                    f"{parsed.get('unit') or ''}").strip()
-        vsb = parsed.get("vs_baseline")
-        print(f"  {r['round']:<6} "
-              f"{r['rc'] if r['rc'] is not None else '-':>3}  "
-              f"{r['outcome']:<20} {head:<42} "
-              f"{vsb if vsb is not None else '-':>11}")
-
-    # per-section metric trajectories: flatten each round's extra block
-    # to dotted scalar keys, then one row per metric across rounds
-    def flatten(obj, prefix="", depth=0, out=None):
-        if out is None:
-            out = {}
-        if isinstance(obj, dict) and depth < 3:
-            for k, v in obj.items():
-                key = f"{prefix}.{k}" if prefix else str(k)
-                flatten(v, key, depth + 1, out)
-        elif isinstance(obj, (int, float)) and not isinstance(obj, bool):
-            out[prefix] = float(obj)
-        return out
-
-    flat = {
-        r["round"]: flatten((r["parsed"] or {}).get("extra") or {})
-        for r in rounds
-    }
-    names = sorted({k for d in flat.values() for k in d})
-    if not names:
-        print("  (no round carries a per-section extra block)")
-        return 0
-    cols = [r["round"] for r in rounds]
-    regressions = 0
-    by_section = {}
-    for name in names:
-        by_section.setdefault(name.split(".", 1)[0], []).append(name)
-    for section in sorted(by_section):
-        print(f"  section {section}:")
-        print("    " + f"{'metric':<34}"
-              + "".join(f"{c:>12}" for c in cols))
-        for name in by_section[section]:
-            vals = [flat[c].get(name) for c in cols]
-            cells, prev, flagged = [], None, False
-            # direction heuristic: throughput-like names regress when
-            # they DROP, latency-like when they RISE; ambiguous names
-            # are shown but never flagged
-            low = name.lower()
-            direction = None
-            if any(t in low for t in ("per_sec", "per_s", "speedup")):
-                direction = "higher"
-            elif low.endswith("_ms") or "ms_" in low.rsplit(".", 1)[-1]:
-                direction = "lower"
-            for v in vals:
-                if v is None:
-                    cells.append(f"{'-':>12}")
-                    continue
-                mark = ""
-                if prev is not None and direction is not None and prev:
-                    delta = v / prev - 1.0
-                    worse = (delta < -args.threshold
-                             if direction == "higher"
-                             else delta > args.threshold)
-                    if worse:
-                        mark = "!"
-                        flagged = True
-                cells.append(f"{v:>11g}{mark or ' '}")
-                prev = v
-            short = name.split(".", 1)[1] if "." in name else name
-            print(f"    {short:<34}" + "".join(cells))
-            regressions += flagged
-    if regressions:
-        print(f"  {regressions} metric(s) regressed >"
-              f"{args.threshold * 100:.0f}% vs their prior round (!)")
     return 0
 
 
@@ -970,18 +787,6 @@ def main_obs(argv=None) -> int:
                           "a synthetic frontend+2-replica run (hedge, "
                           "retry, skewed clock, planted orphan; <5 s)")
     ptr.set_defaults(fn=cmd_trace)
-
-    pbt = sub.add_parser(
-        "bench-trend",
-        help="fold BENCH_r*.json round journals into per-section "
-             "metric trajectories (diagnostic; always exits 0)",
-    )
-    pbt.add_argument("--dir", default=".",
-                     help="directory holding BENCH_r*.json (default .)")
-    pbt.add_argument("--threshold", type=float, default=0.1,
-                     help="fractional move vs the prior round that "
-                          "flags a metric (default 0.1 = 10%%)")
-    pbt.set_defaults(fn=cmd_bench_trend)
 
     psl = sub.add_parser(
         "slo",

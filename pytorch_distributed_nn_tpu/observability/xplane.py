@@ -1,23 +1,19 @@
-"""Device-trace (xplane) summarization: the promoted tools/xplane_summary.
+"""The operator's view of a device trace: the per-op table and the flight
+recorder's incident report.
 
-One implementation now serves three consumers (the copy-paste risk the
-promotion kills):
-
-- the CLI tool — ``python tools/xplane_summary.py <trace_dir>`` is a
-  back-compat shim over :func:`main` here;
+- the command — ``python -m pytorch_distributed_nn_tpu.observability.xplane
+  <trace_dir>`` prints the per-op and per-family device-time table of a
+  ``--profile N`` capture or an incident bundle's ``trace/``;
 - the flight recorder — ``write_incident_report`` turns a just-captured
   incident bundle (``observability/flightrec.py``) into ``report.md``:
   trigger summary, per-op device-time table from the bundle's trace,
-  event-ring tail, environment pointer;
-- library callers — the parsing core stays in ``utils/profiling``
-  (``summarize_xplane`` / ``format_summary`` / ``device_step_time_ms`` /
-  ``collective_overlap_report``) and is re-exported here so
-  ``observability`` consumers need one import.
+  event-ring tail, environment pointer.
 
-The xplane proto bindings ship inside TensorFlow on this image; every
-entry point degrades gracefully (a report is still written, marking the
-trace section unavailable) when they are absent or the trace has no
-device planes (CPU-only captures).
+The trace itself is opened in ``utils/profiling`` (``summarize_xplane``).
+Every entry point degrades gracefully (a report is still written, marking
+the trace section unavailable) when the trace cannot be read or has no
+device planes (CPU-only captures). How fast the system is is not read
+here: that is ``python3 -m benchmark.run``.
 """
 
 from __future__ import annotations
@@ -29,43 +25,19 @@ import sys
 import time
 from typing import Optional
 
-# TF's generated protos on this image predate the installed protobuf's
-# C++ fast-path; the pure-python implementation parses them fine. Must be
-# set before the first TF proto import (utils/profiling._load_xplane).
-os.environ.setdefault("PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION", "python")
-
-from pytorch_distributed_nn_tpu.utils.profiling import (  # noqa: E402
-    FAMILIES,
-    collective_overlap_report,
-    device_step_time_ms,
+from pytorch_distributed_nn_tpu.utils.profiling import (
+    _find_xplane,
     family_summary,
     format_family_summary,
     format_summary,
-    op_family,
     summarize_xplane,
 )
 
-__all__ = [
-    "FAMILIES",
-    "collective_overlap_report",
-    "device_step_time_ms",
-    "family_summary",
-    "format_family_summary",
-    "format_summary",
-    "op_family",
-    "summarize_xplane",
-    "trace_summary_text",
-    "render_incident_report",
-    "write_incident_report",
-    "main",
-]
-
-
-#: inline-report parse ceiling: this image's protobuf runs the pure-python
-#: implementation, which chews ~minutes per 50 MB — a host-heavy CPU trace
-#: can exceed that easily, and the recorder's background report thread must
-#: not burn minutes of the training host's CPU. The CLI (`main`) has no cap:
-#: an explicit invocation is the user's own time.
+#: inline-report ceiling: the whole file is parsed into memory, a
+#: host-heavy CPU trace can be far larger than this, and the recorder's
+#: background report thread must not take that from the training host. The
+#: command (`main`) has no cap: an explicit invocation is the user's own
+#: time.
 REPORT_MAX_TRACE_BYTES = 48 << 20
 
 
@@ -84,19 +56,15 @@ def trace_summary_text(trace_dir: str, top: int = 30, collapse: bool = True,
     the SAME ``op_family`` the cost model uses."""
     if max_bytes is not None:
         try:
-            from pytorch_distributed_nn_tpu.utils.profiling import (
-                _find_xplane,
-            )
-
             size = os.path.getsize(_find_xplane(trace_dir))
         except Exception as e:
             return f"(trace summary unavailable: {e})"
         if size > max_bytes:
             return (
                 f"(trace is {size / 1e6:.0f} MB — past the inline "
-                "summary ceiling for the pure-python proto parser; run "
-                f"`python tools/xplane_summary.py {trace_dir}` or open "
-                "it with TensorBoard)"
+                "summary ceiling; run `python -m pytorch_distributed_nn_tpu"
+                f".observability.xplane {trace_dir}` or open it with "
+                "TensorBoard)"
             )
     try:
         summary = summarize_xplane(trace_dir, top=top, collapse=collapse)
@@ -253,7 +221,7 @@ def write_incident_report(bundle_dir: str,
 
 
 # ---------------------------------------------------------------------------
-# CLI (tools/xplane_summary.py is a shim over this)
+# CLI
 # ---------------------------------------------------------------------------
 
 
@@ -264,18 +232,15 @@ def main(argv=None) -> int:
     `jax.profiler.trace`), or an incident bundle's `trace/`; the tool
     finds the newest plugins/profile/*/*.xplane.pb under it. `--full`
     keeps full op names instead of collapsing fusions into families.
+    Device time per step, idle share and exposed collective time are the
+    benchmark's (`python3 -m benchmark.run ... --trace 1`), not this
+    table's.
     """
     p = argparse.ArgumentParser(description=main.__doc__)
     p.add_argument("trace_dir")
     p.add_argument("--full", action="store_true",
                    help="full op names (no fusion-family collapsing)")
     p.add_argument("--top", type=int, default=30)
-    p.add_argument("--steps", type=int, default=None,
-                   help="if given, also print device ms/step = total/steps")
-    p.add_argument("--overlap", action="store_true",
-                   help="report collective/compute overlap (grad-sync "
-                        "cost hidden under backward; meaningful on "
-                        "multi-chip traces)")
     args = p.parse_args(argv)
 
     summary = summarize_xplane(
@@ -287,15 +252,6 @@ def main(argv=None) -> int:
     print(format_summary(summary))
     print("\nper family:")
     print(format_family_summary(family_summary(summary)))
-    if args.steps:
-        total = sum(
-            o.total_ms for ops in summary.values() for o in ops
-        ) / len(summary)
-        print(f"\ndevice time: {total / args.steps:.2f} ms/step "
-              f"over {args.steps} steps")
-    if args.overlap:
-        print("\ncollective/compute overlap:",
-              collective_overlap_report(args.trace_dir))
     return 0
 
 

@@ -21,9 +21,8 @@ padded-bucket family, all pre-traced at :meth:`GenerativeEngine.warmup`:
   never invalidate a trace.
 
 ``retraces()`` counts executables across all three families; the test
-suite, ``bench.py --only decode`` and the chaos ``generate`` scenario
-assert it stays 0 across mixed prompt lengths, generation lengths and
-hot swaps.
+suite and the chaos ``generate`` scenario assert it stays 0 across mixed
+prompt lengths, generation lengths and hot swaps.
 
 Hot swap (docs/serving.md "Generative serving"): :meth:`swap` installs
 new weights like the single-pass engine — but a decoder also carries

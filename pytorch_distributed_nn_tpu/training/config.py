@@ -140,8 +140,7 @@ class TrainConfig:
     # None keeps the in-memory loaders. Eval/test data stays in-memory.
     data_path: Optional[str] = None
     # Streaming loader: depth of the ready-batch prefetch queue.
-    # 0 = fully synchronous reads on the step loop (the "cold" path
-    # bench.py --only input_stall measures).
+    # 0 = fully synchronous reads on the step loop (the "cold" path).
     stream_prefetch: int = 2
     data_dir: str = "./data"
     synthetic_size: Optional[int] = None  # force synthetic data of this size

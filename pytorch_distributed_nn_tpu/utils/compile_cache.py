@@ -10,8 +10,8 @@ cache unless the variable asks for one — they must not start filling the
 checkout unasked.
 
 Called once by every entry point whose process takes the device:
-``cli`` (train / single / evaluator / serve), ``bench.py``, the sweep
-trial child and ``chip_smoke.py``.
+``cli`` (train / single / evaluator / serve), the sweep trial child and
+``chip_smoke.py``.
 """
 
 from __future__ import annotations

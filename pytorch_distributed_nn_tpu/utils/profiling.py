@@ -1,15 +1,19 @@
-"""jax.profiler trace capture + offline XLA-op summarization.
+"""Offline summary of a ``jax.profiler`` device trace: the operator's table.
 
 The reference's only "profiler" was wall-clock phase logging inside the
 worker loop (reference: src/distributed_worker.py:146-173) consumed by
-regex in notebooks. Here profiling is first-class: `trace_steps` wraps a
-span of training steps in `jax.profiler.trace` (viewable in TensorBoard /
-Perfetto), and `summarize_xplane` parses the captured `.xplane.pb` device
-trace into a per-op time table — the tool that produced the roofline
-analysis in PERF.md — without needing a TensorBoard server.
+regex in notebooks. Here a captured ``.xplane.pb`` (``--profile N``, or a
+flight-recorder incident bundle) is reduced by ``summarize_xplane`` to a
+per-op / per-family device-time table, without a TensorBoard server:
+``python -m pytorch_distributed_nn_tpu.observability.xplane <trace_dir>``,
+an incident's ``report.md``, and ``analysis/calibration.fit_from_trace``.
 
-The xplane proto bindings ship inside TensorFlow on this image; the parser
-degrades gracefully (raises with a clear message) when they are absent.
+This is the one module of the package that opens a trace, with
+``jax.profiler.ProfileData`` — nothing but jax, imported only when a trace
+is opened. It defines no rate and no per-step time: how fast the system is
+comes from ``python3 -m benchmark.run``, whose own reader (``benchmark/``,
+independent on purpose) the fixture test in ``tests/test_tools.py`` pins
+this one against.
 """
 
 from __future__ import annotations
@@ -17,18 +21,8 @@ from __future__ import annotations
 import collections
 import glob
 import os
-from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import Dict, List, Optional
-
-
-@contextmanager
-def trace_span(log_dir: str):
-    """Context manager: capture a jax.profiler trace into ``log_dir``."""
-    import jax
-
-    with jax.profiler.trace(log_dir):
-        yield
 
 
 @dataclass
@@ -152,6 +146,11 @@ def format_family_summary(
 
 
 def _find_xplane(trace_dir: str) -> str:
+    """The newest trace under ``trace_dir`` (the directory given to
+    ``--profile-dir`` / ``jax.profiler.trace``), or ``trace_dir`` itself
+    when it already is a trace file."""
+    if os.path.isfile(trace_dir):
+        return trace_dir
     paths = sorted(
         glob.glob(os.path.join(trace_dir, "plugins/profile/*/*.xplane.pb"))
     )
@@ -164,18 +163,14 @@ def _find_xplane(trace_dir: str) -> str:
 
 
 def _load_xplane(path: str):
-    try:
-        from tensorflow.tsl.profiler.protobuf import xplane_pb2  # type: ignore
-    except Exception as e:  # pragma: no cover - depends on image contents
-        raise ImportError(
-            "xplane proto bindings unavailable (need tensorflow's "
-            "tsl.profiler protos to parse device traces); view the trace "
-            "with TensorBoard instead"
-        ) from e
-    xs = xplane_pb2.XSpace()
-    with open(path, "rb") as f:
-        xs.ParseFromString(f.read())
-    return xs
+    """``jax.profiler.ProfileData`` of an ``.xplane.pb`` as the profiler
+    wrote it, or of a ``.textproto`` of the same message."""
+    from jax.profiler import ProfileData
+
+    if path.endswith(".textproto"):
+        with open(path) as f:
+            return ProfileData.from_text_proto(f.read())
+    return ProfileData.from_file(path)
 
 
 def summarize_xplane(
@@ -189,26 +184,20 @@ def summarize_xplane(
     ``collapse=True`` groups ops by family (fusion name prefix before the
     first '.'), which is the right granularity for "where does the step
     go"; ``collapse=False`` keeps full op names.
-
-    NOTE: protobuf on this image needs
-    PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION=python to load TF's generated
-    protos; tools/xplane_summary.py sets it before importing.
     """
-    xs = _load_xplane(_find_xplane(trace_dir))
+    data = _load_xplane(_find_xplane(trace_dir))
     out: Dict[str, List[OpTime]] = {}
-    for plane in xs.planes:
+    for plane in data.planes:
         if "TPU" not in plane.name and "GPU" not in plane.name:
             continue
-        ev_meta = plane.event_metadata
         tot: collections.Counter = collections.Counter()
         cnt: collections.Counter = collections.Counter()
         for line in plane.lines:
             if line.name != "XLA Ops":
                 continue
             for ev in line.events:
-                name = ev_meta[ev.metadata_id].name
-                key = name.split(".")[0] if collapse else name
-                tot[key] += ev.duration_ps / 1e9  # ms
+                key = ev.name.split(".")[0] if collapse else ev.name
+                tot[key] += ev.duration_ns / 1e6  # ms
                 cnt[key] += 1
         if not tot:
             continue
@@ -217,10 +206,9 @@ def summarize_xplane(
             OpTime(name=k, total_ms=v, count=cnt[k], pct=100.0 * v / total)
             for k, v in tot.most_common(top)
         ]
-        # Truncation must not silently drop device time: a `--full --top N`
-        # table whose rows summed to a fraction of the real total would
-        # make "device ms/step" look better than it is. Fold the tail into
-        # one synthetic row so every consumer's sum equals the true total.
+        # Truncation must not silently drop device time: fold the tail
+        # into one synthetic row so every consumer's sum equals the true
+        # total.
         if len(tot) > top:
             shown = sum(r.total_ms for r in rows)
             shown_n = sum(r.count for r in rows)
@@ -245,116 +233,3 @@ def format_summary(summary: Dict[str, List[OpTime]]) -> str:
                 f"{o.name[:110]}"
             )
     return "\n".join(lines)
-
-
-def device_step_time_ms(trace_dir: str, num_steps: int) -> Optional[float]:
-    """Total device op time / num_steps — the dispatch-free step cost.
-
-    Aggregates across ALL device planes: a multi-chip trace has one plane
-    per local device, and the old first-plane-only read under-reported
-    device time by the local chip count. Per-op time within one plane is
-    serial device occupancy, so the cluster-wide figure is the SUM over
-    planes (chips run concurrently but each burns its own device-time).
-    """
-    summary = summarize_xplane(trace_dir, top=10**6)
-    if not summary:
-        return None
-    total = sum(o.total_ms for ops in summary.values() for o in ops)
-    return total / max(num_steps, 1)
-
-
-_COLLECTIVE_MARKERS = (
-    "all-reduce", "reduce-scatter", "all-gather", "collective-permute",
-    "all-to-all",
-)
-
-
-def collective_overlap_report(trace_dir: str) -> Dict[str, float]:
-    """How much collective (grad-sync) time hides under compute.
-
-    The measurement behind the reference's whole split-backward design
-    (reference: src/model_ops/resnet_split.py:365-501 hand-overlapped
-    gradient Isends with backprop): XLA emits async collectives as
-    ``<op>-start`` / ``<op>-done`` pairs; the wall span between a pair is
-    the collective's in-flight window, and every compute op scheduled
-    inside that window is overlap the scheduler found. Returns:
-
-      collective_in_flight_ms — total start→done wall time,
-      overlapped_compute_ms   — compute op time inside those windows,
-      exposed_ms              — in-flight time NOT covered by compute
-                                (the true comm cost of the step),
-      overlap_ratio           — overlapped / in-flight (0 when no async
-                                collectives — e.g. a 1-chip trace).
-
-    Run a pod-slice training step under ``--profile N`` and point this at
-    the train dir's profile directory.
-    """
-    xs = _load_xplane(_find_xplane(trace_dir))
-    report = {
-        "collective_in_flight_ms": 0.0,
-        "overlapped_compute_ms": 0.0,
-        "exposed_ms": 0.0,
-        "overlap_ratio": 0.0,
-    }
-    for plane in xs.planes:
-        if "TPU" not in plane.name:
-            continue
-        ev_meta = plane.event_metadata
-        events = []  # (begin_ps, end_ps, name)
-        for line in plane.lines:
-            if line.name != "XLA Ops":
-                continue
-            for ev in line.events:
-                begin = ev.offset_ps
-                events.append(
-                    (begin, begin + ev.duration_ps,
-                     ev_meta[ev.metadata_id].name)
-                )
-        events.sort()
-        # Pair start/done on the FULL op name modulo the -start/-done
-        # token ("all-reduce-start.2" <-> "all-reduce-done.2"): several
-        # async collectives of the same type are in flight at once under
-        # bucketed grads, so a type-level key would mispair them.
-        starts = {}
-        windows = []  # (start_end, done_begin)
-        for begin, end, name in events:
-            if not any(m in name for m in _COLLECTIVE_MARKERS):
-                continue
-            op = name.split(" ")[0].lstrip("%")
-            if "-start" in op:
-                starts[op.replace("-start", "")] = end
-            elif "-done" in op:
-                key = op.replace("-done", "")
-                if key in starts:
-                    windows.append((starts.pop(key), begin))
-        # Merge in-flight windows into disjoint intervals: compute under
-        # two concurrent collectives must count once, and the sweep stays
-        # linear instead of windows x events.
-        merged = []
-        for w0, w1 in sorted(w for w in windows if w[1] > w[0]):
-            if merged and w0 <= merged[-1][1]:
-                merged[-1][1] = max(merged[-1][1], w1)
-            else:
-                merged.append([w0, w1])
-        in_flight = sum(w1 - w0 for w0, w1 in merged) / 1e9
-        covered = 0.0
-        mi = 0
-        for begin, end, name in events:  # both lists are time-sorted
-            if any(m in name for m in _COLLECTIVE_MARKERS):
-                continue
-            while mi < len(merged) and merged[mi][1] <= begin:
-                mi += 1
-            for w0, w1 in merged[mi:]:
-                if w0 >= end:
-                    break
-                covered += max(min(end, w1) - max(begin, w0), 0)
-        covered /= 1e9
-        report["collective_in_flight_ms"] += in_flight
-        report["overlapped_compute_ms"] += min(covered, in_flight)
-        report["exposed_ms"] += max(in_flight - covered, 0.0)
-    if report["collective_in_flight_ms"] > 0:
-        report["overlap_ratio"] = (
-            report["overlapped_compute_ms"]
-            / report["collective_in_flight_ms"]
-        )
-    return {k: round(v, 3) for k, v in report.items()}

@@ -43,7 +43,7 @@ faulthandler.register(signal.SIGUSR1)
 def main() -> int:
     import logging
 
-    logging.basicConfig(level=logging.INFO)  # surface "Checkpointed" lines
+    logging.basicConfig(level=logging.INFO)  # the test dumps these on failure
     pid = int(sys.argv[1])
     nprocs = int(sys.argv[2])
     port = sys.argv[3]

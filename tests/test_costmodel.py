@@ -9,7 +9,6 @@ the old-stream/new-stream compatibility both directions.
 
 import json
 import os
-from types import SimpleNamespace as NS
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +17,7 @@ import pytest
 
 from pytorch_distributed_nn_tpu.analysis import costmodel
 from pytorch_distributed_nn_tpu.analysis.calibration import (
+    DEFAULT_PROFILES,
     CalibrationProfile,
     TPU_V5E,
     default_profile,
@@ -43,11 +43,6 @@ class TestOpFamily:
         assert f("%copy.4") == "other"
         assert f("all-reduce.5") == "other"
         assert f("%convolution.5") == "other"  # refined by metadata only
-
-    def test_xplane_reexports_same_function(self):
-        from pytorch_distributed_nn_tpu.observability import xplane
-
-        assert xplane.op_family is costmodel.op_family
 
 
 class TestCostWalk:
@@ -189,32 +184,48 @@ class TestCalibration:
             with pytest.raises(ValueError, match="no calibration profile"):
                 default_profile(backend, kind)
 
-    def _xspace(self, op_ms):
-        meta = {i: NS(name=name) for i, (name, _) in enumerate(op_ms)}
-        events = [
-            NS(metadata_id=i, duration_ps=ms * 1e9)
-            for i, (_, ms) in enumerate(op_ms)
-        ]
-        plane = NS(name="/device:TPU:0", event_metadata=meta,
-                   lines=[NS(name="XLA Ops", events=events)])
-        return NS(planes=[plane])
+    def test_v5e_peaks_agree_with_the_benchmarks_table(self):
+        """One decision, two files: the package may not import
+        ``benchmark/`` and a program PR may not edit ``peaks.json``, so
+        this is what keeps the two tables of nominal peaks the same."""
+        with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                               "..", "benchmark", "peaks.json")) as f:
+            peaks = json.load(f)
+        tpu_kinds = {k for k, p in DEFAULT_PROFILES.items()
+                     if p.backend == "tpu"}
+        assert tpu_kinds == set(peaks)
+        for kind in tpu_kinds:
+            prof = default_profile("tpu", kind)
+            assert prof.peak_flops_per_s == peaks[kind]["bf16_flops_per_s"]
+            assert prof.hbm_peak_bytes_per_s == peaks[kind]["hbm_bytes_per_s"]
 
-    def test_fit_from_synthetic_trace_roundtrip(self, monkeypatch, tmp_path):
+    @staticmethod
+    def _write_trace(path, op_ms):
+        """One chip's ``XLA Ops`` line, back to back, as a text XSpace."""
+        events, meta, at = [], [], 0
+        for i, (name, ms) in enumerate(op_ms, start=1):
+            ps = int(ms * 1e9)
+            events.append(f"events {{ metadata_id: {i} offset_ps: {at} "
+                          f"duration_ps: {ps} }}")
+            meta.append(f'event_metadata {{ key: {i} value {{ id: {i} '
+                        f'name: "{name}" }} }}')
+            at += ps
+        with open(path, "w") as f:
+            f.write('planes { id: 1 name: "/device:TPU:0" lines { id: 1 '
+                    'name: "XLA Ops" ' + " ".join(events) + " } "
+                    + " ".join(meta) + " }\n")
+
+    def test_fit_from_synthetic_trace_roundtrip(self, tmp_path):
         """Calibration round-trip from a synthetic xplane trace: fitted
         ceiling == family flops x steps / family device time, persisted
         and reloaded bit-equal."""
-        from pytorch_distributed_nn_tpu.utils import profiling
-
-        monkeypatch.setattr(profiling, "_find_xplane", lambda d: d)
-        monkeypatch.setattr(
-            profiling, "_load_xplane",
-            lambda p: self._xspace([
-                ("convert_reduce_fusion.1", 10.0),
-                ("multiply_add_fusion.2", 5.0),
-                ("fusion.3", 2.0),
-                ("all-reduce.4", 2.0),
-            ]),
-        )
+        trace = str(tmp_path / "step.textproto")
+        self._write_trace(trace, [
+            ("convert_reduce_fusion.1", 10.0),
+            ("multiply_add_fusion.2", 5.0),
+            ("fusion.3", 2.0),
+            ("all-reduce.4", 2.0),
+        ])
         cost = {
             "flops": 1.51e9,
             "ici_bytes": 1e6,
@@ -225,7 +236,7 @@ class TestCalibration:
                 "other": {"flops": 0.0, "hbm_bytes": 0.0},
             },
         }
-        prof = fit_from_trace("unused", cost, steps=4,
+        prof = fit_from_trace(trace, cost, steps=4,
                               base=default_profile("tpu", TPU_V5E))
         assert prof.source == "trace"
         assert prof.compute_ceilings["convert_reduce_fusion"] == (

@@ -395,11 +395,7 @@ class TestTrainerFlightrec:
     """End-to-end: a real injected host delay under --flightrec produces
     one incident bundle with a REAL jax.profiler trace (CPU)."""
 
-    def test_delay_produces_one_bundle(self, tmp_path, monkeypatch):
-        # keep the report's trace section away from the TF proto import
-        # (the chaos `flightrec` scenario exercises the real parser)
-        monkeypatch.setattr(xplane, "summarize_xplane",
-                            lambda *a, **k: {})
+    def test_delay_produces_one_bundle(self, tmp_path):
         from pytorch_distributed_nn_tpu.observability import reader
         from pytorch_distributed_nn_tpu.training.trainer import (
             TrainConfig,
@@ -432,6 +428,9 @@ class TestTrainerFlightrec:
         assert inc["kind"] == "step_regression" and inc["step"] == 7
         assert inc["has_trace"], "CPU jax.profiler trace should be captured"
         assert inc["has_report"]
+        # the report's trace section is the real reader on the real trace
+        with open(os.path.join(inc["path"], "report.md")) as f:
+            assert "no device planes" in f.read()
         rs = reader.read_stream(os.path.join(d, "telemetry.jsonl"))
         assert sum(
             1 for e in rs.events if e.get("type") == "incident"

@@ -57,13 +57,28 @@ def _scheduler(engine, telemetry=None, **kw):
 
 
 # ---------------------------------------------------------------------------
-# bitwise: KV-cache decode == full recompute, at every position
+# KV-cache decode == full recompute, at every position
 # ---------------------------------------------------------------------------
 
+#: float32 ulps, at the scale of the largest logit, by which a cached
+#: decode step may differ from the full forward (set from the dtype, not
+#: from a run: jax 0.9.0 on the CPU differs by about 3)
+DECODE_ULPS = 8
 
-def test_kv_decode_bitwise_equals_full_recompute():
+
+def test_kv_decode_equals_full_recompute_to_a_few_ulp():
     """Model-level pin: prefill + per-position cached decode reproduces
-    the full causal forward's last-position logits bit for bit."""
+    the full causal forward's last-position logits and, exactly, its
+    greedy token sequence.
+
+    Not bit for bit: the decode step runs its matmuls on one row, and its
+    attention on a broadcast query against the padded cache (S slots, the
+    unused ones masked), where the full forward runs S rows. The terms
+    summed are the same; the order XLA sums them in depends on the
+    shapes, and since jax 0.9.0 the float32 results differ in their last
+    bits (1.9e-7 at logits of order 0.5). What must hold is that the
+    difference stays at rounding (``DECODE_ULPS`` ulp of the logits'
+    scale) and never changes which token is chosen."""
     m = build_model("GptTiny")
     cfg = m.config
     rng = jax.random.PRNGKey(0)
@@ -106,11 +121,15 @@ def test_kv_decode_bitwise_equals_full_recompute():
                       mask=jnp.asarray(fmask))
         ref_row = np.asarray(ref)[0, len(seq) - 1]
         got = np.asarray(dec)[0]
-        np.testing.assert_array_equal(
-            ref_row, got,
+        np.testing.assert_allclose(
+            got, ref_row, rtol=0.0,
+            atol=DECODE_ULPS * np.finfo(np.float32).eps
+            * float(np.abs(ref_row).max()),
             err_msg=f"decode diverged from recompute at position {pos}",
         )
         tok = int(np.argmax(got))
+        assert tok == int(np.argmax(ref_row)), (
+            f"greedy token differs from recompute at position {pos}")
 
 
 def test_engine_generation_matches_full_recompute(engine,

@@ -21,6 +21,8 @@ import subprocess
 import sys
 import time
 
+from pytorch_distributed_nn_tpu.observability import core as obs_core
+from pytorch_distributed_nn_tpu.observability import reader
 from pytorch_distributed_nn_tpu.training import checkpoint as ckpt
 
 
@@ -99,17 +101,26 @@ def _run_workers(train_dir: str, mode: str, expect_start: int = 4,
     return outs
 
 
+def _checkpoint_writes(train_dir: str, rank: int):
+    """Steps of the ``checkpoint_write`` events in one process's stream."""
+    rs = reader.read_stream(
+        os.path.join(train_dir, obs_core.stream_basename(rank)))
+    return [e["step"] for e in rs.events
+            if e.get("type") == "checkpoint_write"]
+
+
 def test_two_process_train_checkpoint_resume(tmp_path):
     train_dir = str(tmp_path / "train")
     os.makedirs(train_dir)
-    outs = _run_workers(train_dir, "dp")
+    _run_workers(train_dir, "dp")
 
     # run-1 wrote steps 2 and 4; no duplicate/torn files from a second
-    # writer (process 1 logs no checkpoint lines). all_steps matches
+    # writer: every write is an event in the stream of the process that
+    # made it, and process 1's stream holds none. all_steps matches
     # checkpoint entries only, never their .meta.json CRC manifests.
     assert ckpt.all_steps(train_dir) == [2, 4]
-    assert "Checkpointed" in outs[0]
-    assert "Checkpointed" not in outs[1]
+    assert _checkpoint_writes(train_dir, 0) == [2, 4]
+    assert _checkpoint_writes(train_dir, 1) == []
 
 
 def test_two_process_gspmd_sharded_checkpoint_resume(tmp_path):

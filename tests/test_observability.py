@@ -478,62 +478,6 @@ class TestTimingShim:
         assert timer.durations["data"] >= 0.0
 
 
-class TestProfilingAggregation:
-    """device_step_time_ms must aggregate over ALL device planes — the
-    first-plane-only read under-reported multi-chip traces (satellite
-    fix). Synthetic xplane built from the same SimpleNamespace shape the
-    proto parser walks (tests/test_tools.py idiom)."""
-
-    def _xspace(self, planes):
-        from types import SimpleNamespace as NS
-
-        out = []
-        for name, op_ms in planes:
-            meta = {i: NS(name=f"op.{i}") for i in range(len(op_ms))}
-            events = [
-                NS(metadata_id=i, duration_ps=ms * 1e9)
-                for i, ms in enumerate(op_ms)
-            ]
-            out.append(NS(name=name, event_metadata=meta,
-                          lines=[NS(name="XLA Ops", events=events)]))
-        return NS(planes=out)
-
-    def test_multi_plane_sum(self, monkeypatch):
-        from pytorch_distributed_nn_tpu.utils import profiling
-
-        monkeypatch.setattr(profiling, "_find_xplane", lambda d: d)
-        monkeypatch.setattr(
-            profiling, "_load_xplane",
-            lambda p: self._xspace([
-                ("/device:TPU:0", [6.0, 4.0]),
-                ("/device:TPU:1", [5.0, 5.0]),
-                ("/host:CPU", [99.0]),  # non-device plane: ignored
-            ]),
-        )
-        # 10 ms on each of two chips over 5 steps = 4 ms/step total
-        assert profiling.device_step_time_ms("x", 5) == pytest.approx(4.0)
-
-    def test_single_plane_unchanged(self, monkeypatch):
-        from pytorch_distributed_nn_tpu.utils import profiling
-
-        monkeypatch.setattr(profiling, "_find_xplane", lambda d: d)
-        monkeypatch.setattr(
-            profiling, "_load_xplane",
-            lambda p: self._xspace([("/device:TPU:0", [6.0, 4.0])]),
-        )
-        assert profiling.device_step_time_ms("x", 2) == pytest.approx(5.0)
-
-    def test_no_device_planes_is_none(self, monkeypatch):
-        from pytorch_distributed_nn_tpu.utils import profiling
-
-        monkeypatch.setattr(profiling, "_find_xplane", lambda d: d)
-        monkeypatch.setattr(
-            profiling, "_load_xplane",
-            lambda p: self._xspace([("/host:CPU", [1.0])]),
-        )
-        assert profiling.device_step_time_ms("x", 2) is None
-
-
 class TestTrainerIntegration:
     """One tiny end-to-end run: the stream carries manifest + steps +
     events, the heartbeat carries the rate gauges, metrics.prom is valid

@@ -149,68 +149,62 @@ class TestCliDryRun:
             main(["ssh", "--name", "x", "--dry-run"])
 
 
+FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "benchmark", "fixtures")
+#: traces of this program from the TPU v5e (and one made by hand in the
+#: same grammar), read-only here: tests/benchmark/ owns them
+TRACES = ("hand", "resnet18_ckpt_save_v5e", "bert_base_step_v5e",
+          "bert_base_dp4_step_v5e")
+
+
+@pytest.mark.parametrize("name", TRACES)
 class TestXplaneSummary:
-    """summarize_xplane truncation must not drop device time (the --steps
-    ms/step figure is sum-of-rows; a silent top-N cut under-reported it)."""
+    """The package's trace reader (the operator's per-op table) on what
+    the profiler writes, against the benchmark's reader of the same file:
+    two readers, independent on purpose, that must agree on every chip's
+    ``XLA Ops`` time."""
 
-    def _fake_xspace(self, n_ops):
-        from types import SimpleNamespace as NS
+    @staticmethod
+    def _path(name):
+        return os.path.join(FIXTURES, name + ".textproto")
 
-        meta = {i: NS(name=f"op.{i}") for i in range(n_ops)}
-        events = [NS(metadata_id=i, duration_ps=1e9) for i in range(n_ops)]
-        plane = NS(name="/device:TPU:0", event_metadata=meta,
-                   lines=[NS(name="XLA Ops", events=events)])
-        return NS(planes=[plane])
-
-    def test_tail_row_preserves_total(self, monkeypatch):
+    @pytest.mark.parametrize("collapse", [False, True])
+    def test_rows_sum_to_each_chips_xla_ops(self, name, collapse):
+        from benchmark import trace
         from pytorch_distributed_nn_tpu.utils import profiling
 
-        monkeypatch.setattr(profiling, "_find_xplane", lambda d: d)
-        monkeypatch.setattr(
-            profiling, "_load_xplane", lambda p: self._fake_xspace(10)
-        )
-        rows = profiling.summarize_xplane("unused", top=3, collapse=False)[
-            "/device:TPU:0"
-        ]
-        assert len(rows) == 4  # 3 shown + "(other 7 ops)"
-        assert rows[-1].name == "(other 7 ops)"
-        assert rows[-1].count == 7
-        assert sum(r.total_ms for r in rows) == pytest.approx(10.0)
-        assert sum(r.pct for r in rows) == pytest.approx(100.0)
+        summary = profiling.summarize_xplane(
+            self._path(name), top=10 ** 6, collapse=collapse)
+        chips = trace.load(self._path(name)).chips
+        assert sorted(summary) == sorted(chips)
+        for plane, lines in chips.items():
+            ops = lines[trace.OPS_LINE]
+            rows = summary[plane]
+            assert sum(r.total_ms for r in rows) == pytest.approx(
+                sum(e.end - e.start for e in ops) / 1e6)
+            assert sum(r.count for r in rows) == len(ops)
+            assert sum(r.pct for r in rows) == pytest.approx(100.0)
+            # everything shown: no tail row
+            assert all(not r.name.startswith("(other") for r in rows)
+            if not collapse:
+                assert {r.name for r in rows} == {e.text for e in ops}
 
-    def test_no_tail_row_when_everything_shown(self, monkeypatch):
+    def test_top_folds_the_rest_into_one_tail_row(self, name):
+        """Truncation must not drop device time: a table cut to ``top``
+        rows still sums to the chip's total."""
         from pytorch_distributed_nn_tpu.utils import profiling
 
-        monkeypatch.setattr(profiling, "_find_xplane", lambda d: d)
-        monkeypatch.setattr(
-            profiling, "_load_xplane", lambda p: self._fake_xspace(3)
-        )
-        rows = profiling.summarize_xplane("unused", top=3, collapse=False)[
-            "/device:TPU:0"
-        ]
-        assert len(rows) == 3
-        assert all(not r.name.startswith("(other") for r in rows)
-
-
-class TestXlaFlagSweep:
-    def test_sweep_tables_are_consistent(self):
-        """Every sweep entry references a real config and flag set, and
-        every config carries a kind the child runner understands."""
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "xla_flag_sweep",
-            os.path.join(
-                os.path.dirname(__file__), "..", "tools", "xla_flag_sweep.py"
-            ),
-        )
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        for name, entries in mod.SWEEPS.items():
-            for config, flagset in entries:
-                assert config in mod.CONFIGS, (name, config)
-                assert flagset in mod.FLAG_SETS, (name, flagset)
-        for cfg in mod.CONFIGS.values():
-            assert cfg["kind"] in ("mlm", "resnet")
-            if cfg["kind"] == "mlm":
-                assert cfg["B"] % 32 == 0 and cfg["L"] == 512
+        full = profiling.summarize_xplane(
+            self._path(name), top=10 ** 6, collapse=False)
+        cut = profiling.summarize_xplane(
+            self._path(name), top=3, collapse=False)
+        assert sorted(cut) == sorted(full)
+        for plane, rows in cut.items():
+            assert len(full[plane]) > 3
+            assert len(rows) == 4  # 3 shown + the folded tail
+            assert rows[:3] == full[plane][:3]
+            assert rows[-1].name == f"(other {len(full[plane]) - 3} ops)"
+            assert rows[-1].count == sum(r.count for r in full[plane][3:])
+            assert sum(r.total_ms for r in rows) == pytest.approx(
+                sum(r.total_ms for r in full[plane]))
+            assert sum(r.pct for r in rows) == pytest.approx(100.0)

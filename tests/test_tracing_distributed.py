@@ -4,10 +4,10 @@ Covers the propagation layer (``TraceContext`` header parse/mint/child
 lineage, the ``run_manifest`` env relay), the assembly half
 (``reader.assemble_trace`` over the synthetic frontend fixture: hedge
 branches, winner marking, orphan flagging, clock-offset recovery), the
-``obs trace`` / ``obs bench-trend`` CLI, the submit-signature contract
-the serving tier relies on, and the sweep orchestrator -> trial manifest
-lineage. The LIVE cross-process path (real frontend + replicas under
-SIGKILL) is the chaos ``replica_loss --cases kill`` invariant.
+``obs trace`` CLI, the submit-signature contract the serving tier relies
+on, and the sweep orchestrator -> trial manifest lineage. The LIVE
+cross-process path (real frontend + replicas under SIGKILL) is the chaos
+``replica_loss --cases kill`` invariant.
 """
 
 import glob
@@ -19,10 +19,7 @@ import pytest
 
 from pytorch_distributed_nn_tpu.observability import reader, tracing
 from pytorch_distributed_nn_tpu.observability.core import run_manifest
-from pytorch_distributed_nn_tpu.observability.obs_cli import (
-    _recover_bench_sections,
-    main_obs,
-)
+from pytorch_distributed_nn_tpu.observability.obs_cli import main_obs
 from pytorch_distributed_nn_tpu.observability.tracing import TraceContext
 
 
@@ -212,7 +209,7 @@ class TestAssembleTrace:
 
 
 # ---------------------------------------------------------------------------
-# obs trace / obs bench-trend CLI
+# obs trace CLI
 # ---------------------------------------------------------------------------
 
 
@@ -234,43 +231,6 @@ class TestObsTraceCLI:
 
     def test_selftest_passes(self, capsys):
         assert main_obs(["trace", "--selftest"]) == 0
-
-
-class TestBenchTrend:
-    def test_recover_sections_balances_braces(self):
-        tail = ('"p50": 0.1}, "availability": {"p99_ms": 12.0, '
-                '"nested": {"a": 1}}, "broken": {"x": ')
-        out = _recover_bench_sections(tail)
-        assert out == {
-            "availability": {"p99_ms": 12.0, "nested": {"a": 1}},
-        }
-
-    def test_empty_dir_is_not_a_failure(self, tmp_path, capsys):
-        assert main_obs(["bench-trend", "--dir", str(tmp_path)]) == 0
-        assert "no BENCH_r" in capsys.readouterr().out
-
-    def test_folds_rounds_including_torn_tail(self, tmp_path, capsys):
-        (tmp_path / "BENCH_r01.json").write_text(json.dumps({
-            "rc": 0, "tail": "",
-            "parsed": {"metric": "steps_per_sec", "value": 10.0,
-                       "extra": {"availability": {"p99_ms": 8.0}}},
-        }))
-        # a torn round: the result line's head fell off the tail window
-        (tmp_path / "BENCH_r02.json").write_text(json.dumps({
-            "rc": 1,
-            "tail": '_sec": 9.5, "availability": {"p99_ms": 9.0}, "x',
-        }))
-        assert main_obs(["bench-trend", "--dir", str(tmp_path)]) == 0
-        out = capsys.readouterr().out
-        assert "bench trend over 2 round(s)" in out
-        assert "r01" in out and "r02" in out
-        assert "partial (rc=1)" in out  # torn round recovered, not lost
-        assert "p99_ms" in out  # per-section trajectory row
-
-
-# ---------------------------------------------------------------------------
-# sweep -> trial lineage (the env relay end to end, local pool)
-# ---------------------------------------------------------------------------
 
 
 def test_sweep_trial_manifests_carry_trace_lineage(tmp_path):
