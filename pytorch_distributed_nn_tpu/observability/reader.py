@@ -29,6 +29,7 @@ import json
 import math
 import os
 import random
+import statistics
 from typing import Dict, List, Optional
 
 from pytorch_distributed_nn_tpu.observability.core import (
@@ -189,6 +190,9 @@ def _rate(records: List[dict]) -> float:
     return len(records) / wall if wall > 0 else float("nan")
 
 
+_SAVES_LISTED = 8  # `obs summary` lists the newest saves one by one
+
+
 def _event_stall_ms(e: dict) -> Optional[float]:
     """Loop blockage of one checkpoint_write event, in ms.
 
@@ -205,6 +209,51 @@ def _event_stall_ms(e: dict) -> Optional[float]:
     return None
 
 
+def _save_costs(rs: RunStream, writes: List[dict]) -> List[dict]:
+    """Per save, the program's ``stall_ms`` beside what the save cost the
+    loop: the sum of ``dispatch_gap_ms`` over the log windows from this
+    save to the next (or to the end of the stream), less as many steps at
+    the run's steady pace. The records of one flush share one ``wall_ms``,
+    which tells the windows apart; the steady pace is the median
+    ``wall_ms`` of the windows no save began in, the stream's first (it
+    holds the compile) left out (none: no figures). The sum runs to the
+    next save because dispatch leads the device by the runtime's launch
+    queue: a loop kept from dispatching right after a save shows in that
+    window's gaps, the device's lateness in the next flush's wait, and
+    only together are they what the save cost. A window whose gaps do not
+    add up to its wall time within a third is left out, and its save with
+    it: the first window of a ``train()`` call, which nothing in flight
+    and no flush's wait precede."""
+    windows = []  # one per flush, in step order
+    for r in rs.steps:
+        if "dispatch_gap_ms" not in r or "wall_ms" not in r:
+            continue
+        if not windows or windows[-1]["wall_ms"] != r["wall_ms"]:
+            windows.append({"first": r["step"], "wall_ms": r["wall_ms"],
+                            "steps": 0, "gaps_ms": 0.0})
+        windows[-1]["steps"] += 1
+        windows[-1]["gaps_ms"] += float(r["dispatch_gap_ms"])
+    writes = [e for e in writes if "step" in e]
+    saved = sorted({e["step"] for e in writes})
+    steady = [w["wall_ms"] for w in windows[1:] if w["first"] - 1 not in saved]
+    if not steady:
+        return []
+    pace = statistics.median(steady)
+    out = []
+    for e in writes:
+        until = min((s for s in saved if s > e["step"]), default=math.inf)
+        span = [w for w in windows if e["step"] < w["first"] <= until]
+        walls = [w["steps"] * w["wall_ms"] for w in span]
+        if span and all(abs(w["gaps_ms"] - wall) * 3 < wall
+                        for w, wall in zip(span, walls)):
+            out.append({
+                "step": e["step"], "stall_ms": _event_stall_ms(e),
+                "late_ms": sum(w["gaps_ms"] - w["steps"] * pace
+                               for w in span),
+            })
+    return out
+
+
 def io_stall_summary(rs: RunStream) -> Optional[dict]:
     """The I/O-stall section of ``obs summary``: how much the step loop
     actually blocked on host checkpoint I/O, vs how much writing happened
@@ -212,6 +261,7 @@ def io_stall_summary(rs: RunStream) -> Optional[dict]:
     writes = [e for e in rs.events if e.get("type") == "checkpoint_write"]
     if not writes:
         return None
+    saves = _save_costs(rs, writes)
     stalls = [s for s in map(_event_stall_ms, writes) if s is not None]
     write_ms = [
         float(e["write_ms"]) if "write_ms" in e
@@ -227,6 +277,8 @@ def io_stall_summary(rs: RunStream) -> Optional[dict]:
         "stall_ms": phase_stats(stalls),
         "write_ms": phase_stats(write_ms),
         "queued_ms": phase_stats(queued),
+        "late_ms": phase_stats([v["late_ms"] for v in saves]),
+        "saves": saves,
         "backpressure_waits": sum(
             1 for e in rs.events if e.get("type") == "ckpt_backpressure"
         ),
@@ -814,6 +866,19 @@ def render_summary(summary: dict, manifest: Optional[dict] = None) -> str:
                 f"  write (ms)        p50 {wr['p50']:8.1f}  "
                 f"p99 {wr['p99']:8.1f}  total {wr['total']:8.1f}"
             )
+        late = io.get("late_ms")
+        if late:
+            lines.append(
+                f"  loop late (ms)    p50 {late['p50']:8.1f}  "
+                f"p99 {late['p99']:8.1f}  total {late['total']:8.1f}"
+                "  (dispatch gaps from a save to the next, over the "
+                "steady pace)"
+            )
+            for v in io["saves"][-_SAVES_LISTED:]:
+                lines.append(
+                    f"    save @ step {v['step']}: stall "
+                    f"{v['stall_ms']:.1f} ms, late {v['late_ms']:.1f} ms"
+                )
         if io.get("backpressure_waits"):
             lines.append(
                 f"  backpressure: {io['backpressure_waits']} save(s) "

@@ -51,14 +51,14 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.pdtn_max_compressed_size.argtypes = [ctypes.c_uint64]
         lib.pdtn_compress.restype = ctypes.c_int64
         lib.pdtn_compress.argtypes = [
-            ctypes.c_char_p, ctypes.c_uint64,
-            ctypes.c_char_p, ctypes.c_uint64,
+            ctypes.c_void_p, ctypes.c_uint64,
+            ctypes.c_void_p, ctypes.c_uint64,
             ctypes.c_int, ctypes.c_uint32,
         ]
         lib.pdtn_decompress.restype = ctypes.c_int64
         lib.pdtn_decompress.argtypes = [
-            ctypes.c_char_p, ctypes.c_uint64,
-            ctypes.c_char_p, ctypes.c_uint64,
+            ctypes.c_void_p, ctypes.c_uint64,
+            ctypes.c_void_p, ctypes.c_uint64,
             ctypes.c_uint32,
         ]
         _lib = lib
@@ -69,36 +69,71 @@ def available() -> bool:
     return _load() is not None
 
 
-def compress(data: bytes, level: int = 1, width: int = 4) -> bytes:
-    """Compress bytes with byte-shuffle width `width` (4 = float32)."""
+def _flat_u8(data) -> np.ndarray:
+    """`data` (bytes, memoryview, C-contiguous ndarray) as a flat uint8
+    array over the same memory: no copy."""
+    if isinstance(data, np.ndarray):
+        return np.ascontiguousarray(data).reshape(-1).view(np.uint8)
+    return np.frombuffer(data, np.uint8)
+
+
+def _require_lib() -> ctypes.CDLL:
     lib = _load()
     if lib is None:
         raise RuntimeError("native codec unavailable (build native/ with make)")
-    n = len(data)
-    cap = lib.pdtn_max_compressed_size(n)
-    out = ctypes.create_string_buffer(cap)
-    size = lib.pdtn_compress(data, n, out, cap, level, width)
-    if size < 0:
-        raise RuntimeError("pdtn_compress failed")
-    header = np.zeros(1, _HEADER)
+    return lib
+
+
+def compress_buffer(data, level: int = 1, width: int = 4) -> np.ndarray:
+    """Compress a buffer into a uint8 array: the codec header, then the
+    compressed body, with byte-shuffle width `width` (4 = float32).
+
+    Nothing here touches the input or the output under the interpreter
+    lock: the input is read by pointer, the output is allocated
+    uninitialised and returned as a view of its used part, and the
+    foreign call releases the lock. A checkpoint's writer thread calls
+    this beside the train loop (training/checkpoint.py)."""
+    lib = _require_lib()
+    src = _flat_u8(data)
+    n = src.size
+    cap = int(lib.pdtn_max_compressed_size(n))
+    out = np.empty(_HEADER.itemsize + cap, np.uint8)
+    header = out[: _HEADER.itemsize].view(_HEADER)
     header["orig_size"] = n
     header["width"] = width
-    return header.tobytes() + out.raw[:size]
+    header["pad"] = 0
+    size = lib.pdtn_compress(
+        src.ctypes.data, n, out.ctypes.data + _HEADER.itemsize, cap,
+        level, width,
+    )
+    if size < 0:
+        raise RuntimeError("pdtn_compress failed")
+    return out[: _HEADER.itemsize + size]
 
 
-def decompress(blob: bytes) -> bytes:
-    lib = _load()
-    if lib is None:
-        raise RuntimeError("native codec unavailable (build native/ with make)")
-    header = np.frombuffer(blob[: _HEADER.itemsize], _HEADER)[0]
+def compress(data, level: int = 1, width: int = 4) -> bytes:
+    """``compress_buffer`` as bytes, for callers with small payloads."""
+    return compress_buffer(data, level=level, width=width).tobytes()
+
+
+def decompress(blob) -> memoryview:
+    """Inflate what ``compress`` / ``compress_buffer`` made. The result is
+    a byte view of an uninitialised buffer the codec filled: it compares
+    equal to the original ``bytes`` and feeds ``np.frombuffer`` and
+    msgpack as they are, with no second copy."""
+    lib = _require_lib()
+    src = _flat_u8(blob)
+    header = src[: _HEADER.itemsize].view(_HEADER)[0]
     n = int(header["orig_size"])
     width = int(header["width"])
-    payload = blob[_HEADER.itemsize :]
-    out = ctypes.create_string_buffer(n)
-    size = lib.pdtn_decompress(payload, len(payload), out, n, width)
+    payload = src[_HEADER.itemsize :]
+    out = np.empty(n, np.uint8)
+    size = lib.pdtn_decompress(
+        payload.ctypes.data, payload.size, out.ctypes.data, n, width
+    )
     if size != n:
         raise RuntimeError("pdtn_decompress failed")
-    return out.raw
+    return memoryview(out)
 
 
 def w_compress(arr: np.ndarray, level: int = 1) -> bytes:
@@ -106,7 +141,7 @@ def w_compress(arr: np.ndarray, level: int = 1) -> bytes:
     arr = np.ascontiguousarray(arr)
     meta = (str(arr.dtype).encode() + b"|" +
             ",".join(map(str, arr.shape)).encode() + b"|")
-    return meta + compress(arr.tobytes(), level=level, width=arr.dtype.itemsize)
+    return meta + compress(arr, level=level, width=arr.dtype.itemsize)
 
 
 def w_decompress(blob: bytes) -> np.ndarray:

@@ -12,15 +12,32 @@ overlaps with training.
 
 A save splits into two halves::
 
-    save(state)                         # the TRAIN LOOP pays only this
+    save(state)                         # the TRAIN LOOP waits for this
       ├─ backpressure wait              # depth-1: at most one save in flight
       ├─ on-device clone (async dispatch, ~HBM bandwidth)
       └─ enqueue → returns              # stall_ms = everything above
-    writer thread                       # overlapped with training steps
-      ├─ device_get(clone)              # the 20-60 MB/s d2h fetch
+    writer thread                       # beside the training steps
+      ├─ device_get(clone)              # the d2h fetch
       ├─ serialize + host_codec compress
       ├─ atomic publish + CRC32 manifest + retry   (the EXISTING writers)
       └─ keep-last GC
+
+``stall_ms`` is what the loop waits for, not all a save can cost it. The
+writer is a thread of the same interpreter: whatever it does with the
+interpreter lock held keeps the loop from dispatching, and a save
+follows a flush, which has drained the device's queue: the device idles
+until the loop gets the lock back. Through PR 30 that was ≈ 430 ms a save
+against a ``stall_ms`` of 8 (msgpack's ``packb`` over the whole state, a
+zero-filled ctypes buffer, four state-sized ``bytes`` copies: PERF.md,
+PR 31). So the rule for everything the writer runs between the fetch and
+the rename (``checkpoint.save_checkpoint``): no pass over state-sized or
+leaf-sized bytes with the lock held — numpy copies, the codec's foreign
+call, ``zlib.crc32`` and ``file.write`` release it; buffers are
+allocated uninitialised. What is left to the lock is per-leaf
+bookkeeping (≈ 25-45 ms a save on the same job since, the device's own
+fetch and snapshot work included). The step records' ``dispatch_gap_ms``
+shows what a save did cost the loop (``obs summary``'s I/O-stall section
+sets it beside ``stall_ms``).
 
 Contracts, in order of importance:
 
@@ -59,7 +76,8 @@ Contracts, in order of importance:
   never race the writer thread on the same ``model_step_<N>`` path.
 
 Telemetry: ``checkpoint_write`` events gain ``queued_ms`` / ``write_ms`` /
-``stall_ms`` / ``fetch_ms``; the registry gains the ``ckpt_queue_depth``
+``stall_ms`` / ``fetch_ms`` (and, from the file writer, ``serialize_ms`` /
+``compress_ms`` / ``file_ms``); the registry gains the ``ckpt_queue_depth``
 gauge and ``ckpt_stall_ms_total`` counter (exported via promexport like
 every other metric); ``obs summary`` renders the I/O-stall section from
 the events.

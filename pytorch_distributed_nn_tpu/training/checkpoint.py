@@ -40,11 +40,13 @@ from __future__ import annotations
 import json
 import os
 import re
+import struct
 import time
 import zlib
 from typing import Optional, Tuple
 
 import jax
+import msgpack
 import numpy as np
 from flax import serialization
 
@@ -187,6 +189,87 @@ def _codec():
         return None
 
 
+_EXT_NDARRAY = 1  # flax.serialization._MsgpackExtType.ndarray
+_FIXEXT = {1: b"\xd4", 2: b"\xd5", 4: b"\xd6", 8: b"\xd7", 16: b"\xd8"}
+
+
+def _sized(n: int, codes: bytes) -> bytes:
+    """A msgpack header that carries the length ``n`` in 1, 2 or 4 bytes:
+    ``codes`` are the three type bytes, smallest first."""
+    if n < 1 << 8:
+        return codes[0:1] + struct.pack(">B", n)
+    if n < 1 << 16:
+        return codes[1:2] + struct.pack(">H", n)
+    return codes[2:3] + struct.pack(">I", n)
+
+
+def _ndarray_header(arr: np.ndarray) -> bytes:
+    """Everything flax writes for an array leaf before its data: the ext
+    header of type ``ndarray``, then the head of the inner msgpack tuple
+    ``(shape, dtype.name, bin)`` (flax.serialization._ndarray_to_bytes)."""
+    inner = (
+        b"\x93" + msgpack.packb(arr.shape) + msgpack.packb(arr.dtype.name)
+        + _sized(arr.nbytes, b"\xc4\xc5\xc6")
+    )
+    n = len(inner) + arr.nbytes
+    ext = _FIXEXT.get(n) or _sized(n, b"\xc7\xc8\xc9")
+    return ext + struct.pack("b", _EXT_NDARRAY) + inner
+
+
+def serialize_state(state) -> np.ndarray:
+    """The bytes of flax's ``to_bytes`` for ``state``, one for one, as a
+    uint8 array, without a pass over the leaves' data under the
+    interpreter lock.
+
+    flax packs the whole tree in one ``msgpack.packb`` and copies each
+    leaf three to four times on the way, all with the lock held: on the
+    async writer's thread that kept the train loop from dispatching for
+    as long as the state is large (PERF.md, PR 31). Here the lock is held
+    for the map and leaf headers only (small ``bytes``, microseconds a
+    leaf whatever its size); each leaf's data goes from the array into
+    its slice of one uninitialised buffer by ``np.copyto``, which
+    releases the lock. A leaf that is not a plain array flax would pack
+    whole (a scalar, ``None``, an object dtype, an array over flax's
+    chunking limit) goes through flax's own packer, alone.
+    """
+    pieces = []  # header bytes, and arrays where their data goes
+    map_header = msgpack.Packer(autoreset=True).pack_map_header
+
+    def walk(node):
+        if type(node) is dict:
+            pieces.append(map_header(len(node)))
+            for key, child in node.items():
+                pieces.append(msgpack.packb(key))
+                walk(child)
+            return
+        if isinstance(node, jax.Array):
+            node = np.asarray(node)
+        if (
+            type(node) is np.ndarray
+            and not (node.dtype.hasobject or node.dtype.isalignedstruct)
+            and node.nbytes <= serialization.MAX_CHUNK_SIZE
+        ):
+            pieces.append(_ndarray_header(node))
+            pieces.append(node)
+        else:
+            pieces.append(serialization.msgpack_serialize(node))
+
+    walk(serialization.to_state_dict(state))
+    sizes = [
+        p.nbytes if isinstance(p, np.ndarray) else len(p) for p in pieces
+    ]
+    out = np.empty(sum(sizes), np.uint8)
+    off = 0
+    for piece, n in zip(pieces, sizes):
+        dst = out[off:off + n]
+        if not isinstance(piece, np.ndarray):
+            dst[:] = np.frombuffer(piece, np.uint8)
+        elif n:
+            np.copyto(dst.view(piece.dtype).reshape(piece.shape), piece)
+        off += n
+    return out
+
+
 def save_checkpoint(
     directory: str, state: TrainState, step: Optional[int] = None,
     compress: bool = True, fault_plan=None, event_extra: Optional[dict] = None,
@@ -202,15 +285,22 @@ def save_checkpoint(
     bitrot/partial copy), which the manifest then convicts on resume.
 
     ``state`` may be the live device state OR a host snapshot of it
-    (``jax.device_get``): flax serializes both to identical msgpack bytes,
-    which is what makes the async pipeline (training/async_ckpt.py)
-    byte-identical to this synchronous path.
+    (``jax.device_get``): both serialize to identical msgpack bytes
+    (``serialize_state``: flax's, byte for byte), which is what makes the
+    async pipeline (training/async_ckpt.py) byte-identical to this
+    synchronous path. That pipeline runs this function on a thread beside
+    the train loop, so from here to the rename no pass over state-sized
+    or leaf-sized bytes may hold the interpreter lock: such passes are
+    numpy copies, the codec's foreign call, ``zlib.crc32`` and
+    ``file.write``, from and into buffers allocated uninitialised.
 
     The ``checkpoint_write`` event carries ``write_ms`` (serialize +
-    publish duration) and ``stall_ms`` (how long the TRAIN LOOP was
-    blocked — here the full write, since this call is synchronous).
-    ``event_extra`` lets an overlapped caller override ``stall_ms`` with
-    the actual loop blockage and add queueing fields.
+    publish duration), its parts ``serialize_ms`` / ``compress_ms`` /
+    ``file_ms`` (the three ``ckpt/*`` spans below) and ``stall_ms`` (how
+    long the TRAIN LOOP was blocked — here the full write, since this
+    call is synchronous). ``event_extra`` lets an overlapped caller
+    override ``stall_ms`` with the actual loop blockage and add queueing
+    fields.
     """
     t0 = time.perf_counter()
     os.makedirs(directory, exist_ok=True)
@@ -228,14 +318,19 @@ def save_checkpoint(
                 "FILE checkpoints — use a fresh --train-dir or the "
                 "matching parallelism config"
             )
-    with span("ckpt/serialize"):
-        payload = serialization.to_bytes(state)
+    with span("ckpt/serialize") as serialize:
+        payload = serialize_state(state)
     codec = _codec() if compress else None
+    compress_ms = 0.0
+    # the file is these buffers one after the other; they are never
+    # joined, because a join is a state-sized copy under the lock
     if codec is not None:
-        with span("ckpt/compress"):
-            blob = _MAGIC_LZ + codec.compress(payload)
+        with span("ckpt/compress") as compressed:
+            pieces = (_MAGIC_LZ, codec.compress_buffer(payload))
+        compress_ms = compressed.seconds * 1000
     else:
-        blob = _MAGIC_RAW + payload
+        pieces = (_MAGIC_RAW, payload)
+    nbytes = sum(len(p) for p in pieces)
 
     # flaky_io fault: the FIRST publish attempt fails with a transient
     # OSError — exactly the NFS/fuse EIO the retry policy absorbs. The
@@ -251,14 +346,15 @@ def save_checkpoint(
             )
             raise OSError(f"fault: flaky_io@{step} — injected transient EIO")
         with open(tmp, "wb") as f:
-            f.write(blob)
+            for piece in pieces:
+                f.write(piece)
         # atomic: the polling evaluator never sees a torn file
         os.replace(tmp, path)
 
-    with span("ckpt/file"):
+    with span("ckpt/file") as filed:
         retry_call(_publish, attempts=3, base_delay=0.05,
                    retry_on=(OSError,), label=f"checkpoint write {path}")
-        _write_file_meta(path, step, blob, geometry=geometry)
+        _write_file_meta(path, step, pieces, geometry=geometry)
         if data_state is not None:
             save_data_state(path, data_state)
     if fault_plan is not None and fault_plan.should_tear(step):
@@ -268,12 +364,17 @@ def save_checkpoint(
         )
     elapsed = time.perf_counter() - t0
     fields = {
-        "path": path, "bytes": len(blob),
+        "path": path, "bytes": nbytes,
         "seconds": round(elapsed, 6), "format": "file",
         "write_ms": round(elapsed * 1000, 3),
         # synchronous save: the loop was blocked for the whole write;
         # the async pipeline overrides this with its (tiny) real stall
         "stall_ms": round(elapsed * 1000, 3),
+        # the three writer spans, so a stream says where a write went
+        # without a trace (compress_ms 0.0: written uncompressed)
+        "serialize_ms": round(serialize.seconds * 1000, 3),
+        "compress_ms": round(compress_ms, 3),
+        "file_ms": round(filed.seconds * 1000, 3),
     }
     if event_extra:
         fields.update(event_extra)
@@ -282,12 +383,16 @@ def save_checkpoint(
 
 
 def _write_file_meta(
-    path: str, step: int, blob: bytes, geometry: Optional[dict] = None,
+    path: str, step: int, pieces, geometry: Optional[dict] = None,
 ) -> None:
     """Manifest AFTER the data publish: a crash in between leaves a
     manifest-less checkpoint, which verify treats as legacy-unverified
-    (decode still gates it) rather than corrupt."""
+    (decode still gates it) rather than corrupt. ``pieces`` are the
+    buffers the file is made of, in order."""
     mtmp = meta_path(path) + ".tmp"
+    crc = 0
+    for piece in pieces:
+        crc = zlib.crc32(piece, crc)
 
     def _publish_meta():
         with open(mtmp, "w") as f:
@@ -295,8 +400,8 @@ def _write_file_meta(
                 {
                     "format": _FILE_META_FORMAT,
                     "step": step,
-                    "bytes": len(blob),
-                    "crc32": zlib.crc32(blob) & 0xFFFFFFFF,
+                    "bytes": sum(len(p) for p in pieces),
+                    "crc32": crc & 0xFFFFFFFF,
                     # written-on geometry: what elastic resume compares the
                     # live fleet against (resilience/elastic.py)
                     "geometry": geometry or _default_geometry(),
@@ -420,9 +525,11 @@ def load_raw(path: str) -> dict:
     return serialization.msgpack_restore(_decode_payload(path, blob))
 
 
-def _decode_payload(path: str, blob: bytes) -> bytes:
-    """Shared container decode: magic-byte dispatch + host-codec inflate."""
-    magic, payload = blob[:4], blob[4:]
+def _decode_payload(path: str, blob: bytes) -> memoryview:
+    """Shared container decode: magic-byte dispatch + host-codec inflate.
+    The payload is a view (of ``blob``, or of the codec's output): no
+    state-sized copy is made on the way back in either."""
+    magic, payload = blob[:4], memoryview(blob)[4:]
     if magic == _MAGIC_LZ:
         codec = _codec()
         if codec is None:

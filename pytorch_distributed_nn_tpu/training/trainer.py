@@ -1302,6 +1302,7 @@ class Trainer:
         ok = False  # set only when the loop body completes
         step = self.start_step - 1  # last completed step when the loop is empty
         wall_t0 = time.perf_counter()  # the first window's wall_ms starts here
+        dispatched_at = time.monotonic()  # the first step's dispatch_gap_ms
         try:
           with (sup if sup is not None else contextlib.nullcontext()):
             for step in range(self.start_step, total_steps):
@@ -1361,12 +1362,23 @@ class Trainer:
                     wait_ms = getattr(self.train_loader, "last_wait_ms", None)
                     if wait_ms is None:
                         wait_ms = data_time * 1000.0
+                    # host time from the previous step's dispatch returning
+                    # to this one's (the first of a call: from loop entry).
+                    # Dispatch leads the device by the runtime's launch
+                    # queue, so after a flush one gap is the wait for the
+                    # device to drain, the next few are near zero and the
+                    # rest are the step time; a gap over that pattern is a
+                    # step the loop was kept from dispatching (a save's
+                    # stall, a thread holding the interpreter lock).
+                    now = time.monotonic()
+                    gap_ms, dispatched_at = (now - dispatched_at) * 1e3, now
                     pending.append({
                         "step": step + 1,
                         "epoch": step // max(steps_per_epoch, 1),
                         "_metrics": m,
                         "data_time": data_time,
                         "input_wait_ms": round(wait_ms, 3),
+                        "dispatch_gap_ms": round(gap_ms, 3),
                     })
                     if (step + 1) % c.log_every == 0:
                         flush()

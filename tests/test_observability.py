@@ -239,6 +239,59 @@ class TestSummaryAndCompare:
         assert [e["step"] for e in s["evals"]] == [30, 60]
         assert not math.isnan(s["step_rate"]["overall"])
 
+    def test_io_stall_sets_what_a_save_cost_beside_its_stall(self, golden):
+        """Per save: the dispatch gaps from the save to the next over the
+        run's steady pace, beside the program's stall_ms. The gaps are the
+        chip's shape: dispatch runs a launch queue ahead of the device, so
+        a flush's gap is long and the next few near zero."""
+        def window(first, wall_ms, gaps):
+            return [{"step": first + i, "wall_ms": wall_ms,
+                     "dispatch_gap_ms": g} for i, g in enumerate(gaps)]
+
+        steady = [398.5, 0.5, 0.5, 0.5] + [100.0] * 4  # 8 steps of 100 ms
+        steps = (
+            window(1, 900.0, [7000.0] + steady[1:])   # the compile
+            + window(9, 100.0, steady)
+            # the save of step 16: the loop dispatches 380 ms late, the
+            # device runs dry; one more dispatch returns at once
+            + window(17, 147.5, [778.0, 0.5, 0.5, 0.5, 0.5] + [100.0] * 3)
+            # ... and the next flush waits for the late device
+            + window(25, 100.0, [598.5, 0.5, 0.5, 0.5] + [100.0] * 4)
+            # the save of step 32 cost its stall and no more
+            + window(33, 101.0, [406.5, 0.5, 0.5, 0.5] + [100.0] * 4)
+            + window(41, 100.0, steady)
+        )
+        events = [
+            {"type": "checkpoint_write", "step": s, "stall_ms": 8.0,
+             "write_ms": 2900.0, "async": True, "bytes": 100}
+            # nothing after the save of step 48: no figure, no entry
+            for s in (16, 32, 48)
+        ]
+        rs = reader.RunStream("x", None, [], steps, events)
+        io = reader.io_stall_summary(rs)
+        assert [v["step"] for v in io["saves"]] == [16, 32]
+        assert io["saves"][0]["late_ms"] == pytest.approx(280.0 + 200.0)
+        assert io["saves"][1]["late_ms"] == pytest.approx(8.0)
+        assert io["saves"][0]["stall_ms"] == 8.0
+        assert io["late_ms"]["count"] == 2 and io["stall_ms"]["count"] == 3
+        # the first window of a second train() call: its gaps do not add
+        # up to its wall time, so the save before it gets no figure
+        second = steps + window(49, 100.0, [1.0, 0.5, 0.5, 0.5] + [100.0] * 4)
+        assert [v["step"] for v in reader.io_stall_summary(reader.RunStream(
+            "x", None, [], second, events))["saves"]] == [16, 32]
+        # a save after every flush leaves no steady window: no figures
+        every = [dict(e, step=s) for e in events[:1] for s in range(8, 49, 8)]
+        assert reader.io_stall_summary(
+            reader.RunStream("x", None, [], steps, every))["saves"] == []
+        # a stream from before the field: the section is as it was
+        old = reader.summarize_run(reader.read_stream(golden))
+        assert old["io_stall"]["late_ms"] is None
+        assert old["io_stall"]["saves"] == []
+        assert "loop late" not in reader.render_summary(old)
+        text = reader.render_summary(old | {"io_stall": io})
+        assert "loop late (ms)" in text
+        assert "save @ step 16: stall 8.0 ms, late 480.0 ms" in text
+
     def test_percentile_nearest_rank(self):
         vals = [1.0, 2.0, 3.0, 4.0]
         assert reader.percentile(vals, 50) == 2.0

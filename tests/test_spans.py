@@ -180,17 +180,15 @@ def test_trainer_trace_holds_the_catalogue_and_records_carry_wall_ms(
     (``ckpt/backpressure``), so every write but the last closes inside
     the trace — and the last, still open when the trace stops, is there
     by its ``:begin`` event alone."""
-    from flax import serialization
-
     from pytorch_distributed_nn_tpu.training import checkpoint as ckpt
 
-    real = serialization.to_bytes
+    real = ckpt.serialize_state
 
-    def slow_to_bytes(state):
+    def slow_serialize(state):
         time.sleep(0.15)
         return real(state)
 
-    monkeypatch.setattr(ckpt.serialization, "to_bytes", slow_to_bytes)
+    monkeypatch.setattr(ckpt, "serialize_state", slow_serialize)
     trainer = Trainer(TrainConfig(
         network="LeNet", dataset="MNIST", batch_size=64, test_batch_size=64,
         lr=0.01, momentum=0.9, max_steps=6, num_workers=2,
@@ -211,6 +209,12 @@ def test_trainer_trace_holds_the_catalogue_and_records_carry_wall_ms(
     wall_s = sum(r["wall_ms"] for r in history) / 1000.0
     assert wall_s == pytest.approx(run_s, rel=0.05)
     assert all(r["step_time"] > 0 for r in history)  # kept, held to nothing
+    # the dispatch stamps: every record has its gap, and their sum is the
+    # run from loop entry to the last dispatch returning (the last flush
+    # and the drain of the last save come after it)
+    gaps_s = sum(r["dispatch_gap_ms"] for r in steps) / 1000.0
+    assert all(r["dispatch_gap_ms"] > 0 for r in steps)
+    assert 0.5 * run_s < gaps_s <= run_s
 
     found = glob.glob(str(tmp_path / "profile/plugins/profile/*/*.xplane.pb"))
     assert len(found) == 1
