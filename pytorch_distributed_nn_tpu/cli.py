@@ -56,7 +56,8 @@ def _add_common_train_flags(p: argparse.ArgumentParser):
     p.add_argument("--epochs", type=int, default=1)
     p.add_argument("--network", default="ResNet18")
     p.add_argument("--dataset", default="Cifar10",
-                   choices=["MNIST", "Cifar10", "Cifar100", "SVHN", "MLMSynth"])
+                   choices=["MNIST", "Cifar10", "Cifar100", "SVHN", "MLMSynth",
+                            "NextTokenSynth"])
     p.add_argument("--seq-len", type=int, default=None,
                    help="MLM: sequence length (default: model max_len spec)")
     p.add_argument("--vocab-size", type=int, default=None,
@@ -360,7 +361,8 @@ def main_evaluator(argv=None) -> int:
     p.add_argument("--model-dir", required=True)
     p.add_argument("--network", default="ResNet18")
     p.add_argument("--dataset", default="Cifar10",
-                   choices=["MNIST", "Cifar10", "Cifar100", "SVHN", "MLMSynth"])
+                   choices=["MNIST", "Cifar10", "Cifar100", "SVHN", "MLMSynth",
+                            "NextTokenSynth"])
     p.add_argument("--eval-freq", type=int, default=100)
     p.add_argument("--eval-interval", type=float, default=10.0,
                    help="poll period in seconds (reference hardcoded 10)")
@@ -419,7 +421,10 @@ def main_evaluator(argv=None) -> int:
     if is_text_model(args.network):
         import jax.numpy as jnp
 
-        from pytorch_distributed_nn_tpu.data.text import MLMBatches, MLMLoader
+        from pytorch_distributed_nn_tpu.data.text import (
+            MLMLoader,
+            TEXT_DATASETS,
+        )
         from pytorch_distributed_nn_tpu.ops.metrics import (
             masked_cross_entropy,
             mlm_metrics,
@@ -437,7 +442,7 @@ def main_evaluator(argv=None) -> int:
             (seq_len,), num_replicas=n, input_dtype=jnp.int32,
         )
         loader = MLMLoader(
-            MLMBatches(
+            TEXT_DATASETS.get(args.dataset, TEXT_DATASETS["MLMSynth"])(
                 vocab_size=model.config.vocab_size, seq_len=seq_len,
                 batch_size=bs, seed=args.seed + 10_000,
                 corpus_seed=args.seed,  # same language the trainer used
@@ -677,7 +682,7 @@ def main_sweep(argv=None) -> int:
     pr.add_argument("--network", default="LeNet")
     pr.add_argument("--dataset", default="MNIST",
                     choices=["MNIST", "Cifar10", "Cifar100", "SVHN",
-                             "MLMSynth"])
+                             "MLMSynth", "NextTokenSynth"])
     pr.add_argument("--batch-size", type=int, default=32)
     pr.add_argument("--test-batch-size", type=int, default=32)
     pr.add_argument("--optimizer", choices=["sgd", "adam"], default="sgd")
@@ -986,7 +991,7 @@ def main_fleet(argv=None) -> int:
     pr.add_argument("--network", default="LeNet")
     pr.add_argument("--dataset", default="MNIST",
                     choices=["MNIST", "Cifar10", "Cifar100", "SVHN",
-                             "MLMSynth"])
+                             "MLMSynth", "NextTokenSynth"])
     pr.add_argument("--batch-size", type=int, default=32)
     pr.add_argument("--test-batch-size", type=int, default=32)
     pr.add_argument("--optimizer", choices=["sgd", "adam"], default="sgd")

@@ -59,6 +59,21 @@ class BigramCorpus:
         out[:, length - 1] = SEP_ID
         return out
 
+    def sample_walks(self, rng: np.random.RandomState, batch: int, length: int):
+        """(batch, length) int32 real-token ids: a plain random walk of the
+        chain, no special tokens (next-token batches). Every draw is made
+        at once and only the walk itself loops, so a long sequence costs
+        the host ~2 us a position, not a ``rng.choice`` call."""
+        n_real = self.vocab_size - NUM_SPECIAL
+        choice = rng.choice(
+            self.branching, size=(length, batch), p=self.succ_probs)
+        out = np.empty((batch, length), np.int32)
+        cur = rng.randint(0, n_real, size=batch)
+        for j in range(length):
+            out[:, j] = cur
+            cur = self.successors[cur, choice[j]]
+        return out + NUM_SPECIAL
+
 
 def mask_tokens(
     tokens: np.ndarray,
@@ -206,6 +221,43 @@ class MLMBatches:
             (x[i * bs:(i + 1) * bs], y[i * bs:(i + 1) * bs])
             for i in range(n_batches)
         ]
+
+
+def next_token_labels(tokens: np.ndarray) -> np.ndarray:
+    """Labels of a causal LM: position t predicts token t + 1; the last
+    position has nothing to predict (``IGNORE_INDEX``)."""
+    labels = np.full_like(tokens, IGNORE_INDEX)
+    labels[:, :-1] = tokens[:, 1:]
+    return labels
+
+
+class NextTokenBatches(MLMBatches):
+    """Infinite iterator of (tokens, labels) next-token batches over the
+    same bigram corpus, stream and state contract as `MLMBatches`: the
+    inputs are the walk itself (nothing masked), the labels the tokens
+    shifted by one with ``IGNORE_INDEX`` last — so the MLM loss and
+    metrics functions are the causal LM's too, over every position but
+    the last. ``dataset='NextTokenSynth'``."""
+
+    def _pair(self, rng: np.random.RandomState, batch: int):
+        toks = self.corpus.sample_walks(rng, batch, self.seq_len)
+        return toks, next_token_labels(toks)
+
+    def __next__(self) -> Tuple[np.ndarray, np.ndarray]:
+        rng = self._stream_rng(self._counter)
+        self._counter += 1
+        return self._pair(rng, self.batch_size)
+
+    def eval_set(self, n_batches: int):
+        """``n_batches`` fixed batches, the same every call (the draw is
+        per batch: next-token evaluation is only ever sized in whole
+        batches of this loader)."""
+        rng = np.random.RandomState(self._seed + 7919)
+        return [self._pair(rng, self.batch_size) for _ in range(n_batches)]
+
+
+#: TrainConfig.dataset -> the batch iterator a text model trains on
+TEXT_DATASETS = {"MLMSynth": MLMBatches, "NextTokenSynth": NextTokenBatches}
 
 
 class MLMLoader:

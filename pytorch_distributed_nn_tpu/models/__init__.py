@@ -14,6 +14,12 @@ from __future__ import annotations
 from typing import Any, Dict
 
 from pytorch_distributed_nn_tpu.models.lenet import LeNet
+from pytorch_distributed_nn_tpu.models.lfm2 import (
+    Lfm2Config,
+    Lfm2MoE,
+    lfm2_8b_a1b_ep4,
+    lfm2_tiny,
+)
 from pytorch_distributed_nn_tpu.models.resnet import (
     CifarResNet,
     ResNet,
@@ -78,6 +84,12 @@ _REGISTRY = {
     # the serving/generate/ engine pre-traces.
     "GptTiny": gpt_tiny,
     "GptMini": gpt_mini,
+    # LFM2-MoE decoder (gated short convolutions + grouped-query attention,
+    # sparse experts): one chip's share of LFM2-8B-A1B under four-way
+    # expert parallelism at published widths, and a toy of the same shape.
+    # Train as causal LMs (dataset='NextTokenSynth'); no decode mode yet.
+    "Lfm2_8B_A1B_EP4": lfm2_8b_a1b_ep4,
+    "Lfm2Tiny": lfm2_tiny,
     "VGG11NoBN": vgg11,
     "VGG13NoBN": vgg13,
     "VGG16NoBN": vgg16,
@@ -92,11 +104,14 @@ _DEFAULT_INPUT_SPEC = (32, 32, 3)
 
 # Text models take (L,) int32 token inputs instead of images; callers branch
 # on membership here (e.g. the trainer and __graft_entry__).
-TEXT_MODELS = {"BertBase", "BertTiny", "GptTiny", "GptMini"}
+TEXT_MODELS = {"BertBase", "BertTiny", "GptTiny", "GptMini",
+               "Lfm2_8B_A1B_EP4", "Lfm2Tiny"}
 INPUT_SPECS["BertBase"] = (512,)
 INPUT_SPECS["BertTiny"] = (128,)
 INPUT_SPECS["GptTiny"] = (64,)
 INPUT_SPECS["GptMini"] = (128,)
+INPUT_SPECS["Lfm2_8B_A1B_EP4"] = (8192,)
+INPUT_SPECS["Lfm2Tiny"] = (64,)
 
 # Causal decoders: artifacts of these networks serve the generative path
 # (serving/generate/) — POST /v1/generate instead of /v1/infer.

@@ -620,6 +620,36 @@ def _fleet_summary(rs: RunStream) -> Optional[dict]:
                         if h.get("state") == "dead")}
 
 
+def experts_summary(rs: RunStream) -> Optional[dict]:
+    """The expert-layer section of ``obs summary``, from the counters a
+    model with sparse experts leaves in every step record (models/lfm2.py:
+    ``moe_pairs``, ``moe_rows``, ``moe_load_max``, ``moe_load_mean``,
+    ``moe_layers``, each summed over the expert layers). ``None`` for
+    streams without them — the absent-family contract."""
+    steps = [r for r in rs.steps if r.get("moe_pairs") and r.get("moe_layers")]
+    if not steps:
+        return None
+
+    def mean(key):
+        return sum(float(r[key]) for r in steps) / len(steps)
+
+    pairs, rows, layers = mean("moe_pairs"), mean("moe_rows"), mean("moe_layers")
+    out = {
+        "steps": len(steps),
+        "expert_layers": layers,
+        "pairs_per_layer": pairs / layers,
+        "pad_rows_pct": 100.0 * (rows - pairs) / rows if rows else None,
+        "max_over_mean_load": mean("moe_load_max") / mean("moe_load_mean"),
+    }
+    # tokens a step, from the record's own rate (text models)
+    tokens = [float(r["tokens_per_sec"]) * float(r["step_time"])
+              for r in steps if r.get("tokens_per_sec") and r.get("step_time")]
+    if tokens:
+        out["pairs_per_token"] = (
+            pairs / layers / (sum(tokens) / len(tokens)))
+    return out
+
+
 def summarize_run(rs: RunStream, skip: int = 1) -> dict:
     """Everything `obs summary` prints, as one JSON-able dict.
 
@@ -688,6 +718,7 @@ def summarize_run(rs: RunStream, skip: int = 1) -> dict:
         "io_stall": io_stall_summary(rs),
         "serving": serving_summary(rs),
         "efficiency": efficiency_summary(rs, skip=skip),
+        "experts": experts_summary(rs),
         "events": dict(sorted(events_by_type.items())),
         # deployment transitions (serving/router.py, docs/serving.md
         # "Deployment lifecycle"): every swap/canary/promote/rollback of
@@ -1050,6 +1081,16 @@ def render_summary(summary: dict, manifest: Optional[dict] = None) -> str:
                     f"{row['latency_ms']:7.2f}ms  {dom_s:<22} "
                     f"{row.get('version') or '-'}"
                 )
+    moe = summary.get("experts")
+    if moe:
+        line = (f"experts: {moe['pairs_per_layer']:.0f} pairs a layer a step"
+                f" over {moe['expert_layers']:.0f} layers")
+        if moe.get("pairs_per_token") is not None:
+            line += f" ({moe['pairs_per_token']:.3f} a token)"
+        line += (f" · fullest expert {moe['max_over_mean_load']:.2f} x the "
+                 f"mean · {moe['pad_rows_pct']:.1f}% of the grouped matmul's "
+                 "rows are padding")
+        lines.append(line)
     eff = summary.get("efficiency")
     if eff:
         mfu = eff.get("mfu") or {}

@@ -17,6 +17,12 @@ import optax
 # data.text produces labels with this value; keep it the single source.
 IGNORE_INDEX = -1
 
+#: The flax collection a model may ``sow`` scalars into during the training
+#: forward pass (models/lfm2.py: the expert layers' pairs, rows, fullest
+#: expert). The train step sums each name over the modules that sowed it
+#: and reports it beside the loss, so it lands in every step record.
+COUNTERS = "counters"
+
 
 def cross_entropy_loss(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     """Mean softmax cross-entropy over integer labels (torch CrossEntropyLoss)."""

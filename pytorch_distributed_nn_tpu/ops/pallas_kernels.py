@@ -1140,3 +1140,194 @@ def fused_layer_norm(x, gamma, beta, eps=1e-6, out_dtype=None):
         return y.astype(out_dtype)
     y = _fused_ln(x.reshape(N, D), gamma, beta, float(eps), out_dtype)
     return y.reshape(*lead, D)
+
+
+# ---------------------------------------------------------------------------
+# Grouped matmul (expert FFN, models/lfm2.py)
+# ---------------------------------------------------------------------------
+#
+# ``x (R, K)`` holds the rows of G groups one after the other, each group
+# starting on a row tile (``group_tiles`` lays them out), and ``w (G, K, N)``
+# one matrix a group: ``out[r] = x[r] @ w[group of r]``. R is a static bound
+# (every pair a dropless router could send here); the rows really routed
+# fill the first ``active`` tiles and the grid steps past them compute
+# nothing and fetch nothing (their block indices are clamped to the last
+# active tile's), so the device's work follows the rows, not the bound.
+# Rows past the last active tile are NOT written: callers read a row only
+# through an index that says it is real.
+
+#: rows a tile; a group is padded to a whole number of them
+GMM_TILE_M = 256
+_GMM_TILE_N = 1024          # preferred columns of a weight block
+_GMM_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"),
+    vmem_limit_bytes=48 * 2 ** 20,
+)
+_TGMM_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=48 * 2 ** 20,
+)
+
+
+def group_tiles(group_sizes, rows: int, tile_m: int = GMM_TILE_M):
+    """Where each group's rows go in a buffer of ``rows`` rows.
+
+    ``group_sizes (G,) int32`` -> ``(starts (G,), meta (rows/tile_m + 1,))``:
+    group g's rows start at row ``starts[g]`` (a multiple of ``tile_m``) and
+    every group owns at least one tile, so that each weight gradient block
+    is visited (an empty group's rows are zeros). ``meta[t]`` is the group
+    tile t belongs to (the last group for the tiles no group owns) and
+    ``meta[-1]`` the number of tiles that are owned: what the kernels
+    prefetch. ``rows`` must be at least ``sum(sizes) + G * tile_m``.
+    """
+    if rows % tile_m:
+        raise ValueError(f"rows={rows} is not a multiple of tile_m={tile_m}")
+    groups = group_sizes.shape[0]
+    tiles = jnp.maximum(1, -(-group_sizes // tile_m)).astype(jnp.int32)
+    ends = jnp.cumsum(tiles)
+    starts = (ends - tiles) * tile_m
+    owner = jnp.searchsorted(
+        ends, jnp.arange(rows // tile_m, dtype=jnp.int32), side="right")
+    meta = jnp.concatenate(
+        [jnp.minimum(owner, groups - 1).astype(jnp.int32), ends[-1:]])
+    return starts, meta
+
+
+def _pick_tile(n: int, prefer: int) -> int:
+    """The largest multiple of 128 that divides ``n`` and is <= prefer
+    (``n`` itself where it has no such divisor: a block may span a dim)."""
+    for t in range(min(prefer, n) // 128 * 128, 0, -128):
+        if n % t == 0:
+            return t
+    return n
+
+
+def _gmm_kernel(meta_ref, x_ref, w_ref, o_ref, *, transpose_rhs: bool):
+    i = pl.program_id(1)
+
+    @pl.when(i < meta_ref[pl.num_programs(1)])
+    def _():
+        contract = (((1,), (1,)), ((), ())) if transpose_rhs else (
+            ((1,), (0,)), ((), ()))
+        o_ref[...] = jax.lax.dot_general(
+            x_ref[...], w_ref[0], contract,
+            preferred_element_type=jnp.float32,
+        ).astype(o_ref.dtype)
+
+
+def _gmm_call(meta, x, w, transpose_rhs: bool, tile_m: int):
+    """``x (R, K) @ w[g] (K, N)`` (``w[g] (N, K)`` transposed) -> (R, N).
+    Grid (column blocks, row tiles), rows innermost: a weight block stays
+    in VMEM while its group's row tiles pass."""
+    R, K = x.shape
+    N = w.shape[1] if transpose_rhs else w.shape[2]
+    tn = _pick_tile(N, _GMM_TILE_N)
+    n_tiles = R // tile_m
+
+    def row(i, meta_ref):
+        return jnp.minimum(i, meta_ref[n_tiles] - 1)
+
+    if transpose_rhs:
+        w_spec = pl.BlockSpec(
+            (1, tn, K), lambda j, i, m: (m[row(i, m)], j, 0))
+    else:
+        w_spec = pl.BlockSpec(
+            (1, K, tn), lambda j, i, m: (m[row(i, m)], 0, j))
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
+        out_shape=jax.ShapeDtypeStruct((R, N), x.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(N // tn, n_tiles),
+            in_specs=[
+                pl.BlockSpec((tile_m, K), lambda j, i, m: (row(i, m), 0)),
+                w_spec,
+            ],
+            out_specs=pl.BlockSpec(
+                (tile_m, tn), lambda j, i, m: (row(i, m), j)),
+        ),
+        compiler_params=_GMM_PARAMS,
+        interpret=_interpret(),
+    )(meta, x, w)
+
+
+def _tgmm_kernel(meta_ref, first_ref, x_ref, g_ref, o_ref):
+    i = pl.program_id(2)
+
+    @pl.when(first_ref[i] == 1)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(i < meta_ref[pl.num_programs(2)])
+    def _():
+        o_ref[0] += jax.lax.dot_general(
+            x_ref[...], g_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+
+def _tgmm_call(meta, x, g, groups: int, tile_m: int):
+    """Weight gradients: ``out[g] = x_g^T @ g_g`` over group g's rows,
+    (G, K, N) float32. Grid (K blocks, N blocks, row tiles), rows innermost:
+    a group's block accumulates in VMEM while its row tiles pass."""
+    R, K = x.shape
+    N = g.shape[1]
+    tk, tn = _pick_tile(K, _GMM_TILE_N), _pick_tile(N, _GMM_TILE_N)
+    n_tiles = R // tile_m
+    # 1 where a tile is the first of its group: its block starts from zero
+    owner = meta[:n_tiles]
+    first = jnp.concatenate([
+        jnp.ones((1,), jnp.int32),
+        (owner[1:] != owner[:-1]).astype(jnp.int32)])
+
+    def row(i, meta_ref):
+        return jnp.minimum(i, meta_ref[n_tiles] - 1)
+
+    return pl.pallas_call(
+        _tgmm_kernel,
+        out_shape=jax.ShapeDtypeStruct((groups, K, N), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(K // tk, N // tn, n_tiles),
+            in_specs=[
+                pl.BlockSpec((tile_m, tk),
+                             lambda a, b, i, m, f: (row(i, m), a)),
+                pl.BlockSpec((tile_m, tn),
+                             lambda a, b, i, m, f: (row(i, m), b)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, tk, tn), lambda a, b, i, m, f: (m[row(i, m)], a, b)),
+        ),
+        compiler_params=_TGMM_PARAMS,
+        interpret=_interpret(),
+    )(meta, first, x, g)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _grouped_matmul(x, w, meta, tile_m):
+    return _gmm_call(meta, x, w.astype(x.dtype), False, tile_m)
+
+
+def _grouped_matmul_fwd(x, w, meta, tile_m):
+    return _grouped_matmul(x, w, meta, tile_m), (x, w, meta)
+
+
+def _grouped_matmul_bwd(tile_m, res, g):
+    x, w, meta = res
+    # dx is the forward kernel on the transposed weight blocks
+    dx = _gmm_call(meta, g, w.astype(g.dtype), True, tile_m)
+    dw = _tgmm_call(meta, x, g, w.shape[0], tile_m)
+    return dx, dw.astype(w.dtype), None
+
+
+_grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def grouped_matmul(x, w, meta, tile_m: int = GMM_TILE_M):
+    """``out[r] = x[r] @ w[group of r]`` for rows laid out by
+    ``group_tiles`` (``meta`` is its second result). x (R, K) in the
+    compute dtype, w (G, K, N) in any float dtype (cast to x's here, so the
+    weight gradient comes back in w's), out (R, N) in x's dtype with
+    float32 accumulation. Differentiable in x and w. Rows past the last
+    owned tile are not written, in the result or in dx."""
+    return _grouped_matmul(x, w, meta, tile_m)

@@ -39,7 +39,7 @@ class TrainConfig:
     """
 
     network: str = "ResNet18"
-    dataset: str = "Cifar10"  # image dataset, or "MLMSynth" for text models
+    dataset: str = "Cifar10"  # image dataset; text: "MLMSynth" | "NextTokenSynth"
     batch_size: int = 128
     test_batch_size: int = 1000
     lr: float = 0.01

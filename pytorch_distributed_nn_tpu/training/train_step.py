@@ -33,7 +33,11 @@ from flax import struct
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from pytorch_distributed_nn_tpu.ops.metrics import cross_entropy_loss, topk_accuracy
+from pytorch_distributed_nn_tpu.ops.metrics import (
+    COUNTERS,
+    cross_entropy_loss,
+    topk_accuracy,
+)
 from pytorch_distributed_nn_tpu.parallel.grad_sync import GradSync
 from pytorch_distributed_nn_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
@@ -120,6 +124,21 @@ def _classification_metrics(logits, labels):
     return {"acc1": acc1, "acc5": acc5}
 
 
+def _counter_metrics(mutated) -> dict:
+    """{name: sum over every module that sowed ``name``} of the
+    ``counters`` collection; empty for a model that sows none."""
+    out: dict = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+        mutated.get(COUNTERS, {})
+    ):
+        name = next(
+            k.key for k in reversed(path)
+            if isinstance(k, jax.tree_util.DictKey)
+        )
+        out[name] = out.get(name, 0.0) + leaf
+    return out
+
+
 def _bn_reduce(batch_stats, mode: str, axis_name: str):
     if not batch_stats:
         return batch_stats
@@ -191,16 +210,19 @@ def build_train_step(
                 {"params": params, "batch_stats": stats},
                 images,
                 train=True,
-                mutable=["batch_stats"],
+                mutable=["batch_stats", COUNTERS],
                 rngs={"dropout": drng},
             )
-            return loss_fn(out, labels), (out, mutated.get("batch_stats", {}))
+            return loss_fn(out, labels), (
+                out, mutated.get("batch_stats", {}), _counter_metrics(mutated)
+            )
 
         if grad_accum == 1:
-            (loss, (logits, new_stats)), grads = jax.value_and_grad(
+            (loss, (logits, new_stats, counted)), grads = jax.value_and_grad(
                 forward, has_aux=True
             )(state.params, state.batch_stats, images, labels, dropout_rng)
-            metrics = {"loss": loss, **metrics_fn(logits, labels)}
+            metrics = {"loss": loss, **metrics_fn(logits, labels),
+                       **jax.lax.stop_gradient(counted)}
             return _finish(state, grads, new_stats, metrics, sync_rng)
 
         n = images.shape[0]
@@ -266,7 +288,8 @@ def build_train_step(
             def body(carry, mb):
                 stats, gsum = carry
                 im, lb, i = mb
-                (loss, (logits, stats_new)), g = jax.value_and_grad(
+                # (a model's counters are not carried through microbatches)
+                (loss, (logits, stats_new, _)), g = jax.value_and_grad(
                     forward, has_aux=True
                 )(state.params, stats, im, lb,
                   jax.random.fold_in(dropout_rng, i))

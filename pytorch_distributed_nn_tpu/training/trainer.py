@@ -20,7 +20,7 @@ import jax
 import numpy as np
 
 from pytorch_distributed_nn_tpu.data import DataLoader, load_dataset
-from pytorch_distributed_nn_tpu.data.text import MLMBatches, MLMLoader
+from pytorch_distributed_nn_tpu.data.text import MLMLoader, TEXT_DATASETS
 from pytorch_distributed_nn_tpu.models import (
     build_model,
     input_spec,
@@ -228,14 +228,15 @@ class Trainer:
 
         num_classes = 100 if c.dataset == "Cifar100" else 10
         dtype = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[c.dtype]
-        if self.is_text and c.dataset != "MLMSynth":
+        if self.is_text and c.dataset not in TEXT_DATASETS:
             raise ValueError(
                 f"text model {c.network!r} requires dataset='MLMSynth' "
-                f"(got {c.dataset!r})"
+                f"or 'NextTokenSynth' (got {c.dataset!r})"
             )
-        if not self.is_text and c.dataset == "MLMSynth":
+        if not self.is_text and c.dataset in TEXT_DATASETS:
             raise ValueError(
-                f"dataset='MLMSynth' requires a text model (got {c.network!r})"
+                f"dataset={c.dataset!r} requires a text model "
+                f"(got {c.network!r})"
             )
         model_kw = {"dtype": dtype}
         if self.is_text and c.vocab_size is not None:
@@ -693,7 +694,7 @@ class Trainer:
                 )
             else:
                 self.train_loader = MLMLoader(
-                    MLMBatches(
+                    TEXT_DATASETS[c.dataset](
                         vocab_size=self.vocab_size, seq_len=self.seq_len,
                         batch_size=c.batch_size, seed=c.seed,
                         mask_prob=c.mask_prob, branching=c.corpus_branching,
@@ -705,7 +706,7 @@ class Trainer:
                 c.test_batch_size - c.test_batch_size % self.n_workers,
             )
             self.test_loader = MLMLoader(
-                MLMBatches(
+                TEXT_DATASETS[c.dataset](
                     vocab_size=self.vocab_size, seq_len=self.seq_len,
                     batch_size=test_bs, seed=c.seed + 10_000,
                     mask_prob=c.mask_prob, branching=c.corpus_branching,

@@ -162,3 +162,46 @@ def test_fused_resnet18_step_compiles_lean_for_v5e(one_chip_mesh, cifar_prep):
     images, labels, idx, key = _loader_args(mesh, held_shape)
     compiled = fused.lower(state, images, labels, idx, key, key).compile()
     _assert_prep_is_cheap_on_the_chip(compiled, image_shape)
+
+
+def test_grouped_matmul_compiles_for_v5e_at_the_lfm2_cells_widths(
+        topo, monkeypatch):
+    """The expert layer's three Mosaic kernels (forward, dx on the
+    transposed weight blocks, the weight gradient) at the widths of cell
+    lfm2_8b_a1b_ep4_b2_L8192: 8 experts of 2048 x 3584 and 1792 x 2048 over
+    the dropless bound of 4 x 16,384 rows. Interpret mode cannot see a tile
+    Mosaic refuses or a block that does not fit VMEM."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from pytorch_distributed_nn_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    one = SingleDeviceSharding(topo.devices[0])
+    tile, experts, d, f = pk.GMM_TILE_M, 8, 2048, 1792
+    rows = 4 * 16384 + experts * tile
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def loss(x, w13, w2, meta):
+        h = pk.grouped_matmul(x, w13, meta, tile)
+        h = jax.nn.silu(h[:, :f]) * h[:, f:]
+        y = pk.grouped_matmul(h, w2, meta, tile)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        shape((rows, d), jnp.bfloat16), shape((experts, d, 2 * f), jnp.float32),
+        shape((experts, f, d), jnp.float32),
+        shape((rows // tile + 1,), jnp.int32)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # forward twice (the second call's input needs the first's result),
+    # dx twice, dw twice
+    assert len(calls) == 6
+    outputs = sorted(_INSTRUCTION.match(c.strip()).group("dtype", "dims")
+                     for c in calls)
+    assert outputs.count(("f32", f"{experts},{d},{2 * f}")) == 1
+    assert outputs.count(("f32", f"{experts},{f},{d}")) == 1
+    assert outputs.count(("bf16", f"{rows},{d}")) == 2    # y and dx
