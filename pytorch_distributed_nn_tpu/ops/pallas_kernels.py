@@ -17,7 +17,11 @@ zoo. These kernels target the two places where hand-fusion beats stock XLA:
   move them through a third grid dimension with scratch accumulators, so
   L is bounded by HBM (clean full-gradient timings to L=32768 on one
   v5e chip; L=65536 executes but its only timing capture was
-  DCE-tainted — PERF.md "long-context" notes).
+  DCE-tainted — PERF.md "long-context" notes). Causal sweeps end
+  (forward, dq) or start (dkv) at the diagonal in both families: the
+  resident loops take their bounds from `_causal_sweep`, the streamed
+  grids skip the same blocks with `pl.when`; only the blocks the
+  diagonal crosses compute scores they then mask.
   Registered as a model attention impl (``attn_fn=pallas_attention``).
 - **Int8 stochastic-rounding quantization**: `quantize_int8_scaled` is the
   quantize step of the int8 gradient collective — ops/compression.py calls
@@ -89,6 +93,27 @@ def _block_scores(q_blk, k_blk, bias_row, causal, q0, k0, scale):
     return s
 
 
+def _causal_sweep(causal: bool, j, own: int, swept: int, n: int,
+                  own_is_query: bool):
+    """Blocks ``[lo, hi)`` of the swept operand that program ``j`` needs.
+
+    The single home of the rule "a (query block, key block) pair counts
+    iff it holds at least one ``q_pos >= k_pos`` score". ``j`` indexes
+    blocks of ``own`` rows, the sweep runs over ``n`` blocks of ``swept``
+    rows. A query block (forward, dq) needs the key blocks that start at
+    or before its last row; a key block (dkv) needs the query blocks whose
+    last row reaches its first column. ``hi <= n`` needs no clamp: the
+    last own row is < L = n * swept. Not causal: the Python ints
+    ``(0, n)``, so the loop lowers with a static trip count. ``j`` may be
+    a traced program index or, in tests, a Python int.
+    """
+    if not causal:
+        return 0, n
+    if own_is_query:
+        return 0, (j * own + own - 1) // swept + 1
+    return (j * own) // swept, n
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *,
                       block_k: int, causal: bool, q_block: int,
@@ -96,10 +121,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
     """Grid (B*H, L/bq, L/bk), K-block innermost: K/V STREAM through VMEM
     as (bk, D) grid blocks while the (o, m, l) running state lives in
     scratch across the kb sweep. Nothing full-length is ever VMEM-resident,
-    so sequence length is bounded by HBM, not VMEM (the previous
-    resident-K/V design hit an opaque Mosaic abort at L>=8192 backward /
-    L>=32768 forward). Also emits the per-row log-sum-exp (m + log l) —
-    the residual the blockwise backward needs.
+    so sequence length is bounded by HBM, not VMEM. Also emits the
+    per-row log-sum-exp (m + log l) — the residual the blockwise backward
+    needs.
     """
     j = pl.program_id(1)
     kb = pl.program_id(2)
@@ -137,7 +161,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         m_ref[:] = m_new
 
     if causal:
-        # blocks strictly above the diagonal contribute nothing
+        # blocks strictly above the diagonal contribute nothing (the rule
+        # of _causal_sweep: kb < hi)
         @pl.when(kb * block_k <= j * q_block + q_block - 1)
         def _():
             compute()
@@ -380,6 +405,9 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, mask_ref, lse_ref, delta_ref,
 # grid overhead) but VMEM-bounded: past L~8k the resident copies plus
 # double buffering abort the Mosaic compiler, so _flash_forward /
 # _flash_backward dispatch to the streamed kernels above that point.
+# When causal the loop covers only _causal_sweep's blocks (a trip count
+# that depends on the program's index: (n + 1) / 2n of the n x n blocks);
+# when not, its bounds are the Python ints 0 and n: a static trip count.
 
 _RESIDENT_MAX_L = 8192
 
@@ -392,7 +420,7 @@ def _flash_fwd_kernel_res(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *,
     q = q_ref[0]  # (BQ, D)
     BQ, D = q.shape
     L = k_ref.shape[1]
-    nk = L // block_k
+    lo, hi = _causal_sweep(causal, j, q_block, block_k, L // block_k, True)
 
     def body(kb, carry):
         o, m, l = carry
@@ -414,7 +442,7 @@ def _flash_fwd_kernel_res(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *,
     o = jnp.zeros((BQ, D), jnp.float32)
     m = jnp.full((BQ, 1), _NEG_INF, jnp.float32)
     l = jnp.zeros((BQ, 1), jnp.float32)
-    o, m, l = jax.lax.fori_loop(0, nk, body, (o, m, l))
+    o, m, l = jax.lax.fori_loop(lo, hi, body, (o, m, l))
     o_ref[0] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     lse_ref[0] = m + jnp.log(jnp.maximum(l, 1e-30))
 
@@ -427,7 +455,7 @@ def _flash_dq_kernel_res(q_ref, k_ref, v_ref, mask_ref, lse_ref, delta_ref,
     q = q_ref[0]  # (BQ, D)
     BQ, D = q.shape
     L = k_ref.shape[1]
-    nk = L // block_k
+    lo, hi = _causal_sweep(causal, j, q_block, block_k, L // block_k, True)
     lse = jnp.broadcast_to(lse_ref[0], (block_k, BQ)).T    # (BQ, BK) f32
     delta = jnp.broadcast_to(delta_ref[0], (block_k, BQ)).T  # (BQ, BK)
     do = do_ref[0].astype(jnp.float32)  # (BQ, D)
@@ -449,7 +477,7 @@ def _flash_dq_kernel_res(q_ref, k_ref, v_ref, mask_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         )
 
-    dq = jax.lax.fori_loop(0, nk, body, jnp.zeros((BQ, D), jnp.float32))
+    dq = jax.lax.fori_loop(lo, hi, body, jnp.zeros((BQ, D), jnp.float32))
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
@@ -461,7 +489,8 @@ def _flash_dkv_kernel_res(k_ref, v_ref, q_ref, mask_ref, lse_ref, delta_ref,
     k = k_ref[0]  # (BK, D)
     BK, D = k.shape
     L = q_ref.shape[1]
-    nq = L // block_q
+    lo, hi = _causal_sweep(causal, j, k_block, block_q, L // block_q, False)
+
     def body(qb, carry):
         dk, dv = carry
         q_blk = q_ref[0, pl.ds(qb * block_q, block_q), :]  # (BQ, D)
@@ -491,7 +520,7 @@ def _flash_dkv_kernel_res(k_ref, v_ref, q_ref, mask_ref, lse_ref, delta_ref,
         return dk, dv
 
     dk, dv = jax.lax.fori_loop(
-        0, nq, body,
+        lo, hi, body,
         (jnp.zeros((BK, D), jnp.float32), jnp.zeros((BK, D), jnp.float32)),
     )
     dk_ref[0] = dk.astype(dk_ref.dtype)
@@ -506,10 +535,10 @@ def _flash_backward(q, k, v, mask, out, lse, g, causal: bool,
     recomputed the full score matrix — O(L²) memory, defeating the flash
     forward's point for training). delta = rowsum(dO ⊙ O) is the standard
     softmax-VJP rank-1 correction, computed outside the kernels (one fused
-    O(L·D) pass). Round 3 moved every full-length operand out of VMEM:
-    K/V (dq) and Q/dO (dkv) stream as grid blocks, and the per-row
-    lse/delta vectors ride lane-major (BH, 1, L) tiles — the previous
-    resident design aborted the Mosaic compiler at L>=8192.
+    O(L·D) pass). Through L = _RESIDENT_MAX_L the swept operands (K/V for
+    dq, Q/dO for dkv) are VMEM-resident; past it they stream as grid
+    blocks. Either way the per-row lse/delta vectors ride lane-major
+    (BH, 1, L) tiles.
     """
     B, L, H, D = q.shape
     scale = 1.0 / np.sqrt(D)

@@ -8,6 +8,7 @@ test that needs it lives in this one file: one process at a time may load
 libtpu, and pytest-xdist hands a file to one worker.
 """
 
+import json
 import math
 import os
 import re
@@ -205,3 +206,40 @@ def test_grouped_matmul_compiles_for_v5e_at_the_lfm2_cells_widths(
     assert outputs.count(("f32", f"{experts},{d},{2 * f}")) == 1
     assert outputs.count(("f32", f"{experts},{f},{d}")) == 1
     assert outputs.count(("bf16", f"{rows},{d}")) == 2    # y and dx
+
+
+def test_causal_flash_backward_compiles_for_v5e_at_the_lfm2_cells_shape(
+        topo, monkeypatch):
+    """The attention layer of cell lfm2_8b_a1b_ep4_b2_L8192 (2 x 8192 x 32
+    heads of 64, bfloat16, causal): the resident kernels' sweeps end or
+    start at the diagonal, so their trip counts are dynamic, which only
+    the real Mosaic compiler can refuse. The benchmark tells the three
+    calls apart by their operand and result counts (the `kinds` of the
+    configuration's `kernels.flash_attention`): a call that fits none is
+    sorted as `unknown` and the cell's causal_flash_attention_roofline
+    falls silent. Read here with the benchmark's own parser."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark import trace
+    from pytorch_distributed_nn_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    x = jax.ShapeDtypeStruct((2, 8192, 32, 64), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+
+    def loss(q, k, v):
+        out = pk.pallas_attention(q, k, v, None, causal=True)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(x, x, x).compile()
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                           "configs", "lfm2_8b_a1b_ep4.json")) as f:
+        family = json.load(f)["kernels"]["flash_attention"]
+    # here the calls carry no flax module's name: sort by the counts alone
+    kernels = {"flash_attention": {**family, "match": ""}}
+    kinds = [trace.classify_kernel(trace.parse_op(line.strip()), kernels)[1]
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(kinds) == sorted(family["calls_per_step"])  # one of each
