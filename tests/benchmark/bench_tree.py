@@ -1,9 +1,10 @@
-"""A copy of the benchmark's tree with two throw-away cells ADDED — a
+"""A copy of the benchmark's tree with three throw-away cells ADDED — a
 configuration (LeNet on MNIST-shaped synthetic data) with its plain
 reference and FLOPs function, a mix, and a per-layer metric with a reader
-of its own; and a second configuration that is cut to one chip
-(``cut_rule``) — as files and ``BENCHMARK.json`` entries only. No file that
-is there is edited: that is what a later PR is allowed to do."""
+of its own; and two more configurations that are cut to one chip
+(``cut_rule``), one of them with per-layer patterns under its source's own
+key names — as files and ``BENCHMARK.json`` entries only. No file that is
+there is edited: that is what a later PR is allowed to do."""
 
 import json
 import os
@@ -55,10 +56,48 @@ CUT = {
         "leading_dense_layers": 1,
     },
 }
-#: the second throw-away: the files of the first, and that cut written
-#: into it (the sizes are a fixture for the rule; nothing runs them)
-CUT_CONFIG = {**CONFIG, **CUT, "name": "lenet_mnist_cut",
-              "model": {**CONFIG["model"], **CUT["model"]}}
+#: A cut whose source spells its counts otherwise (the keys of the catalog's
+#: window-and-global expert decoder): the routed experts under a name of
+#: its own and two per-layer lists that only their shape tells, so the file
+#: says which published layers it keeps. Eight chips share each layer: 8 of
+#: 64 experts, 18,992 of 151,936 rows, one whole period of four layers (a
+#: global layer without rotary positions, then three window layers).
+_PATTERNED_WIDTHS = {
+    "hidden_size": 2560, "head_dim": 128, "num_attention_heads": 28,
+    "num_key_value_heads": 4, "moe_ffn_hidden_size": 768,
+    "moe_num_active_primary_experts": 6, "sliding_window_size": 4096,
+    "max_position_embeddings": 16384,
+}
+CUT_PATTERNED = {
+    "reduced": ["num_hidden_layers", "moe_num_primary_experts", "vocab_size",
+                "rope_layout", "sliding_window_layout"],
+    "model": {**_PATTERNED_WIDTHS, "num_hidden_layers": 4,
+              "moe_num_primary_experts": 8, "vocab_size": 18992,
+              "rope_layout": [0, 1, 1, 1],
+              "sliding_window_layout": [0, 1, 1, 1]},
+    "published": {**_PATTERNED_WIDTHS, "num_hidden_layers": 52,
+                  "moe_num_primary_experts": 64, "vocab_size": 151936,
+                  "rope_layout": [0, 1, 1, 1] * 13,
+                  "sliding_window_layout": [0, 1, 1, 1] * 13},
+    "deployment": {
+        "chips_per_layer": 8,
+        "how": "experts and vocabulary rows divided eight ways, attention "
+               "whole on every chip; the layers left out lie on further "
+               "chips, as the stages of a pipeline",
+        "layer_period": 4,
+        "leading_dense_layers": 0,
+        "kept_layer_ids": [0, 1, 2, 3],
+    },
+}
+#: the further throw-aways: the files of the first, and a cut written into
+#: each (the sizes are a fixture for the rule; nothing runs them)
+CUT_CONFIGS = {
+    name: {**CONFIG, **cut, "name": name,
+           "model": {**CONFIG["model"], **cut["model"]}}
+    for name, cut in (("lenet_mnist_cut", CUT),
+                      ("lenet_mnist_patterned", CUT_PATTERNED))}
+CELLS = {"lenet_tiny": "lenet_mnist", "lenet_cut_tiny": "lenet_mnist_cut",
+         "lenet_patterned_tiny": "lenet_mnist_patterned"}
 
 REFERENCE = '''
     """LeNet loss in plain float32 jax.numpy (a test's throw-away)."""
@@ -117,7 +156,7 @@ READER = '''
 
 def add_cell(root: str) -> str:
     """Copy BENCHMARK.json + benchmark/ to ``root`` and add the cells
-    ``lenet_tiny`` and ``lenet_cut_tiny``. Returns ``root``."""
+    of ``CELLS``. Returns ``root``."""
     shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
     shutil.copytree(
         os.path.join(REPO, "benchmark"), os.path.join(root, "benchmark"),
@@ -129,8 +168,8 @@ def add_cell(root: str) -> str:
             f.write(textwrap.dedent(text).lstrip("\n"))
 
     write("benchmark/configs/lenet_mnist.json", json.dumps(CONFIG, indent=2))
-    write("benchmark/configs/lenet_mnist_cut.json",
-          json.dumps(CUT_CONFIG, indent=2))
+    for name, config in CUT_CONFIGS.items():
+        write(f"benchmark/configs/{name}.json", json.dumps(config, indent=2))
     write("benchmark/reference/lenet_mnist.py", REFERENCE)
     write("benchmark/flops/lenet_mnist.py", FLOPS)
     write("benchmark/mixes/train_short.json", json.dumps({
@@ -151,12 +190,12 @@ def add_cell(root: str) -> str:
         "name": "lenet_mnist", "source": CONFIG["source"],
         "file": "benchmark/configs/lenet_mnist.json", "reduced": [],
         "why": "a throw-away for the tests"})
-    bench["configs"].append({
-        "name": "lenet_mnist_cut", "source": CONFIG["source"],
-        "file": "benchmark/configs/lenet_mnist_cut.json",
-        "reduced": CUT["reduced"], "why": "a throw-away for the tests"})
-    for cell, config in (("lenet_tiny", "lenet_mnist"),
-                         ("lenet_cut_tiny", "lenet_mnist_cut")):
+    for name, config in CUT_CONFIGS.items():
+        bench["configs"].append({
+            "name": name, "source": CONFIG["source"],
+            "file": f"benchmark/configs/{name}.json",
+            "reduced": config["reduced"], "why": "a throw-away for the tests"})
+    for cell, config in CELLS.items():
         bench["workloads"].append({
             "name": cell, "config": config, "traffic": "train_short",
             "chips": 1, "why": "a throw-away for the tests"})

@@ -104,16 +104,63 @@ def test_a_cell_is_added_as_files_and_entries_only(tmp_path):
     # the cells that were there still resolve, and do not see the new metric
     old = manifest.resolve("resnet18_b4096", root)
     assert "loss_at_close" not in [m["name"] for m in old.per_layer]
-    # a configuration that is cut to one chip is files and entries too
-    cut = manifest.resolve("lenet_cut_tiny", root)
-    entry = _config_entry(manifest.load(root), cut.config_name)
-    assert entry["reduced"] and cut_rule.problems(cut.config, entry) == []
+    # a configuration that is cut to one chip is files and entries too,
+    # and so is one whose source spells its layer pattern its own way
+    for name in ("lenet_cut_tiny", "lenet_patterned_tiny"):
+        cut = manifest.resolve(name, root)
+        entry = _config_entry(manifest.load(root), cut.config_name)
+        assert entry["reduced"]
+        assert cut_rule.problems(cut.config, entry) == []
 
 
-def _cut(**changes):
-    """``bench_tree.CUT`` with ``block.key=value`` changes (``None``
+#: A cut whose source gives its layer pattern as two lists of layer ids,
+#: counted from 1, inside a nested block (the keys of the catalog's
+#: linear-attention expert decoder with 27 layers): 32 chips share each
+#: layer; kept are the leading dense layer and one whole period of four.
+_NESTED_WIDTHS = {"hidden_size": 2304, "moe_intermediate_size": 1024,
+                  "num_experts_per_token": 8, "num_shared_experts": 1,
+                  "first_k_dense_replace": 1, "kv_lora_rank": 512}
+_NESTED_BLOCK = {"head_dim": 128, "num_heads": 32, "short_conv_kernel_size": 4}
+CUT_NESTED = {
+    "reduced": ["num_hidden_layers", "linear_attn_config", "num_experts",
+                "vocab_size"],
+    "model": {**_NESTED_WIDTHS, "num_hidden_layers": 5, "num_experts": 8,
+              "vocab_size": 20480,
+              "linear_attn_config": {**_NESTED_BLOCK,
+                                     "full_attn_layers": [4],
+                                     "kda_layers": [1, 2, 3, 5]}},
+    "published": {**_NESTED_WIDTHS, "num_hidden_layers": 27,
+                  "num_experts": 256, "vocab_size": 163840,
+                  "linear_attn_config": {
+                      **_NESTED_BLOCK,
+                      "full_attn_layers": [4, 8, 12, 16, 20, 24, 27],
+                      "kda_layers": [i for i in range(1, 27) if i % 4]}},
+    "deployment": {**bench_tree.CUT["deployment"], "chips_per_layer": 32,
+                   "layer_ids_from": 1, "kept_layer_ids": [1, 2, 3, 4, 5]},
+}
+#: A cut whose source gives its layer pattern as a string, one character a
+#: layer: the second period of six is kept, no dense layer leads.
+CUT_STRING = {
+    "reduced": ["num_hidden_layers", "hybrid_override_pattern",
+                "n_routed_experts"],
+    "model": {"hidden_size": 2688, "num_hidden_layers": 6,
+              "hybrid_override_pattern": "MEME*E", "n_routed_experts": 16},
+    "published": {"hidden_size": 2688, "num_hidden_layers": 24,
+                  "hybrid_override_pattern": "MEME*E" * 4,
+                  "n_routed_experts": 128},
+    "deployment": {**bench_tree.CUT["deployment"], "layer_period": 6,
+                   "leading_dense_layers": 0,
+                   "kept_layer_ids": [6, 7, 8, 9, 10, 11]},
+}
+
+
+PATTERNED = bench_tree.CUT_PATTERNED
+
+
+def _cut(base=bench_tree.CUT, **changes):
+    """``base`` (a cut file) with ``block__key=value`` changes (``None``
     drops the key or the block), and the entry that agrees with it."""
-    config = copy.deepcopy(bench_tree.CUT)
+    config = copy.deepcopy(base)
     for path, value in changes.items():
         *blocks, key = path.split("__")
         target = config
@@ -145,11 +192,78 @@ def _cut(**changes):
     (*_cut(deployment__chips_per_layer=None), "`chips_per_layer`"),
     (*_cut(published=None), "published missing"),
     (_cut()[0], {"reduced": []}, "BENCHMARK.json disagrees"),
+    # a count is told by path, by shape or by name (PR 37)
+    (*_cut(PATTERNED), None),
+    (*_cut(CUT_NESTED), None),
+    (*_cut(CUT_STRING), None),
+    (*_cut(deployment__kept_layer_ids=[1, 2, 3, 4, 5]), None),
+    (*_cut(reduced=bench_tree.CUT["reduced"] + [
+               "n_dense_first_layers", "swa_num_attention_heads",
+               "swa_num_key_value_heads"],
+           model__n_dense_first_layers=1, published__n_dense_first_layers=2,
+           model__swa_num_attention_heads=8,
+           published__swa_num_attention_heads=64,
+           model__swa_num_key_value_heads=2,
+           published__swa_num_key_value_heads=16), None),
+    (*_cut(CUT_NESTED, model__linear_attn_config__head_dim=64),
+     "only counts may be named: 'linear_attn_config.head_dim' is a width"),
+    (*_cut(CUT_NESTED, reduced=[k for k in CUT_NESTED["reduced"]
+                                if k != "linear_attn_config"]),
+     "'linear_attn_config.full_attn_layers', 'linear_attn_config.kda_layers'"
+     " differ from published and 'linear_attn_config' is not in reduced"),
+    (*_cut(PATTERNED, model__sliding_window_layout=[1, 1, 1, 1]),
+     "'sliding_window_layout' keeps [1, 1, 1, 1], the published layers 0-3 "
+     "are [0, 1, 1, 1]"),
+    (*_cut(PATTERNED, deployment__kept_layer_ids=[0, 1, 2, 7]),
+     "kept after the 0 leading dense are consecutive"),
+    (*_cut(PATTERNED, deployment__kept_layer_ids=None),
+     "deployment needs `kept_layer_ids`"),
+    (*_cut(CUT_NESTED, deployment__kept_layer_ids=None),
+     "which layers 'linear_attn_config.full_attn_layers', "
+     "'linear_attn_config.kda_layers' keep"),
+    (*_cut(CUT_NESTED, model__linear_attn_config__full_attn_layers=[8]),
+     "'linear_attn_config.full_attn_layers' keeps [8]: a list of layer ids "
+     "is strictly increasing and its ids lie within 1-5"),
+    (*_cut(CUT_NESTED, model__linear_attn_config__kda_layers=[1, 2, 3]),
+     "'linear_attn_config.kda_layers' keeps [1, 2, 3], the published ids "
+     "among layers 1-5, renumbered, are [1, 2, 3, 5]"),
+    (*_cut(CUT_NESTED, deployment__layer_ids_from=None,
+           deployment__kept_layer_ids=[0, 1, 2, 3, 4],
+           model__linear_attn_config__kda_layers=[1, 2, 3]),
+     "'linear_attn_config.full_attn_layers' is neither"),
+    (*_cut(PATTERNED, reduced=PATTERNED["reduced"] + ["mrope_section"],
+           model__mrope_section=[8, 12, 12],
+           published__mrope_section=[16, 24, 24]),
+     "'mrope_section' is neither a per-layer pattern (one entry for each of "
+     "the 52 published layers) nor a list of layer ids"),
+    (*_cut(PATTERNED, model__rope_layout=[0, 1, 1]),
+     "a per-layer pattern has one entry for each of the 4 kept layers"),
+    (*_cut(CUT_STRING, model__hybrid_override_pattern="MEMEME"),
+     "keeps 'MEMEME', the published layers 6-11 are 'MEME*E'"),
+    (*_cut(PATTERNED, deployment__kept_layer_ids=[0, 1, 2]),
+     "kept_layer_ids gives the published index of each of the 4 kept"),
+    *[(*_cut(PATTERNED, reduced=PATTERNED["reduced"] + [key],
+             **{f"model__{key}": kept, f"published__{key}": full}),
+       f"only counts may be named: {key!r} is a width or a setting")
+      for key, kept, full in (("num_shared_experts", 1, 2),
+                              ("moe_num_active_primary_experts", 3, 6),
+                              ("num_nextn_predict_layers", 1, 3),
+                              ("dense_mlp_idx", 1, 2))],
 ], ids=["nothing_reduced", "good_cut", "good_cut_top_level", "a_width",
         "a_width_unlisted", "four_experts", "a_sixteenth_of_the_vocabulary",
         "three_layers_after_the_dense", "under_a_whole_period",
         "listed_but_not_cut", "no_deployment", "no_chips_per_layer",
-        "no_published", "manifest_disagrees"])
+        "no_published", "manifest_disagrees",
+        "good_patterned_cut", "good_nested_id_lists", "good_layer_string",
+        "good_cut_says_its_layers", "the_catalogs_other_spellings",
+        "a_nested_width", "a_nested_block_unlisted",
+        "four_window_layers_no_global", "a_layer_skipped_in_the_period",
+        "a_pattern_with_no_kept_layer_ids", "id_lists_with_no_kept_layer_ids",
+        "an_id_past_the_kept_depth", "ids_not_the_published_ones",
+        "ids_from_one_read_from_nought", "a_list_of_neither_shape",
+        "a_pattern_of_another_length", "a_string_not_the_published_one",
+        "kept_layer_ids_too_few", "shared_experts", "experts_a_token",
+        "prediction_modules", "an_index_or_a_count"])
 def test_reduced_is_held_to_the_guides_floors(config, entry, complaint):
     found = cut_rule.problems(config, entry)
     if complaint is None:
