@@ -606,7 +606,7 @@ def child_kernels() -> int:
         ("flash_resident_L512", (2, 512, 12, 64), 2e-4),
         ("flash_streamed_L16384", (1, 16384, 1, 64), 5e-4),
     ):
-        assert (L > pk._RESIDENT_MAX_L) == ("streamed" in name)
+        assert pk._resident(L, D) == ("resident" in name)
         q, k, v = (rand(i, (B, L, H, D)) for i in range(3))
         mask = jnp.ones((B, L)).at[:, L - L // 8:].set(0.0)
         got = _compiled(flash_loss(pk.pallas_attention), q, k, v, mask)

@@ -33,6 +33,12 @@ from pytorch_distributed_nn_tpu.models.resnet import (
     ResNet110,
     ResNet152,
 )
+from pytorch_distributed_nn_tpu.models.smallthinker import (
+    SmallThinker,
+    SmallThinkerConfig,
+    smallthinker_21b_a3b_ep8,
+    smallthinker_tiny,
+)
 from pytorch_distributed_nn_tpu.models.transformer import (
     BertMLM,
     CausalLM,
@@ -90,6 +96,13 @@ _REGISTRY = {
     # Train as causal LMs (dataset='NextTokenSynth'); no decode mode yet.
     "Lfm2_8B_A1B_EP4": lfm2_8b_a1b_ep4,
     "Lfm2Tiny": lfm2_tiny,
+    # SmallThinker sparse-expert decoder (window layers with rotary
+    # positions and global layers without, a softmax router that reads the
+    # layer's input, ReLU-gated experts, an untied head): one chip's share
+    # of SmallThinker-21BA3B under eight-way expert parallelism at
+    # published widths, and a toy of the same shape. Training only.
+    "SmallThinker_21B_A3B_EP8": smallthinker_21b_a3b_ep8,
+    "SmallThinkerTiny": smallthinker_tiny,
     "VGG11NoBN": vgg11,
     "VGG13NoBN": vgg13,
     "VGG16NoBN": vgg16,
@@ -105,13 +118,16 @@ _DEFAULT_INPUT_SPEC = (32, 32, 3)
 # Text models take (L,) int32 token inputs instead of images; callers branch
 # on membership here (e.g. the trainer and __graft_entry__).
 TEXT_MODELS = {"BertBase", "BertTiny", "GptTiny", "GptMini",
-               "Lfm2_8B_A1B_EP4", "Lfm2Tiny"}
+               "Lfm2_8B_A1B_EP4", "Lfm2Tiny",
+               "SmallThinker_21B_A3B_EP8", "SmallThinkerTiny"}
 INPUT_SPECS["BertBase"] = (512,)
 INPUT_SPECS["BertTiny"] = (128,)
 INPUT_SPECS["GptTiny"] = (64,)
 INPUT_SPECS["GptMini"] = (128,)
 INPUT_SPECS["Lfm2_8B_A1B_EP4"] = (8192,)
 INPUT_SPECS["Lfm2Tiny"] = (64,)
+INPUT_SPECS["SmallThinker_21B_A3B_EP8"] = (16384,)
+INPUT_SPECS["SmallThinkerTiny"] = (64,)
 
 # Causal decoders: artifacts of these networks serve the generative path
 # (serving/generate/) — POST /v1/generate instead of /v1/infer.

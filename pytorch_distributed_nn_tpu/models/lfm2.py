@@ -227,23 +227,29 @@ class GatedMLP(nn.Module):
             nn.silu(gate) * up)
 
 
+def top_k(ranked, values, k: int):
+    """``(sel (T, k) int32, values[sel] (T, k))``: the k largest entries of
+    ``ranked (T, E)`` by repeated argmax (ties go to the lower index), and
+    ``values (T, E)`` at them."""
+    picks = []
+    for _ in range(k):
+        i = jnp.argmax(ranked, axis=-1)
+        picks.append(i)
+        ranked = jnp.where(
+            jax.nn.one_hot(i, values.shape[-1], dtype=bool), -jnp.inf, ranked)
+    sel = jnp.stack(picks, axis=-1).astype(jnp.int32)
+    # values[sel] as a masked sum: its transpose is elementwise, where a
+    # gather's would be a scatter of T x k scalars
+    chosen = sel[..., None] == jnp.arange(values.shape[-1], dtype=jnp.int32)
+    return sel, jnp.sum(jnp.where(chosen, values[:, None, :], 0.0), axis=-1)
+
+
 def route(scores, bias, k: int, scaling: float = 1.0):
     """``scores (T, E)`` float32 sigmoid outputs -> ``(sel (T, k) int32,
     weights (T, k) float32)``: the k largest of ``scores + bias`` (the bias
     enters only the selection; ties go to the lower index), weighted by
     their own scores, normalised over the k selected, times ``scaling``."""
-    biased = scores + bias
-    picks = []
-    for _ in range(k):
-        i = jnp.argmax(biased, axis=-1)
-        picks.append(i)
-        biased = jnp.where(
-            jax.nn.one_hot(i, scores.shape[-1], dtype=bool), -jnp.inf, biased)
-    sel = jnp.stack(picks, axis=-1).astype(jnp.int32)
-    # scores[sel] as a masked sum: its transpose is elementwise, where a
-    # gather's would be a scatter of T x k scalars
-    chosen = sel[..., None] == jnp.arange(scores.shape[-1], dtype=jnp.int32)
-    weights = jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0), axis=-1)
+    sel, weights = top_k(scores + bias, scores, k)
     weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
     return sel, weights * scaling
 
@@ -340,16 +346,24 @@ def _pairs_bwd(res, g):
 pairs_of_rows.defvjp(_pairs_fwd, _pairs_bwd)
 
 
+#: the gate of an expert's FFN: ``W2(gate(W1 x) * W3 x)``
+GATES = {"silu": nn.silu, "relu": nn.relu}
+
+
 class Experts(nn.Module):
     """The held experts' part of ``y = sum_{e in sel} w_e FFN_e(x)`` for a
     routing already decided: dispatch, two grouped matmuls (``w13`` = [W1 |
-    W3] side by side, then ``w2``), combine. Dropless. Sows, for the step's
+    W3] side by side, then ``w2``), combine. Dropless. Shared by every
+    sparse-expert model: ``config`` is read for ``experts_held``,
+    ``hidden_size``, ``moe_intermediate_size`` and ``dtype`` only, and
+    ``gate`` names the FFN's gate. Sows, for the step's
     records (summed there over the expert layers): ``moe_pairs`` (pairs
     computed here), ``moe_rows`` (rows the grouped matmul's tiles covered),
     ``moe_load_max`` / ``moe_load_mean`` (the fullest held expert's pairs,
     the mean one's) and ``moe_layers`` (1: how many layers were summed)."""
 
-    config: Lfm2Config
+    config: Any
+    gate: str = "silu"
 
     @nn.compact
     def __call__(self, tokens, sel, weights):
@@ -373,7 +387,7 @@ class Experts(nn.Module):
             rows = rows_of_tokens(tokens.astype(cfg.dtype), *where)
         with jax.named_scope("moe/experts"):
             h = grouped_matmul(rows, w13, meta)
-            h = nn.silu(h[:, :f]) * h[:, f:]
+            h = GATES[self.gate](h[:, :f]) * h[:, f:]
             out_rows = grouped_matmul(h, w2, meta)
         with jax.named_scope("moe/combine"):
             # rows past the last owned tile are never written: a row is

@@ -94,11 +94,14 @@ def full_attention(
     v: jnp.ndarray,
     mask: Optional[jnp.ndarray],
     causal: bool = False,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Reference softmax attention. q/k/v: (B, L, H, D) → (B, L, H, D).
 
     Softmax statistics accumulate in float32 regardless of input dtype
-    (bf16-safe); matmuls stay in the input dtype for the MXU.
+    (bf16-safe); matmuls stay in the input dtype for the MXU. With
+    ``causal``, a ``window`` keeps to each query the ``window`` keys that
+    end with its own (``ops/pallas_kernels.pallas_attention``'s rule).
     """
     B, Lq, H, D = q.shape
     Lk = k.shape[1]
@@ -110,7 +113,12 @@ def full_attention(
     if causal:
         idx_q = jnp.arange(Lq)[:, None]
         idx_k = jnp.arange(Lk)[None, :]
-        scores = jnp.where(idx_q >= idx_k, scores, -1e30)
+        seen = idx_q >= idx_k
+        if window is not None:
+            seen = seen & (idx_q - idx_k < window)
+        scores = jnp.where(seen, scores, -1e30)
+    elif window is not None:
+        raise ValueError(f"window={window} needs causal=True")
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 

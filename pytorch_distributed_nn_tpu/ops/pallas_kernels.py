@@ -12,16 +12,20 @@ zoo. These kernels target the two places where hand-fusion beats stock XLA:
   saved as the backward residual. Backward: two kernels recompute
   probabilities per block from (q, k, lse) — dq sweeps K/V per Q block,
   dk/dv sweep Q/dO per K block — so training memory is O(L·D), not
-  O(L²). HYBRID dispatch on L: through L=8192 the swept operands are
-  VMEM-resident per program (fastest); past that, streamed-grid variants
-  move them through a third grid dimension with scratch accumulators, so
-  L is bounded by HBM (clean full-gradient timings to L=32768 on one
-  v5e chip; L=65536 executes but its only timing capture was
-  DCE-tainted — PERF.md "long-context" notes). Causal sweeps end
-  (forward, dq) or start (dkv) at the diagonal in both families: the
-  resident loops take their bounds from `_causal_sweep`, the streamed
-  grids skip the same blocks with `pl.when`; only the blocks the
-  diagonal crosses compute scores they then mask.
+  O(L²). HYBRID dispatch on L x head width (`_resident`): through
+  L=8192 at width 64 the swept operands are VMEM-resident per program
+  (fastest); past that, streamed-grid variants move them through a third
+  grid dimension with scratch accumulators, so L is bounded by HBM (clean
+  full-gradient timings to L=32768 on one v5e chip; L=65536 executes but
+  its only timing capture was DCE-tainted — PERF.md "long-context"
+  notes). Causal sweeps end (forward, dq) or start (dkv) at the diagonal
+  in both families, and with a ``window`` (a query sees the ``window``
+  keys that end with its own) they start / end where the band does: the
+  resident loops take their bounds from `_causal_sweep`; the streamed
+  grids are as long as the longest sweep and their index maps place each
+  step's block, so they neither fetch nor compute a block outside it.
+  Only the blocks the diagonal or the band's edge crosses compute scores
+  they then mask.
   Registered as a model attention impl (``attn_fn=pallas_attention``).
 - **Int8 stochastic-rounding quantization**: `quantize_int8_scaled` is the
   quantize step of the int8 gradient collective — ops/compression.py calls
@@ -70,14 +74,20 @@ def _interpret() -> bool:
 
 
 
-def _block_scores(q_blk, k_blk, bias_row, causal, q0, k0, scale):
+def _block_scores(q_blk, k_blk, bias_row, causal, q0, k0, scale,
+                  window=None):
     """Masked f32 score panel shared by all six flash kernels.
 
     q_blk (BQ, D) x k_blk (BK, D) -> s (BQ, BK), plus the additive
     lane-major bias row (1, BK) and, when causal, the (q0 + i >= k0 + j)
-    triangle mask. The single home of the scoring/masking convention —
-    the resident and streamed kernel variants differ only in where their
-    operands and accumulators live.
+    triangle mask; with a ``window`` a query also sees no key further
+    back than ``window - 1`` positions (``q_pos - k_pos < window``: the
+    query's own key and the ``window - 1`` before it). The single home of
+    the scoring/masking convention — the resident and streamed kernel
+    variants differ only in where their operands and accumulators live.
+    A row that sees nothing of a block takes p = 1 there while its
+    running max is still ``_NEG_INF``; the first block that holds a key it
+    sees (its own diagonal block at the latest) rescales that to zero.
     """
     BQ = q_blk.shape[0]
     BK = k_blk.shape[0]
@@ -89,49 +99,93 @@ def _block_scores(q_blk, k_blk, bias_row, causal, q0, k0, scale):
     if causal:
         q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 0)
         k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 1)
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        seen = q_pos >= k_pos
+        if window is not None:
+            seen = seen & (q_pos - k_pos < window)
+        s = jnp.where(seen, s, _NEG_INF)
     return s
 
 
 def _causal_sweep(causal: bool, j, own: int, swept: int, n: int,
-                  own_is_query: bool):
+                  own_is_query: bool, window=None):
     """Blocks ``[lo, hi)`` of the swept operand that program ``j`` needs.
 
     The single home of the rule "a (query block, key block) pair counts
-    iff it holds at least one ``q_pos >= k_pos`` score". ``j`` indexes
+    iff it holds at least one score the mask keeps" (``q_pos >= k_pos``
+    and, with a ``window``, ``q_pos - k_pos < window``). ``j`` indexes
     blocks of ``own`` rows, the sweep runs over ``n`` blocks of ``swept``
     rows. A query block (forward, dq) needs the key blocks that start at
-    or before its last row; a key block (dkv) needs the query blocks whose
-    last row reaches its first column. ``hi <= n`` needs no clamp: the
-    last own row is < L = n * swept. Not causal: the Python ints
-    ``(0, n)``, so the loop lowers with a static trip count. ``j`` may be
-    a traced program index or, in tests, a Python int.
+    or before its last row and, with a window, end at or after its first
+    row less ``window - 1``; a key block (dkv) needs the query blocks
+    whose last row reaches its first column and, with a window, whose
+    first row lies within ``window - 1`` of its last column. The causal
+    ``hi <= n`` needs no clamp: the last own row is < L = n * swept. Not
+    causal: the Python ints ``(0, n)``, so the loop lowers with a static
+    trip count. ``j`` may be a traced program index (in a kernel or in an
+    index map) or a Python int (tests, ``_streamed_sweep``).
     """
     if not causal:
         return 0, n
+    at_least, at_most = ((max, min) if isinstance(j, int)
+                         else (jnp.maximum, jnp.minimum))
     if own_is_query:
-        return 0, (j * own + own - 1) // swept + 1
-    return (j * own) // swept, n
+        lo = 0 if window is None else (
+            at_least(j * own - (window - 1), 0) // swept)
+        return lo, (j * own + own - 1) // swept + 1
+    hi = n if window is None else at_most(
+        (j * own + own - 1 + window - 1) // swept + 1, n)
+    return (j * own) // swept, hi
+
+
+def _streamed_sweep(causal: bool, n_own: int, own: int, swept: int, n: int,
+                    own_is_query: bool, window=None):
+    """``(steps, at)`` for a streamed grid whose ``n_own`` programs each
+    sweep ``_causal_sweep``'s range. ``steps`` is the longest sweep any of
+    them makes, the grid's innermost dimension: ``n``, or under a window
+    its band (``ceil((window - 1) / block) + 1`` blocks at equal block
+    sizes). ``at(j, t)`` is the swept operand's block at step ``t`` of
+    program ``j``, for the index maps: ``lo + t`` while the sweep lasts
+    and its last block from then on, which Pallas does not fetch again —
+    so a causal grid fetches, and not only computes, nothing past the
+    diagonal, and a window's grid nothing outside its band. Not causal:
+    ``(n, t itself)``."""
+    if not causal:
+        return n, lambda j, t: t
+
+    def sweep(j):
+        return _causal_sweep(True, j, own, swept, n, own_is_query, window)
+
+    def at(j, t):
+        lo, hi = sweep(j)
+        return jnp.minimum(lo + t, hi - 1)
+
+    return max(hi - lo for lo, hi in map(sweep, range(n_own))), at
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *,
                       block_k: int, causal: bool, q_block: int,
-                      scale: float):
-    """Grid (B*H, L/bq, L/bk), K-block innermost: K/V STREAM through VMEM
-    as (bk, D) grid blocks while the (o, m, l) running state lives in
-    scratch across the kb sweep. Nothing full-length is ever VMEM-resident,
-    so sequence length is bounded by HBM, not VMEM. Also emits the
-    per-row log-sum-exp (m + log l) — the residual the blockwise backward
-    needs.
+                      scale: float, n_k: int, window=None):
+    """Grid (B*H, L/bq, sweep), the sweep innermost: K/V STREAM through
+    VMEM as (bk, D) grid blocks while the (o, m, l) running state lives in
+    scratch across the sweep. Nothing full-length is ever VMEM-resident,
+    so sequence length is bounded by HBM, not VMEM. Step ``t`` of the
+    sweep holds key block ``lo + t`` of ``_causal_sweep``'s range (the
+    index maps place it); the grid is as long as the longest sweep
+    (``_streamed_sweep``: all ``n_k`` blocks, or a window's band) and a
+    program whose own sweep is shorter idles through the rest. Also
+    emits the per-row log-sum-exp (m + log l) — the residual the
+    blockwise backward needs.
     """
     j = pl.program_id(1)
-    kb = pl.program_id(2)
-    nk = pl.num_programs(2)
+    t = pl.program_id(2)
+    nt = pl.num_programs(2)
     q = q_ref[0]  # (BQ, D)
     BQ, D = q.shape
+    lo, hi = _causal_sweep(causal, j, q_block, block_k, n_k, True, window)
+    kb = lo + t
 
-    @pl.when(kb == 0)
+    @pl.when(t == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
@@ -148,7 +202,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         # sublane->lane relayout compiles pathologically in multi-output
         # kernels.)
         s = _block_scores(q, k_blk, mask_ref[0], causal,
-                          j * q_block, kb * block_k, scale)
+                          j * q_block, kb * block_k, scale, window)
         m = m_ref[:]  # (BQ, 1)
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         corr = jnp.exp(m - m_new)
@@ -161,15 +215,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         m_ref[:] = m_new
 
     if causal:
-        # blocks strictly above the diagonal contribute nothing (the rule
-        # of _causal_sweep: kb < hi)
-        @pl.when(kb * block_k <= j * q_block + q_block - 1)
+        # past the sweep's end (the diagonal) nothing contributes
+        @pl.when(kb < hi)
         def _():
             compute()
     else:
         compute()
 
-    @pl.when(kb == nk - 1)
+    @pl.when(t == nt - 1)
     def _finalize():
         l = jnp.maximum(l_ref[:], 1e-30)
         o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
@@ -202,7 +255,8 @@ def _mask_bh(mask, B, L, H):
     return jnp.repeat(bias, H, axis=0)[:, None, :]
 
 
-def _flash_forward(q, k, v, mask, causal: bool, block_q: int, block_k: int):
+def _flash_forward(q, k, v, mask, causal: bool, block_q: int, block_k: int,
+                   window=None):
     """q/k/v: (B, L, H, D); mask: (B, L) or None → (out, lse).
 
     ``lse`` is the (B*H, L, 1) per-row log-sum-exp residual consumed by the
@@ -218,11 +272,12 @@ def _flash_forward(q, k, v, mask, causal: bool, block_q: int, block_k: int):
     qb, kb, vb = _to_bh(q), _to_bh(k), _to_bh(v)
     mask_bh = _mask_bh(mask, B, L, H)
 
-    if L <= _RESIDENT_MAX_L:  # fast path: K/V resident per program
+    if _resident(L, D):  # fast path: K/V resident per program
         out, lse = pl.pallas_call(
             functools.partial(
                 _flash_fwd_kernel_res,
                 block_k=bk, causal=causal, q_block=bq, scale=scale,
+                window=window,
             ),
             out_shape=(
                 jax.ShapeDtypeStruct((B * H, L, D), q.dtype),
@@ -249,11 +304,14 @@ def _flash_forward(q, k, v, mask, causal: bool, block_q: int, block_k: int):
         )(qb, kb, vb, mask_bh)
         return _from_bh(out, B, H), lse
 
-    grid = (B * H, L // bq, L // bk)
+    n_k = L // bk
+    steps, kblock = _streamed_sweep(causal, L // bq, bq, bk, n_k, True, window)
+    grid = (B * H, L // bq, steps)
     out, lse = pl.pallas_call(
         functools.partial(
             _flash_fwd_kernel,
             block_k=bk, causal=causal, q_block=bq, scale=scale,
+            n_k=n_k, window=window,
         ),
         out_shape=(
             jax.ShapeDtypeStruct((B * H, L, D), q.dtype),
@@ -263,11 +321,11 @@ def _flash_forward(q, k, v, mask, causal: bool, block_q: int, block_k: int):
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda i, j, t: (i, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, D), lambda i, j, t: (i, t, 0),
+            pl.BlockSpec((1, bk, D), lambda i, j, t: (i, kblock(j, t), 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, D), lambda i, j, t: (i, t, 0),
+            pl.BlockSpec((1, bk, D), lambda i, j, t: (i, kblock(j, t), 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bk), lambda i, j, t: (i, 0, t),
+            pl.BlockSpec((1, 1, bk), lambda i, j, t: (i, 0, kblock(j, t)),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=(
@@ -289,17 +347,20 @@ def _flash_forward(q, k, v, mask, causal: bool, block_q: int, block_k: int):
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, mask_ref, lse_ref, delta_ref,
                      do_ref, dq_ref, acc_ref, *, block_k: int, causal: bool,
-                     q_block: int, scale: float):
-    """dq: grid (B*H, L/bq, L/bk), K/V streaming, dq accumulates in scratch.
+                     q_block: int, scale: float, n_k: int, window=None):
+    """dq: grid (B*H, L/bq, sweep), K/V streaming, dq accumulates in
+    scratch; the sweep is the forward's (key block ``lo + t``).
 
     Recomputes p = exp(s*scale - lse) per block from the forward's lse
     residual — no L×L materialization. ds = p ⊙ (dp − delta); dq = ds @ K.
     """
     j = pl.program_id(1)
     t = pl.program_id(2)
-    nk = pl.num_programs(2)
+    nt = pl.num_programs(2)
     q = q_ref[0]  # (BQ, D)
     BQ, D = q.shape
+    lo, hi = _causal_sweep(causal, j, q_block, block_k, n_k, True, window)
+    kb = lo + t
 
     @pl.when(t == 0)
     def _init():
@@ -314,7 +375,7 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, mask_ref, lse_ref, delta_ref,
         lse = jnp.broadcast_to(lse_ref[0], (block_k, BQ)).T
         delta = jnp.broadcast_to(delta_ref[0], (block_k, BQ)).T
         s = _block_scores(q, k_blk, mask_ref[0], causal,
-                          j * q_block, t * block_k, scale)
+                          j * q_block, kb * block_k, scale, window)
         # masked entries carry s ≈ -1e30, so exp(s - lse) underflows to 0
         # for any row with at least one valid key (same additive-bias
         # convention as the forward).
@@ -330,13 +391,13 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, mask_ref, lse_ref, delta_ref,
         )
 
     if causal:
-        @pl.when(t * block_k <= j * q_block + q_block - 1)
+        @pl.when(kb < hi)
         def _():
             compute()
     else:
         compute()
 
-    @pl.when(t == nk - 1)
+    @pl.when(t == nt - 1)
     def _finalize():
         dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
 
@@ -344,13 +405,17 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, mask_ref, lse_ref, delta_ref,
 def _flash_dkv_kernel(k_ref, v_ref, q_ref, mask_ref, lse_ref, delta_ref,
                       do_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                       block_q: int, causal: bool, k_block: int,
-                      scale: float):
-    """dk/dv: grid (B*H, L/bk, L/bq), Q/dO streaming, dk/dv in scratch."""
+                      scale: float, n_q: int, window=None):
+    """dk/dv: grid (B*H, L/bk, sweep), Q/dO streaming, dk/dv in scratch;
+    step ``t`` holds query block ``lo + t`` of the key block's sweep,
+    which starts at the diagonal and, with a window, ends with its band."""
     j = pl.program_id(1)
     t = pl.program_id(2)
-    nq = pl.num_programs(2)
+    nt = pl.num_programs(2)
     k = k_ref[0]  # (BK, D)
     BK, D = k.shape
+    lo, hi = _causal_sweep(causal, j, k_block, block_q, n_q, False, window)
+    qb = lo + t
 
     @pl.when(t == 0)
     def _init():
@@ -366,7 +431,7 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, mask_ref, lse_ref, delta_ref,
         lse_blk = jnp.broadcast_to(lse_ref[0], (BK, block_q)).T  # (BQ, BK)
         delta_blk = jnp.broadcast_to(delta_ref[0], (BK, block_q)).T
         s = _block_scores(q_blk, k, mask_ref[0], causal,
-                          t * block_q, j * k_block, scale)
+                          qb * block_q, j * k_block, scale, window)
         p = jnp.exp(s - lse_blk)  # (BQ, BK)
         dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
             p, do_blk, (((0,), (0,)), ((), ())),
@@ -383,21 +448,21 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, mask_ref, lse_ref, delta_ref,
         )  # (BK, D)
 
     if causal:
-        # a Q block below the whole K block contributes nothing only when
-        # its LAST row is above the diagonal start of this K block
-        @pl.when(t * block_q + block_q - 1 >= j * k_block)
+        # the sweep starts at the diagonal (lo); past its end (the
+        # sequence's, or the window's band's) nothing contributes
+        @pl.when(qb < hi)
         def _():
             compute()
     else:
         compute()
 
-    @pl.when(t == nq - 1)
+    @pl.when(t == nt - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-# --- resident variants (L <= _RESIDENT_MAX_L) ----------------------------
+# --- resident variants (_resident(L, D)) ----------------------------------
 #
 # K/V (fwd, dq) / Q,dO (dkv) stay VMEM-resident for the whole program and
 # an in-kernel fori_loop sweeps them. ~5-20% faster than the streamed
@@ -406,21 +471,38 @@ def _flash_dkv_kernel(k_ref, v_ref, q_ref, mask_ref, lse_ref, delta_ref,
 # double buffering abort the Mosaic compiler, so _flash_forward /
 # _flash_backward dispatch to the streamed kernels above that point.
 # When causal the loop covers only _causal_sweep's blocks (a trip count
-# that depends on the program's index: (n + 1) / 2n of the n x n blocks);
-# when not, its bounds are the Python ints 0 and n: a static trip count.
+# that depends on the program's index: (n + 1) / 2n of the n x n blocks,
+# fewer under a window); when not, its bounds are the Python ints 0 and
+# n: a static trip count.
+#
+# The limit was found at head width 64, on an earlier compiler, and it is a
+# limit on bytes, so the choice goes by L x head width: a resident K/V copy
+# at width 128 is twice the bytes of one at 64. Widths under 64 count as 64,
+# so at every width through 64 the choice is by L alone, as it was. It is a
+# cautious limit: for the v5e, libtpu 0.0.34 compiles the resident kernels
+# at 8192 x 128 and at 16,384 x 64 too (sandbox, PR 38; not run), and which
+# family is the faster past the limit has not been measured (PERF.md
+# section 7).
 
-_RESIDENT_MAX_L = 8192
+_RESIDENT_MAX_L = 8192        # at head width _RESIDENT_HEAD_DIM
+_RESIDENT_HEAD_DIM = 64
+
+
+def _resident(L: int, D: int) -> bool:
+    return (L * max(D, _RESIDENT_HEAD_DIM)
+            <= _RESIDENT_MAX_L * _RESIDENT_HEAD_DIM)
 
 
 def _flash_fwd_kernel_res(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *,
                           block_k: int, causal: bool, q_block: int,
-                          scale: float):
+                          scale: float, window=None):
     """One (batch*head, q-block) program: resident K/V, fori_loop sweep."""
     j = pl.program_id(1)
     q = q_ref[0]  # (BQ, D)
     BQ, D = q.shape
     L = k_ref.shape[1]
-    lo, hi = _causal_sweep(causal, j, q_block, block_k, L // block_k, True)
+    lo, hi = _causal_sweep(causal, j, q_block, block_k, L // block_k, True,
+                           window)
 
     def body(kb, carry):
         o, m, l = carry
@@ -428,7 +510,7 @@ def _flash_fwd_kernel_res(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *,
         v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :]
         bias = mask_ref[0, :, pl.ds(kb * block_k, block_k)]  # (1, BK)
         s = _block_scores(q, k_blk, bias, causal,
-                          j * q_block, kb * block_k, scale)
+                          j * q_block, kb * block_k, scale, window)
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         corr = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
@@ -449,13 +531,14 @@ def _flash_fwd_kernel_res(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *,
 
 def _flash_dq_kernel_res(q_ref, k_ref, v_ref, mask_ref, lse_ref, delta_ref,
                          do_ref, dq_ref, *, block_k: int, causal: bool,
-                         q_block: int, scale: float):
+                         q_block: int, scale: float, window=None):
     """dq for one (batch*head, q-block) program: resident K/V sweep."""
     j = pl.program_id(1)
     q = q_ref[0]  # (BQ, D)
     BQ, D = q.shape
     L = k_ref.shape[1]
-    lo, hi = _causal_sweep(causal, j, q_block, block_k, L // block_k, True)
+    lo, hi = _causal_sweep(causal, j, q_block, block_k, L // block_k, True,
+                           window)
     lse = jnp.broadcast_to(lse_ref[0], (block_k, BQ)).T    # (BQ, BK) f32
     delta = jnp.broadcast_to(delta_ref[0], (block_k, BQ)).T  # (BQ, BK)
     do = do_ref[0].astype(jnp.float32)  # (BQ, D)
@@ -465,7 +548,7 @@ def _flash_dq_kernel_res(q_ref, k_ref, v_ref, mask_ref, lse_ref, delta_ref,
         v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :]
         bias = mask_ref[0, :, pl.ds(kb * block_k, block_k)]  # (1, BK)
         s = _block_scores(q, k_blk, bias, causal,
-                          j * q_block, kb * block_k, scale)
+                          j * q_block, kb * block_k, scale, window)
         p = jnp.exp(s - lse)  # (BQ, BK) f32
         dp = jax.lax.dot_general(
             do, v_blk.astype(jnp.float32), (((1,), (1,)), ((), ())),
@@ -483,13 +566,15 @@ def _flash_dq_kernel_res(q_ref, k_ref, v_ref, mask_ref, lse_ref, delta_ref,
 
 def _flash_dkv_kernel_res(k_ref, v_ref, q_ref, mask_ref, lse_ref, delta_ref,
                           do_ref, dk_ref, dv_ref, *, block_q: int,
-                          causal: bool, k_block: int, scale: float):
+                          causal: bool, k_block: int, scale: float,
+                          window=None):
     """dk/dv for one (batch*head, k-block) program: resident Q/dO sweep."""
     j = pl.program_id(1)
     k = k_ref[0]  # (BK, D)
     BK, D = k.shape
     L = q_ref.shape[1]
-    lo, hi = _causal_sweep(causal, j, k_block, block_q, L // block_q, False)
+    lo, hi = _causal_sweep(causal, j, k_block, block_q, L // block_q, False,
+                           window)
 
     def body(qb, carry):
         dk, dv = carry
@@ -502,7 +587,7 @@ def _flash_dkv_kernel_res(k_ref, v_ref, q_ref, mask_ref, lse_ref, delta_ref,
             delta_ref[0, :, pl.ds(qb * block_q, block_q)], (BK, block_q)
         ).T
         s = _block_scores(q_blk, k, mask_ref[0], causal,
-                          qb * block_q, j * k_block, scale)
+                          qb * block_q, j * k_block, scale, window)
         p = jnp.exp(s - lse_blk)  # (BQ, BK)
         dv = dv + jax.lax.dot_general(
             p, do_blk, (((0,), (0,)), ((), ())),
@@ -528,14 +613,14 @@ def _flash_dkv_kernel_res(k_ref, v_ref, q_ref, mask_ref, lse_ref, delta_ref,
 
 
 def _flash_backward(q, k, v, mask, out, lse, g, causal: bool,
-                    block_q: int, block_k: int):
+                    block_q: int, block_k: int, window=None):
     """Blockwise VJP: O(L) memory (never materializes the L×L scores).
 
     Replaces the closed-form jnp backward the round-1 build shipped (which
     recomputed the full score matrix — O(L²) memory, defeating the flash
     forward's point for training). delta = rowsum(dO ⊙ O) is the standard
     softmax-VJP rank-1 correction, computed outside the kernels (one fused
-    O(L·D) pass). Through L = _RESIDENT_MAX_L the swept operands (K/V for
+    O(L·D) pass). Where `_resident(L, D)` the swept operands (K/V for
     dq, Q/dO for dkv) are VMEM-resident; past it they stream as grid
     blocks. Either way the per-row lse/delta vectors ride lane-major
     (BH, 1, L) tiles.
@@ -554,7 +639,7 @@ def _flash_backward(q, k, v, mask, out, lse, g, causal: bool,
         gb.astype(jnp.float32) * ob.astype(jnp.float32), axis=-1,
     )[:, None, :]  # (BH, 1, L)
 
-    if L <= _RESIDENT_MAX_L:  # fast path: resident-operand kernels
+    if _resident(L, D):  # fast path: resident-operand kernels
         full = lambda i, j: (i, 0, 0)
         blk_q = lambda i, j: (i, j, 0)
         lane_blk = lambda i, j: (i, 0, j)
@@ -570,6 +655,7 @@ def _flash_backward(q, k, v, mask, out, lse, g, causal: bool,
             functools.partial(
                 _flash_dq_kernel_res,
                 block_k=bk, causal=causal, q_block=bq, scale=scale,
+                window=window,
             ),
             out_shape=jax.ShapeDtypeStruct((B * H, L, D), q.dtype),
             grid=(B * H, L // bq),
@@ -582,6 +668,7 @@ def _flash_backward(q, k, v, mask, out, lse, g, causal: bool,
             functools.partial(
                 _flash_dkv_kernel_res,
                 block_q=bq, causal=causal, k_block=bk, scale=scale,
+                window=window,
             ),
             out_shape=(
                 jax.ShapeDtypeStruct((B * H, L, D), k.dtype),
@@ -599,12 +686,16 @@ def _flash_backward(q, k, v, mask, out, lse, g, causal: bool,
             _from_bh(dv, B, H),
         )
 
+    n_q, n_k = L // bq, L // bk
+    k_steps, kblock = _streamed_sweep(causal, n_q, bq, bk, n_k, True, window)
     spec_q_d = pl.BlockSpec((1, bq, D), lambda i, j, t: (i, j, 0),
                             memory_space=pltpu.VMEM)
-    spec_k_stream = pl.BlockSpec((1, bk, D), lambda i, j, t: (i, t, 0),
-                                 memory_space=pltpu.VMEM)
-    spec_mask_stream = pl.BlockSpec((1, 1, bk), lambda i, j, t: (i, 0, t),
-                                    memory_space=pltpu.VMEM)
+    spec_k_stream = pl.BlockSpec(
+        (1, bk, D), lambda i, j, t: (i, kblock(j, t), 0),
+        memory_space=pltpu.VMEM)
+    spec_mask_stream = pl.BlockSpec(
+        (1, 1, bk), lambda i, j, t: (i, 0, kblock(j, t)),
+        memory_space=pltpu.VMEM)
     spec_lane_j = pl.BlockSpec((1, 1, bq), lambda i, j, t: (i, 0, j),
                                memory_space=pltpu.VMEM)
 
@@ -612,9 +703,10 @@ def _flash_backward(q, k, v, mask, out, lse, g, causal: bool,
         functools.partial(
             _flash_dq_kernel,
             block_k=bk, causal=causal, q_block=bq, scale=scale,
+            n_k=n_k, window=window,
         ),
         out_shape=jax.ShapeDtypeStruct((B * H, L, D), q.dtype),
-        grid=(B * H, L // bq, L // bk),
+        grid=(B * H, n_q, k_steps),
         in_specs=[spec_q_d, spec_k_stream, spec_k_stream,
                   spec_mask_stream, spec_lane_j, spec_lane_j, spec_q_d],
         out_specs=spec_q_d,
@@ -623,25 +715,29 @@ def _flash_backward(q, k, v, mask, out, lse, g, causal: bool,
         interpret=_interpret(),
     )(qb, kb, vb, mask_bh, lse_t, delta_t, gb)
 
+    q_steps, qblock = _streamed_sweep(causal, n_k, bk, bq, n_q, False, window)
     spec_k_d = pl.BlockSpec((1, bk, D), lambda i, j, t: (i, j, 0),
                             memory_space=pltpu.VMEM)
-    spec_q_stream = pl.BlockSpec((1, bq, D), lambda i, j, t: (i, t, 0),
-                                 memory_space=pltpu.VMEM)
+    spec_q_stream = pl.BlockSpec(
+        (1, bq, D), lambda i, j, t: (i, qblock(j, t), 0),
+        memory_space=pltpu.VMEM)
     spec_mask_j = pl.BlockSpec((1, 1, bk), lambda i, j, t: (i, 0, j),
                                memory_space=pltpu.VMEM)
-    spec_lane_stream = pl.BlockSpec((1, 1, bq), lambda i, j, t: (i, 0, t),
-                                    memory_space=pltpu.VMEM)
+    spec_lane_stream = pl.BlockSpec(
+        (1, 1, bq), lambda i, j, t: (i, 0, qblock(j, t)),
+        memory_space=pltpu.VMEM)
 
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_dkv_kernel,
             block_q=bq, causal=causal, k_block=bk, scale=scale,
+            n_q=n_q, window=window,
         ),
         out_shape=(
             jax.ShapeDtypeStruct((B * H, L, D), k.dtype),
             jax.ShapeDtypeStruct((B * H, L, D), v.dtype),
         ),
-        grid=(B * H, L // bk, L // bq),
+        grid=(B * H, n_k, q_steps),
         in_specs=[spec_k_d, spec_k_d, spec_q_stream, spec_mask_j,
                   spec_lane_stream, spec_lane_stream, spec_q_stream],
         out_specs=(spec_k_d, spec_k_d),
@@ -658,20 +754,22 @@ def _flash_backward(q, k, v, mask, out, lse, g, causal: bool,
     )
 
 
-def _make_flash(causal: bool, block_q: int, block_k: int):
+def _make_flash(causal: bool, block_q: int, block_k: int, window=None):
     @jax.custom_vjp
     def flash(q, k, v, mask):
-        out, _ = _flash_forward(q, k, v, mask, causal, block_q, block_k)
+        out, _ = _flash_forward(q, k, v, mask, causal, block_q, block_k,
+                                window)
         return out
 
     def fwd(q, k, v, mask):
-        out, lse = _flash_forward(q, k, v, mask, causal, block_q, block_k)
+        out, lse = _flash_forward(q, k, v, mask, causal, block_q, block_k,
+                                  window)
         return out, (q, k, v, mask, out, lse)
 
     def bwd(res, g):
         q, k, v, mask, out, lse = res
         dq, dk, dv = _flash_backward(
-            q, k, v, mask, out, lse, g, causal, block_q, block_k
+            q, k, v, mask, out, lse, g, causal, block_q, block_k, window
         )
         return dq, dk, dv, None
 
@@ -681,7 +779,7 @@ def _make_flash(causal: bool, block_q: int, block_k: int):
 
 # Preferred block size, tuned on TPU v5e: bq=bk=512 (both the resident
 # kernels' sweep block and the streamed kernels' grid block). Which
-# kernel family runs is decided by _RESIDENT_MAX_L, not block size.
+# kernel family runs is decided by _resident(L, D), not block size.
 _PREFERRED_BLOCK = 512
 _FLASH_CACHE = {}
 
@@ -704,17 +802,28 @@ def _pick_block(L: int) -> int:
     )
 
 
-def pallas_attention(q, k, v, mask=None, causal: bool = False):
+def pallas_attention(q, k, v, mask=None, causal: bool = False,
+                     window: Optional[int] = None):
     """Model-zoo attention impl backed by the flash kernel.
 
     Drop-in for `models.transformer.full_attention`: q/k/v (B, L, H, D),
-    optional (B, L) pad mask. Differentiable (custom VJP). Block sizes are
-    chosen per sequence length (cached per (causal, block)).
+    optional (B, L) pad mask, and with ``causal`` an optional ``window``:
+    query i sees keys ``i - window + 1 .. i``. A window that covers the
+    sequence is no window (the causal kernels, as they are without the
+    argument). Differentiable (custom VJP). Block sizes are chosen per
+    sequence length (cached per (causal, block, window)).
     """
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(
+                f"window={window} needs causal=True and at least the "
+                "query's own key")
+        if window >= q.shape[1]:
+            window = None
     b = _pick_block(q.shape[1])
-    key = (causal, b)
+    key = (causal, b, window)
     if key not in _FLASH_CACHE:
-        _FLASH_CACHE[key] = _make_flash(causal, b, b)
+        _FLASH_CACHE[key] = _make_flash(causal, b, b, window)
     return _FLASH_CACHE[key](q, k, v, mask)
 
 

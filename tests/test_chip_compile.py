@@ -243,3 +243,40 @@ def test_causal_flash_backward_compiles_for_v5e_at_the_lfm2_cells_shape(
              for line in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert sorted(kinds) == sorted(family["calls_per_step"])  # one of each
+
+
+@pytest.mark.parametrize("family, window", [
+    ("window_attention", 4096), ("global_attention", None)])
+def test_streamed_flash_compiles_for_v5e_at_the_smallthinker_cells_shape(
+        topo, monkeypatch, family, window):
+    """The attention layers of cell smallthinker_21b_a3b_ep8_b1_L16384
+    (1 x 16,384 x 28 heads of 128, bfloat16): past the resident limit, so
+    the streamed family, whose index maps compute each step's block from
+    `_causal_sweep` (a floor division and a clamp on traced program
+    indices, with and without a window): only the real Mosaic compiler can
+    refuse that. The three calls are told apart as the benchmark does."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark import trace
+    from pytorch_distributed_nn_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    assert not pk._resident(16384, 128)
+    x = jax.ShapeDtypeStruct((1, 16384, 28, 128), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+
+    def loss(q, k, v):
+        out = pk.pallas_attention(q, k, v, None, causal=True, window=window)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(x, x, x).compile()
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                           "configs", "smallthinker_21b_a3b_ep8.json")) as f:
+        spec = json.load(f)["kernels"][family]
+    kernels = {family: {**spec, "match": ""}}
+    kinds = [trace.classify_kernel(trace.parse_op(line.strip()), kernels)[1]
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(kinds) == sorted(spec["kinds"])           # one of each
