@@ -14,8 +14,12 @@ chip's share of expert parallelism. What the absent experts would add is
 left out and there is no exchange here (an expert axis in ``parallel/`` is
 its own piece of work); with ``experts_held = (0, num_experts)`` the layer
 is the whole one. It is dropless with static shapes: every (token, held
-expert) pair the router selects is computed, in a buffer sized for the
-bound (every pair local) whose unused tiles the grouped matmul skips
+expert) pair the router selects is computed. The rows move in *row space*
+(a gather of the owned rows, a float32 sum of the result rows into their
+tokens) through buffers sized by a *rung*: the smallest of a short ladder
+of static row counts that covers the tiles this step's routing owns,
+chosen on the device, the last rung being the bound (every pair local).
+Within a rung the grouped matmul skips the tiles no expert owns
 (``ops/pallas_kernels.grouped_matmul``).
 
 Shapes: tokens ``(B, L) int32`` -> logits ``(B, L, vocab) float32``, the
@@ -29,6 +33,8 @@ state beside the KV cache and a KV-head axis, and ``__call__`` says so.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import Any, Optional, Tuple
 
 import jax
@@ -52,6 +58,7 @@ from pytorch_distributed_nn_tpu.ops.pallas_kernels import (
     GMM_TILE_M,
     group_tiles,
     grouped_matmul,
+    sum_rows,
 )
 
 #: the router's scores, kept for the backward pass under either remat
@@ -90,9 +97,8 @@ class Lfm2Config:
     max_len: int = 128_000                 # rotary: no table, no limit
     dtype: Any = jnp.bfloat16
     # recompute every block in the backward pass, not only the expert
-    # layers' part after the routing (which always is: its buffers are
-    # sized for the dropless bound, 4 x tokens rows, and kept they would be
-    # 0.74 GB a layer at the cell's sizes)
+    # layers' part after the routing (which always is: ``held_experts``
+    # keeps its inputs and recomputes the chosen rung)
     remat: bool = False
     dropout_rate: float = 0.0              # the family has none
 
@@ -265,7 +271,11 @@ def dispatch(sel, first: int, count: int, tile_rows: int = GMM_TILE_M):
     ``meta`` is the grouped matmul's tile table and ``counts (count,)`` the
     pairs of each held expert. ``R = T * k + count * tile_rows``: every
     pair local, each expert's rows padded to whole tiles — the dropless
-    bound, static."""
+    bound, static. These are index vectors (4 bytes an entry), computed
+    once at the bound; the rows themselves are moved at a rung's size
+    (``ladder``): the owned rows are a prefix of the buffer (tiles
+    ``0 .. meta[-1]``), so a rung reads the first ``rows`` entries of
+    ``pair`` and ``real`` and every local pair's ``dest`` lies in it."""
     T, k = sel.shape
     pairs = T * k
     rows = pairs + count * tile_rows
@@ -296,69 +306,172 @@ def dispatch(sel, first: int, count: int, tile_rows: int = GMM_TILE_M):
     return pair, real, dest, local, meta, counts
 
 
-def _take(x, index, mask):
-    """``x[index]`` where ``mask``, zeros elsewhere (a row that was never
-    written may hold anything: it is selected away, not multiplied)."""
-    return jnp.where(mask[..., None], jnp.take(x, index, axis=0), 0)
+def ladder(pairs: int, count: int, num_experts: int,
+           tile_rows: int = GMM_TILE_M) -> Tuple[int, ...]:
+    """The static row counts the held experts' buffers may take (the
+    *rungs*), ascending, each a whole number of tiles. The first holds
+    even routing (``pairs * count / num_experts`` pairs here) with a
+    quarter of headroom and a tile an expert, the second twice that share;
+    the last is the dropless bound (``dispatch``'s ``R``: every pair
+    here), so every routing has a rung. Three at most: each rung is a
+    compiled copy of the expert computation a layer."""
+    bound = -(-pairs // tile_rows) + count
+    even = pairs * count / num_experts
+    tiles = sorted({math.ceil(share * even / tile_rows) + count
+                    for share in (1.25, 2.5)})
+    return tuple(t * tile_rows for t in tiles if t < bound) + (
+        bound * tile_rows,)
 
 
-# The row buffer is filled, and read back, by gathers whose transposes are
-# scatter-adds of 4 KB rows. Each row holds one pair and each pair sits in
-# one row, so the transposes are gathers too, through the inverse index:
-# these two say so to autodiff.
+# Rows move between the (T, d) tokens and a rung's (rows, d) buffer by two
+# primitives: G, a gather of ``rows`` rows, and S, a float32 sum of ``rows``
+# rows into their tokens (``ops/pallas_kernels.sum_rows``). Each is the
+# other's transpose. Autodiff's own transpose of a bfloat16 gather is a
+# bfloat16 scatter-add, so G says that its transpose is S in float32, and
+# the weights' pick says that its transpose is a gather of scalars through
+# the inverse index.
 
-@jax.custom_vjp
-def rows_of_tokens(x, pair, real, dest, local):
-    """(T, d) tokens -> (R, d) buffer rows: row r is token ``pair[r] // k``."""
-    return _take(x, pair // dest.shape[1], real)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def rows_of_tokens(x, token, real, meta, groups: int, tile: int):
+    """G: (T, d) tokens -> (rows, d) buffer rows, row r token ``token[r]``
+    where ``real[r]`` and zeros elsewhere."""
+    return jnp.where(real[:, None], jnp.take(x, token, axis=0), 0)
 
 
-def _rows_fwd(x, pair, real, dest, local):
-    return rows_of_tokens(x, pair, real, dest, local), (dest, local)
+def _rows_fwd(x, token, real, meta, groups, tile):
+    return (rows_of_tokens(x, token, real, meta, groups, tile),
+            (token, real, meta, x.shape[0]))
 
 
-def _rows_bwd(res, g):
-    dest, local = res
-    dx = _take(g, dest, local).astype(jnp.float32).sum(axis=1)
-    return dx.astype(g.dtype), None, None, None, None
+def _rows_bwd(groups, tile, res, g):
+    token, real, meta, tokens = res
+    dx = sum_rows(g, None, token, real, meta, groups, tokens, tile)
+    return dx.astype(g.dtype), None, None, None
 
 
 rows_of_tokens.defvjp(_rows_fwd, _rows_bwd)
 
 
 @jax.custom_vjp
-def pairs_of_rows(rows, pair, real, dest, local):
-    """(R, d) buffer rows -> (T, k, d): each local pair's row, zeros for
-    the pairs of experts held elsewhere."""
-    return _take(rows, dest, local)
+def weights_of_rows(weights, pair, real, dest, local):
+    """(T, k) float32 weights -> (rows,): row r's pair's weight where
+    ``real[r]``, zero elsewhere."""
+    return jnp.where(real, jnp.take(weights.reshape(-1), pair), 0)
 
 
-def _pairs_fwd(rows, pair, real, dest, local):
-    return pairs_of_rows(rows, pair, real, dest, local), (pair, real)
+def _weights_fwd(weights, pair, real, dest, local):
+    return weights_of_rows(weights, pair, real, dest, local), (dest, local)
 
 
-def _pairs_bwd(res, g):
-    pair, real = res
-    return (_take(g.reshape(-1, g.shape[-1]), pair, real),
+def _weights_bwd(res, g):
+    dest, local = res
+    # every local pair's row lies in the rung: the rung covers the owned
+    # tiles
+    return (jnp.where(local, jnp.take(g, dest, mode="clip"), 0),
             None, None, None, None)
 
 
-pairs_of_rows.defvjp(_pairs_fwd, _pairs_bwd)
+weights_of_rows.defvjp(_weights_fwd, _weights_bwd)
 
 
 #: the gate of an expert's FFN: ``W2(gate(W1 x) * W3 x)``
 GATES = {"silu": nn.silu, "relu": nn.relu}
 
 
+def _rung(rows: int, tile: int, gate, tokens, weights, w13, w2, where):
+    """The held experts' computation in a buffer of ``rows`` rows (static:
+    a rung that covers the owned tiles). tokens (T, d) in the compute
+    dtype, weights (T, k) float32 -> (T, d) float32. Nothing here is sized
+    by the dropless bound but ``where``, dispatch's index vectors, of
+    which the first ``rows`` entries are read."""
+    pair, real, dest, local, meta = where
+    k, f = weights.shape[1], w2.shape[1]
+    pair, real = pair[:rows], real[:rows]
+    meta = jnp.concatenate([meta[:rows // tile], meta[-1:]])
+    token, groups = pair // k, w13.shape[0]
+    with jax.named_scope("moe/dispatch"):
+        x = rows_of_tokens(tokens, token, real, meta, groups, tile)
+    with jax.named_scope("moe/experts"):
+        h = grouped_matmul(x, w13, meta, tile)
+        h = gate(h[:, :f]) * h[:, f:]
+        out_rows = grouped_matmul(h, w2, meta, tile)
+    with jax.named_scope("moe/combine"):
+        # rows past the last owned tile are never written: S reads the
+        # tiles that hold a real row and no other
+        w_row = weights_of_rows(weights, pair, real, dest, local)
+        return sum_rows(out_rows, w_row, token, real, meta, groups,
+                        tokens.shape[0], tile)
+
+
+# Both switches are jitted on their own (and inlined where they are traced
+# into a step): every expert layer of a model calls them at the same
+# shapes, so the rungs and their kernels are traced once a model, not once
+# a layer and pass. ``static = (rungs, tile, gate)``, the gate as the
+# function itself: the trace is cached under it.
+
+@functools.partial(jax.jit, static_argnums=(0,), inline=True)
+def _switch_forward(static, rung, tokens, weights, w13, w2, where):
+    rungs, tile, gate = static
+    return jax.lax.switch(
+        rung, [functools.partial(_rung, rows, tile, gate) for rows in rungs],
+        tokens, weights, w13, w2, where)
+
+
+@functools.partial(jax.jit, static_argnums=(0,), inline=True)
+def _switch_backward(static, rung, g, tokens, weights, w13, w2, where):
+    rungs, tile, gate = static
+
+    def backward(rows):
+        # the checkpoint keeps the Mosaic calls' names what the trace's
+        # readers look for (experts.<n>), recomputed and transposed alike
+        body = jax.checkpoint(functools.partial(_rung, rows, tile, gate))
+
+        def branch(g, tokens, weights, w13, w2, where):
+            return jax.vjp(lambda *inputs: body(*inputs, where),
+                           tokens, weights, w13, w2)[1](g)
+        return branch
+
+    return jax.lax.switch(
+        rung, [backward(rows) for rows in rungs],
+        g, tokens, weights, w13, w2, where)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def held_experts(static, rung, tokens, weights, w13, w2, where):
+    """``switch(rung)`` over one ``_rung`` a rung of ``static = (rungs,
+    tile, gate)``. Differentiated inside each rung, not through the switch
+    (autodiff through it hands every branch every other branch's residuals,
+    zero-filled at the dropless bound): the forward pass keeps its inputs
+    and nothing else, and the backward pass recomputes the chosen rung."""
+    return _switch_forward(static, rung, tokens, weights, w13, w2, where)
+
+
+def _held_fwd(static, rung, *operands):
+    return held_experts(static, rung, *operands), (rung, operands)
+
+
+def _held_bwd(static, res, g):
+    rung, operands = res
+    return (None, *_switch_backward(static, rung, g, *operands), None)
+
+
+held_experts.defvjp(_held_fwd, _held_bwd)
+
+
 class Experts(nn.Module):
     """The held experts' part of ``y = sum_{e in sel} w_e FFN_e(x)`` for a
-    routing already decided: dispatch, two grouped matmuls (``w13`` = [W1 |
-    W3] side by side, then ``w2``), combine. Dropless. Shared by every
-    sparse-expert model: ``config`` is read for ``experts_held``,
+    routing already decided: dispatch, then in row space a gather of the
+    owned rows, two grouped matmuls (``w13`` = [W1 | W3] side by side, then
+    ``w2``) and a weighted float32 sum of the rows into their tokens.
+    Dropless: the buffers are sized by the smallest rung of ``ladder`` that
+    covers the tiles this step's routing owns, chosen on the device, and
+    the last rung is the bound. Shared by every sparse-expert model:
+    ``config`` is read for ``experts_held``, ``num_experts``,
     ``hidden_size``, ``moe_intermediate_size`` and ``dtype`` only, and
     ``gate`` names the FFN's gate. Sows, for the step's
     records (summed there over the expert layers): ``moe_pairs`` (pairs
     computed here), ``moe_rows`` (rows the grouped matmul's tiles covered),
+    ``moe_rows_bound`` (the chosen rung's rows: what the buffers held),
     ``moe_load_max`` / ``moe_load_mean`` (the fullest held expert's pairs,
     the mean one's) and ``moe_layers`` (1: how many layers were summed)."""
 
@@ -380,23 +493,21 @@ class Experts(nn.Module):
             "w2", nn.with_logical_partitioning(
                 _dense_init(), (None, MLP, EMBED)),
             (count, f, d), jnp.float32)
+        tile = GMM_TILE_M
         with jax.named_scope("moe/dispatch"):
             pair, real, dest, local, meta, counts = dispatch(
-                sel, first, count)
-            where = (pair, real, dest, local)
-            rows = rows_of_tokens(tokens.astype(cfg.dtype), *where)
-        with jax.named_scope("moe/experts"):
-            h = grouped_matmul(rows, w13, meta)
-            h = GATES[self.gate](h[:, :f]) * h[:, f:]
-            out_rows = grouped_matmul(h, w2, meta)
-        with jax.named_scope("moe/combine"):
-            # rows past the last owned tile are never written: a row is
-            # read only where the pair is local
-            picked = pairs_of_rows(out_rows, *where)
-            y = jnp.einsum("tk,tkd->td", weights, picked.astype(jnp.float32))
+                sel, first, count, tile)
+            rungs = ladder(sel.size, count, cfg.num_experts, tile)
+            rung_rows = jnp.array(rungs, jnp.int32)
+            rung = jnp.searchsorted(rung_rows, meta[-1] * tile).astype(
+                jnp.int32)
+        y = held_experts(
+            (rungs, tile, GATES[self.gate]), rung, tokens.astype(cfg.dtype),
+            weights, w13, w2, (pair, real, dest, local, meta))
         for name, value in (
             ("moe_pairs", counts.sum()),
-            ("moe_rows", meta[-1] * GMM_TILE_M),
+            ("moe_rows", meta[-1] * tile),
+            ("moe_rows_bound", rung_rows[rung]),
             ("moe_load_max", counts.max()),
             ("moe_load_mean", counts.sum() / count),
             ("moe_layers", jnp.ones((), jnp.float32)),
@@ -410,7 +521,7 @@ class SparseExperts(nn.Module):
     layer (``Experts``). The routing is decided once, in the forward pass,
     and kept for the backward pass (scores, selection and weights: 2 MB a
     layer at 16,384 tokens); what is recomputed there is everything after
-    it, whose buffers are sized for the dropless bound. Recomputed, the
+    it, the held experts' computation at the chosen rung. Recomputed, the
     decisions do not come out the same: XLA keeps more than bfloat16 inside
     a fusion, the recomputation starts from the rounded residual stream,
     and 0.3-0.5 % of the selections differed on the chip (PERF.md section
@@ -518,9 +629,9 @@ def lfm2_8b_a1b_ep4(num_classes: int = 0,
     width as published; experts 0-7 of 32 and 16,384 of the 65,536
     vocabulary rows held here; one of the two leading dense layers and one
     whole period of four expert layers (published layers 2-5). 507.8 M
-    parameters. The expert layers are recomputed in the backward pass:
-    their buffers are sized for the dropless bound (4 x 16,384 rows) and,
-    kept, the step would not fit (16.0 GB compiled; 12.9 GB this way)."""
+    parameters. The expert layers are recomputed in the backward pass
+    (with their buffers kept at the dropless bound, 4 x 16,384 rows, the
+    step did not fit: 16.0 GB compiled; 12.9 GB recomputed)."""
     del num_classes
     return _build(dict(
         vocab_size=16384, num_dense_layers=1, experts_held=(0, 8),

@@ -110,6 +110,10 @@ class SmallThinkerConfig:
     def moe_intermediate_size(self) -> int:
         return self.moe_ffn_hidden_size
 
+    @property
+    def num_experts(self) -> int:
+        return self.moe_num_primary_experts
+
 
 class Attention(nn.Module):
     """Causal grouped-query attention over the whole prefix (``window``

@@ -46,6 +46,7 @@ mesh (tests/test_pallas_kernels.py) and compiled on real chips.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -1287,7 +1288,8 @@ def fused_layer_norm(x, gamma, beta, eps=1e-6, out_dtype=None):
 # ``x (R, K)`` holds the rows of G groups one after the other, each group
 # starting on a row tile (``group_tiles`` lays them out), and ``w (G, K, N)``
 # one matrix a group: ``out[r] = x[r] @ w[group of r]``. R is a static bound
-# (every pair a dropless router could send here); the rows really routed
+# (a rung of ``models/lfm2.ladder``: at most every pair a dropless router
+# could send here); the rows really routed
 # fill the first ``active`` tiles and the grid steps past them compute
 # nothing and fetch nothing (their block indices are clamped to the last
 # active tile's), so the device's work follows the rows, not the bound.
@@ -1303,6 +1305,10 @@ _GMM_PARAMS = pltpu.CompilerParams(
 )
 _TGMM_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=48 * 2 ** 20,
+)
+_SUM_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary", "arbitrary"),
     vmem_limit_bytes=48 * 2 ** 20,
 )
 
@@ -1469,3 +1475,145 @@ def grouped_matmul(x, w, meta, tile_m: int = GMM_TILE_M):
     float32 accumulation. Differentiable in x and w. Rows past the last
     owned tile are not written, in the result or in dx."""
     return _grouped_matmul(x, w, meta, tile_m)
+
+
+# ---------------------------------------------------------------------------
+# Row sum (the expert layer's combine and dispatch's transpose)
+# ---------------------------------------------------------------------------
+#
+# ``out[t] = sum over the rows r of token t of weight[r] * values[r]`` for
+# rows laid out by ``group_tiles`` with each group's rows in token order
+# (``models/lfm2.dispatch``): a scatter-add in XLA, which sorts the indices,
+# gathers the rows in that order and adds them one by one. Here a grid step
+# takes a block of tokens, a group and a chunk of that group's rows, builds
+# the (tokens x rows) matrix that has ``weight[r]`` where row r is token t's
+# and multiplies it with the chunk on the matrix unit: within a group the
+# rows of a token block are consecutive (token order), at most a block of
+# them (a token meets a group once), so a step reads the few chunks they
+# lie in and skips the rest as the grouped matmul skips its unowned tiles.
+# A float32 weight goes in as three bfloat16 terms, exactly, so the products
+# are the float32 ones and only the order of a token's sum is the kernel's.
+
+_SUM_TOKENS = 256           # tokens a block (the block's float32 sum in VMEM)
+_SUM_CHUNK = 128            # rows a step
+
+
+def _sum_rows_kernel(first_ref, count_ref, token_ref, weight_ref, v_ref,
+                     o_ref, *, weighted: bool):
+    b, g, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    tb, chunk = o_ref.shape[0], v_ref.shape[0]
+
+    @pl.when((g == 0) & (c == 0))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    at = b * pl.num_programs(1) + g
+
+    @pl.when(c < count_ref[at])
+    def _():
+        j = first_ref[at] + c
+        token = jnp.broadcast_to(token_ref[pl.ds(j, 1), :], (tb, chunk))
+        mine = (token == b * tb + jax.lax.broadcasted_iota(
+            jnp.int32, (tb, chunk), 0)).astype(jnp.float32)
+        values = v_ref[...]
+
+        def product(onehot):
+            return jax.lax.dot_general(
+                onehot.astype(values.dtype), values, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        if not weighted:
+            o_ref[...] += product(mine)
+            return
+        left = jnp.broadcast_to(weight_ref[pl.ds(j, 1), :], (tb, chunk))
+        total = jnp.zeros(o_ref.shape, jnp.float32)
+        for _ in range(3):      # 3 x 8 bits: a float32 weight's mantissa
+            term = left.astype(jnp.bfloat16).astype(jnp.float32)
+            left = left - term
+            total += product(mine * term)
+        o_ref[...] += total
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _sum_rows(values, weight, token, real, meta, groups: int, tokens: int,
+              tile_m: int):
+    rows, d = values.shape
+    tb, chunk = math.gcd(tokens, _SUM_TOKENS), math.gcd(tile_m, _SUM_CHUNK)
+    blocks, chunks = tokens // tb, -(-tb // chunk) + 1
+    # rows sorted by (group, token block), a group's empty rows after its
+    # real ones and the unowned tiles (the last group's) after everything:
+    # [lo, hi) are the rows of block b in group g
+    owner = jnp.repeat(meta[:rows // tile_m], tile_m)
+    key = owner * (blocks + 1) + jnp.where(real, token // tb, blocks)
+    wanted = (jnp.arange(blocks, dtype=jnp.int32)[:, None]
+              + (blocks + 1) * jnp.arange(groups, dtype=jnp.int32)[None, :]
+              ).reshape(-1)
+    lo = jnp.searchsorted(key, wanted, side="left")
+    hi = jnp.searchsorted(key, wanted, side="right")
+    first = (lo // chunk).astype(jnp.int32)
+    count = jnp.where(hi > lo, (hi - 1) // chunk - first + 1, 0).astype(
+        jnp.int32)
+    by_chunk = (rows // chunk, chunk)
+    weighted = weight is not None
+
+    def rows_of(b, g, c, first_ref, count_ref):
+        at = b * groups + g
+        last = jnp.maximum(count_ref[at], 1) - 1
+        return first_ref[at] + jnp.minimum(c, last), 0
+
+    def whole(b, g, c, first_ref, count_ref):
+        return 0, 0
+
+    return pl.pallas_call(
+        functools.partial(_sum_rows_kernel, weighted=weighted),
+        out_shape=jax.ShapeDtypeStruct((tokens, d), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(blocks, groups, chunks),
+            in_specs=[
+                pl.BlockSpec(by_chunk, whole),
+                pl.BlockSpec(by_chunk, whole),
+                pl.BlockSpec((chunk, d), rows_of),
+            ],
+            out_specs=pl.BlockSpec(
+                (tb, d), lambda b, g, c, first_ref, count_ref: (b, 0)),
+        ),
+        compiler_params=_SUM_PARAMS,
+        interpret=_interpret(),
+    )(first, count,
+      jnp.where(real, token, -1).reshape(by_chunk),
+      (weight if weighted else jnp.zeros((rows,), jnp.float32)).reshape(
+          by_chunk),
+      values)
+
+
+def _sum_rows_fwd(values, weight, token, real, meta, groups, tokens, tile_m):
+    out = _sum_rows(values, weight, token, real, meta, groups, tokens, tile_m)
+    return out, (values, weight, token, real)
+
+
+def _sum_rows_bwd(groups, tokens, tile_m, res, g):
+    values, weight, token, real = res
+    mine = jnp.where(real[:, None], jnp.take(g, token, axis=0), 0)
+    if weight is None:
+        return mine.astype(values.dtype), None, None, None, None
+    dots = jnp.sum(mine * values.astype(jnp.float32), axis=-1)
+    return ((weight[:, None] * mine).astype(values.dtype),
+            jnp.where(real, dots, 0), None, None, None)
+
+
+_sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
+
+
+def sum_rows(values, weight, token, real, meta, groups: int, tokens: int,
+             tile_m: int = GMM_TILE_M):
+    """``out[t] = sum of weight[r] * values[r] over the rows r with
+    token[r] == t and real[r]``, (tokens, d) float32 with float32 products
+    and accumulation. values (R, d) in the compute dtype, laid out by
+    ``group_tiles`` (``meta`` is its second result, ``groups`` its group
+    count) with each group's real rows first and in ascending token order,
+    a token at most once a group; weight (R,) float32, or None for ones;
+    token (R,) int32; real (R,) bool. A row that is not real may hold
+    anything (it was never written): it is matched by no token.
+    Differentiable in values and weight."""
+    return _sum_rows(values, weight, token, real, meta, groups, tokens, tile_m)

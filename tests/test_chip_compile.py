@@ -208,6 +208,118 @@ def test_grouped_matmul_compiles_for_v5e_at_the_lfm2_cells_widths(
     assert outputs.count(("bf16", f"{rows},{d}")) == 2    # y and dx
 
 
+def _computations(text):
+    """name -> body of each computation of an optimised module's text."""
+    return {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%(\S+) \(.*?\) -> .*? \{\n(.*?)^\}", text, re.S | re.M)}
+
+
+def _reached(computations, name, seen=None):
+    """``name`` and every computation it calls, fusions included."""
+    seen = set() if seen is None else seen
+    if name in computations and name not in seen:
+        seen.add(name)
+        body = computations[name]
+        called = re.findall(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", body)
+        for group in re.findall(r"branch_computations=\{([^}]*)\}", body):
+            called += re.findall(r"%([\w.\-]+)", group)
+        for callee in called:
+            _reached(computations, callee, seen)
+    return seen
+
+
+def test_the_expert_layers_rungs_compile_for_v5e_at_the_smallthinker_cells_widths(
+        topo, monkeypatch):
+    """The held experts' forward + backward as cell
+    smallthinker_21b_a3b_ep8_b1_L16384 runs them (16,384 tokens, top-6 of
+    64, 8 experts of 2560 x 1536 and 768 x 2560 held, ReLU gate, under
+    nn.remat): one branch a rung in the forward and in the backward
+    switch, each with its own Mosaic programs (2 + 6 calls), every call
+    under the name the benchmark finds the family by, nothing sized by the
+    dropless bound outside the last rung's branches, and no more
+    temporaries than the bound's body alone needs (autodiff through the
+    switch would hand every branch every other's residuals)."""
+    import jax
+    import jax.numpy as jnp
+    from flax import linen as nn
+    from jax.sharding import SingleDeviceSharding
+
+    from pytorch_distributed_nn_tpu.models import build_model, lfm2
+    from pytorch_distributed_nn_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = build_model("SmallThinker_21B_A3B_EP8").config
+    T, k = 16384, cfg.moe_num_active_primary_experts
+    d, f, count = cfg.hidden_size, cfg.moe_intermediate_size, 8
+    rungs = lfm2.ladder(T * k, count, cfg.num_experts)
+    assert rungs == (17408, 32768, 100352)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, tokens, sel, weights):
+            return nn.remat(lfm2.Experts)(cfg, "relu", name="experts")(
+                tokens, sel, weights)
+
+    def loss(params, tokens, sel, weights):
+        y, _ = Layer().apply({"params": params}, tokens, sel, weights,
+                             mutable=[lfm2.COUNTERS])
+        return jnp.sum(y ** 2)
+
+    def compiled():
+        return jax.jit(jax.grad(loss, (0, 1, 3))).lower(
+            {"experts": {"w13": shape((count, d, 2 * f), jnp.float32),
+                         "w2": shape((count, f, d), jnp.float32)}},
+            shape((T, d), jnp.float32), shape((T, k), jnp.int32),
+            shape((T, k), jnp.float32)).compile()
+
+    laddered = compiled()
+    text = laddered.as_text()
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                           "configs", "smallthinker_21b_a3b_ep8.json")) as f_:
+        match = json.load(f_)["kernels"]["grouped_matmul"]["match"]
+    names = re.findall(
+        r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    # the grouped matmuls are the calls the benchmark's pattern finds; the
+    # row sums of combine and of dispatch's transpose are named otherwise
+    family = [name for name in names if re.search(match, name)]
+    assert len(family) == 8 * len(rungs), names
+    assert sorted(set(re.sub(r"\.\d+$", "", name) for name in names)) == [
+        "combine", "dispatch", "experts"]
+    computations = _computations(text)
+    switches = [re.findall(r"%([\w.\-]+)", group) for group in re.findall(
+        r" conditional\(.*?branch_computations=\{([^}]*)\}", text)]
+    assert sorted(map(len, switches)) == [len(rungs)] * 2   # forward, backward
+    # a float array of bound x width: (T k, d), (R, d), (R, f), (T, k, d)
+    bound = re.compile(
+        r"= \(?(?:bf16|f32)\[(?:%d|%d),\d{3,}\]|= \(?(?:bf16|f32)\[%d,%d,"
+        % (T * k, rungs[-1], T, k))
+    calls = []
+    for branches in switches:
+        for rung, branch in enumerate(branches):
+            bodies = [computations[c] for c in _reached(computations, branch)]
+            calls.append(sum(
+                bool(re.search(match, name)) for b in bodies
+                for name in re.findall(
+                    r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', b)))
+            sized = [line.strip()[:120] for b in bodies
+                     for line in b.splitlines() if bound.search(line)]
+            assert bool(sized) == (rung == len(rungs) - 1), (rung, sized[:3])
+    assert sorted(calls) == [2] * len(rungs) + [6] * len(rungs)
+    entry = computations[re.search(r"^ENTRY %(\S+)", text, re.M).group(1)]
+    assert not [line for line in entry.splitlines() if bound.search(line)]
+    # against the bound's body alone: one rung, nothing to switch over
+    real_ladder = lfm2.ladder
+    monkeypatch.setattr(lfm2, "ladder", lambda *a: real_ladder(*a)[-1:])
+    alone = compiled()
+    assert alone.as_text().count('custom_call_target="tpu_custom_call"') == 10
+    assert (laddered.memory_analysis().temp_size_in_bytes
+            < 1.05 * alone.memory_analysis().temp_size_in_bytes)
+
+
 def test_causal_flash_backward_compiles_for_v5e_at_the_lfm2_cells_shape(
         topo, monkeypatch):
     """The attention layer of cell lfm2_8b_a1b_ep4_b2_L8192 (2 x 8192 x 32

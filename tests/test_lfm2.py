@@ -167,6 +167,99 @@ def test_a_planted_routing_onto_the_held_experts_drops_nothing():
     assert float(counted[lfm2.COUNTERS]["experts"]["moe_pairs"][0]) == 0
 
 
+def test_the_ladder_at_the_cells_shapes_ends_in_the_dropless_bound():
+    # SmallThinker: 16,384 tokens, top-6 of 64, 8 held; LFM2: top-4 of 32
+    assert lfm2.ladder(16384 * 6, 8, 64) == (17408, 32768, 100352)
+    assert lfm2.ladder(16384 * 4, 8, 32) == (22528, 43008, 67584)
+    for pairs, count, experts in ((16384 * 6, 8, 64), (16384 * 4, 8, 32),
+                                  (128, 2, 8), (256, 4, 8), (7, 3, 3)):
+        for tile in (8, 256):
+            rungs = lfm2.ladder(pairs, count, experts, tile)
+            assert 1 <= len(rungs) <= 3 and list(rungs) == sorted(set(rungs))
+            assert all(r % tile == 0 for r in rungs)
+            # the last rung is dispatch's R: every pair here, a tile an expert
+            assert rungs[-1] == (-(-pairs // tile) + count) * tile
+
+
+_LADDER_TILE = 8        # 64 tokens, top-2 of 8, experts 2-3 held: 7, 12, 18 tiles
+
+
+def _dense_experts(tokens, weights, w13, w2, sel, first, gate):
+    """The held experts' share by a loop over them, every token through
+    every held expert."""
+    f, y = w2.shape[1], 0.0
+    for e in range(w13.shape[0]):
+        h = tokens @ w13[e]
+        out = (gate(h[:, :f]) * h[:, f:]) @ w2[e]
+        y += jnp.sum(jnp.where(sel == first + e, weights, 0), -1)[:, None] * out
+    return y
+
+
+@pytest.mark.parametrize("held, rows", [
+    ((16, 16), 56),      # even routing: the first rung, with room
+    ((32, 24), 56),      # the owned tiles are the first rung's, all 7
+    ((32, 25), 96),      # one tile more: the second rung
+    ((48, 48), 96),      # the second rung's 12 tiles, all owned
+    ((49, 48), 144),     # one more: the last rung
+    ((64, 64), 144),     # every pair onto the held experts: the bound
+])
+def test_every_rung_of_the_ladder_computes_the_dense_loops_share(
+        monkeypatch, held, rows):
+    """Routings planted so that the owned tiles land on each rung and on
+    each side of a rung's edge: the rung is the smallest that covers them
+    (``moe_rows_bound``), output and gradients are the dense loop's on
+    every rung, and a higher rung than needed gives the same numbers."""
+    monkeypatch.setattr(lfm2, "GMM_TILE_M", _LADDER_TILE)
+    cfg = dataclasses.replace(
+        build_model("Lfm2Tiny").config, experts_held=(2, 2))
+    T, d, f = 64, cfg.hidden_size, cfg.moe_intermediate_size
+    assert lfm2.ladder(2 * T, 2, 8, _LADDER_TILE) == (56, 96, 144)
+    keys = jax.random.split(jax.random.PRNGKey(11), 5)
+    tokens = jax.random.normal(keys[0], (T, d))
+    weights = jax.random.uniform(keys[1], (T, 2), minval=0.2, maxval=0.8)
+    w13 = jax.random.normal(keys[2], (2, d, 2 * f)) / d ** 0.5
+    w2 = jax.random.normal(keys[3], (2, f, d)) / f ** 0.5
+    probe = jax.random.normal(keys[4], (T, d))
+    # token t's first choice is expert 2 while t < held[0] (else expert 0,
+    # held elsewhere), its second expert 3 while t < held[1] (else 1)
+    t = jnp.arange(T)
+    sel = jnp.stack([jnp.where(t < held[0], 2, 0),
+                     jnp.where(t < held[1], 3, 1)], axis=-1).astype(jnp.int32)
+
+    def layer(tokens, weights, w13, w2):
+        y, counted = lfm2.Experts(cfg).apply(
+            {"params": {"w13": w13, "w2": w2}}, tokens, sel, weights,
+            mutable=[lfm2.COUNTERS])
+        return jnp.sum(y * probe), (y, counted[lfm2.COUNTERS])
+
+    def dense(tokens, weights, w13, w2):
+        y = _dense_experts(tokens, weights, w13, w2, sel, 2, jax.nn.silu)
+        return jnp.sum(y * probe), y
+
+    inputs = (tokens, weights, w13, w2)
+    with jax.default_matmul_precision("highest"):
+        (_, (y, counted)), grads = jax.value_and_grad(
+            layer, (0, 1, 2, 3), has_aux=True)(*inputs)
+        (_, want), want_grads = jax.value_and_grad(
+            dense, (0, 1, 2, 3), has_aux=True)(*inputs)
+        np.testing.assert_allclose(y, want, atol=1e-5)
+        for got, ref in zip(grads, want_grads):
+            np.testing.assert_allclose(got, ref, atol=2e-5)
+        assert float(counted["moe_pairs"][0]) == sum(held)
+        assert float(counted["moe_rows"][0]) == _LADDER_TILE * sum(
+            max(1, -(-n // _LADDER_TILE)) for n in held)
+        assert float(counted["moe_rows_bound"][0]) == rows
+        # the same routing in the buffers of the last rung alone
+        real_ladder = lfm2.ladder
+        monkeypatch.setattr(lfm2, "ladder", lambda *a: real_ladder(*a)[-1:])
+        (_, (y_top, counted)), grads_top = jax.value_and_grad(
+            layer, (0, 1, 2, 3), has_aux=True)(*inputs)
+    assert float(counted["moe_rows_bound"][0]) == 144
+    np.testing.assert_allclose(y_top, y, atol=1e-6)
+    for got, ref in zip(grads_top, grads):
+        np.testing.assert_allclose(got, ref, atol=1e-6)
+
+
 @pytest.mark.parametrize("every_block", [False, True])
 def test_the_backward_pass_keeps_the_routing_the_forward_pass_decided(
         tiny, monkeypatch, every_block):
@@ -305,6 +398,56 @@ def test_grouped_matmul_against_a_dense_loop(sizes):
     np.testing.assert_allclose(dw, rw, atol=1e-3)   # zeros for an empty group
 
 
+@pytest.mark.parametrize("weighted", [True, False])
+@pytest.mark.parametrize("sizes", [[5, 0, 17, 8], [0, 0, 0, 0], [24, 24, 24, 24]])
+def test_sum_rows_against_a_scatter_add(sizes, weighted):
+    """The row sum of combine and of dispatch's transpose: rows laid out
+    by group, a group's rows in token order, a token at most once a group;
+    a row that holds nothing is matched by no token, whatever it holds."""
+    tile, tokens, d, groups = 8, 24, 128, len(sizes)
+    rows = sum(sizes) + groups * tile
+    rows += -rows % tile
+    sizes = jnp.array(sizes, jnp.int32)
+    starts, meta = pk.group_tiles(sizes, rows, tile)
+    r = jnp.arange(rows)
+    owner = jnp.repeat(meta[:-1], tile)
+    offset = r - starts[owner]
+    real = (offset < sizes[owner]) & (r // tile < meta[-1])
+    # group g's rows hold g's first sizes[g] tokens of a seeded order, sorted
+    order = jnp.stack([jnp.pad(jnp.sort(jax.random.permutation(
+        jax.random.PRNGKey(g), tokens)[:int(n)]).astype(jnp.int32),
+        (0, tokens - int(n))) for g, n in enumerate(sizes)])
+    token = jnp.where(real, order[owner, jnp.clip(offset, 0, tokens - 1)], 0)
+    keys = jax.random.split(jax.random.PRNGKey(9), 3)
+    written = (r // tile < meta[-1])[:, None]
+    values = jnp.where(written, jax.random.normal(keys[0], (rows, d)), jnp.nan)
+    values = jnp.where(real[:, None] | ~written, values, 7.0)  # padding rows
+    weight = jnp.where(real, jax.random.uniform(keys[1], (rows,)), 0.0)
+    probe = jax.random.normal(keys[2], (tokens, d))
+
+    def kernel(values, weight):
+        out = pk.sum_rows(values, weight if weighted else None, token, real,
+                          meta, groups, tokens, tile)
+        return jnp.sum(out * probe), out
+
+    def scatter(values, weight):
+        scaled = values * weight[:, None] if weighted else values
+        out = jnp.zeros((tokens, d)).at[token].add(
+            jnp.where(real[:, None], scaled, 0))
+        return jnp.sum(out * probe), out
+
+    (_, got), grads = jax.value_and_grad(kernel, (0, 1), has_aux=True)(
+        values, weight)
+    (_, want), want_grads = jax.value_and_grad(scatter, (0, 1), has_aux=True)(
+        values, weight)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    np.testing.assert_allclose(grads[0], jnp.where(
+        real[:, None], want_grads[0], 0), atol=1e-5)
+    if weighted:
+        np.testing.assert_allclose(
+            grads[1], jnp.where(real, want_grads[1], 0), atol=1e-5)
+
+
 def test_the_trainer_takes_the_preset_and_its_records_carry_the_counters(
         tmp_path):
     from pytorch_distributed_nn_tpu.observability import reader
@@ -329,9 +472,13 @@ def test_the_trainer_takes_the_preset_and_its_records_carry_the_counters(
     steps = [r for r in steps if r.get("kind") == "step"]
     assert len(steps) == 20 and steps[-1]["loss"] < steps[0]["loss"]
     tokens = 2 * 64                       # a replica's tokens a step
+    # at these sizes the ladder is the dropless bound alone: a tile for the
+    # 256 pairs and one an expert, four layers
+    assert lfm2.ladder(2 * tokens, 4, 8) == (5 * pk.GMM_TILE_M,)
     for r in steps:
         assert r["moe_layers"] == 4
         assert 0 < r["moe_pairs"] <= r["moe_rows"]
+        assert r["moe_rows"] <= r["moe_rows_bound"] == 4 * 5 * pk.GMM_TILE_M
         assert r["moe_pairs"] <= 4 * 2 * tokens       # top-2, four layers
         assert r["moe_load_max"] >= r["moe_load_mean"] > 0
     # Adam moves the weights and leaves the bias buffer where it was seeded
@@ -403,11 +550,13 @@ def _wrong_expert(group_sizes, rows, tile_m=pk.GMM_TILE_M):
 
 
 def _dropping(real_dispatch):
-    """A dispatch with a capacity: each token's second choice is dropped."""
+    """A dispatch with a capacity: each token's second choice is dropped
+    (its row holds nothing, which is what the row-space combine reads)."""
     def dispatch(sel, first, count, *a):
         pair, real, dest, local, meta, counts = real_dispatch(
             sel, first, count, *a)
-        return pair, real, dest, local.at[:, 1:].set(False), meta, counts
+        kept = pair % sel.shape[1] == 0
+        return pair, real & kept, dest, local, meta, counts
     return dispatch
 
 
