@@ -150,6 +150,13 @@ EVENT_TYPES = (
     "replica_up",
     "replica_down",
     "drain",
+    # set-up (observability/spans.py SetupLog, observability/compiles.py):
+    # the setup/* spans with the compile work charged to each, one event
+    # at the end of Trainer.__init__ and one per train() call's first
+    # iteration / a program made outside every setup/* span, under the
+    # step the loop was on
+    "setup",
+    "compile",
 )
 
 #: seconds-scale histogram buckets: wide enough for μs-scale data phases

@@ -580,6 +580,47 @@ def _selftest() -> int:
                   promexport.render(reader.replay_registry(rs_v1))
               ))
 
+        # set-up (observability/spans.py SetupLog, compiles.py): the
+        # setup line sums the spans' programs, names the slowest and the
+        # program made in the loop; a stream without them has no line
+        from pytorch_distributed_nn_tpu.observability.core import Telemetry
+
+        def span(name, parent, seconds, compiled, cached):
+            return {"name": name, "parent": parent, "mono0": 0.0,
+                    "mono1": seconds, "seconds": seconds, "fetch_s": 0.0,
+                    "compile_s": {"trace": 0.0, "lower": 0.0,
+                                  "backend": seconds / 2},
+                    "programs": {"compiled": compiled, "cached": cached}}
+
+        setup_run = os.path.join(d, "setup")
+        t = Telemetry.for_run(os.path.join(setup_run, "telemetry.jsonl"))
+        t.emit("setup", spans=[span("setup/model", "setup/init", 4.0, 0, 120),
+                               span("setup/init", None, 5.0, 0, 0)],
+               slowest=[{"fun_name": "init", "seconds": 2.0,
+                         "source": "cached", "span": "setup/model"}])
+        t.emit("setup", step=1,
+               spans=[span("setup/first_step", "train/step", 8.0, 1, 0)],
+               slowest=[{"fun_name": "train_step", "seconds": 4.0,
+                         "source": "compiled", "span": "setup/first_step"}])
+        t.emit("compile", step=7, fun_name="planted", source="compiled",
+               compile_s={"trace": 0.1, "lower": 0.1, "backend": 0.2},
+               fetch_s=0.0)
+        t.close()
+        rs_setup = reader.read_stream(setup_run)
+        su = reader.summarize_run(rs_setup).get("setup") or {}
+        text = reader.render_summary(reader.summarize_run(rs_setup))
+        check("setup line: seconds per span, programs, slowest, recompiles",
+              su.get("compiled") == 1 and su.get("cached") == 120
+              and "first_step@1 8.00s" in text
+              and "1 programs compiled, 120 fetched" in text
+              and "slowest train_step 4.00s (compiled)" in text
+              and "planted (compiled) at step 7" in text,
+              f"setup={su}")
+        check("a stream without setup events has no setup section",
+              reader.summarize_run(rs).get("setup") is None
+              and "setup:" not in reader.render_summary(s),
+              "setup section on a stream without setup events")
+
     failed = [c for c in checks if not c[1]]
     for name, ok, detail in checks:
         mark = "PASS" if ok else "FAIL"

@@ -24,8 +24,14 @@ histogram + ``pdtn_input_wait_ms_total`` counter (step loop blocked on
 the input pipeline, docs/data.md), ``pdtn_events_total{type=...}``
 (typed telemetry events by type), ``pdtn_run_info{run_id=...}`` (run
 identity, value always 1 — the classic info-gauge join key) and the
-``pdtn_phase_seconds{phase=...}`` histogram (utils/timing.py phase
-timer).
+``pdtn_phase_seconds{phase=...}`` histogram (one label per span of
+observability/spans.py).
+
+Compile families (``observability/compiles.py``, set-up and any
+recompile): ``pdtn_compile_seconds{stage=trace|lower|backend}``
+histogram (each stage's exclusive seconds) and
+``pdtn_programs_total{source=compiled|cached}`` counter (programs the
+backend compiled or fetched from the persistent cache).
 
 Checkpoint families (``training/async_ckpt.py``, docs/training.md):
 ``pdtn_ckpt_queue_depth`` (saves in flight) and

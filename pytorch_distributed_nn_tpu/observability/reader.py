@@ -650,6 +650,40 @@ def experts_summary(rs: RunStream) -> Optional[dict]:
     return out
 
 
+def setup_summary(rs: RunStream) -> Optional[dict]:
+    """The set-up section of ``obs summary``: seconds and programs per
+    ``setup/*`` span (the ``setup`` events, observability/spans.py), the
+    slowest programs, and the programs made outside every span
+    (``compile`` events, observability/compiles.py). ``None`` for streams
+    without them — the absent-family contract."""
+    events = [e for e in rs.events if e.get("type") == "setup"]
+    outside = [e for e in rs.events if e.get("type") == "compile"]
+    if not events and not outside:
+        return None
+    spans = []
+    for e in events:
+        for s in e.get("spans", []):
+            programs = s.get("programs") or {}
+            spans.append({
+                "name": s["name"], "step": e.get("step"),
+                "seconds": s["seconds"],
+                "compile_s": sum((s.get("compile_s") or {}).values()),
+                "compiled": programs.get("compiled", 0),
+                "cached": programs.get("cached", 0),
+            })
+    slowest = sorted((f for e in events for f in e.get("slowest", [])),
+                     key=lambda f: f["seconds"], reverse=True)
+    return {
+        "spans": spans,
+        "compiled": sum(s["compiled"] for s in spans),
+        "cached": sum(s["cached"] for s in spans),
+        "compile_s": sum(s["compile_s"] for s in spans),
+        "slowest": slowest[:3],
+        "outside": [{"step": e.get("step"), "fun_name": e.get("fun_name"),
+                     "source": e.get("source")} for e in outside],
+    }
+
+
 def summarize_run(rs: RunStream, skip: int = 1) -> dict:
     """Everything `obs summary` prints, as one JSON-able dict.
 
@@ -719,6 +753,7 @@ def summarize_run(rs: RunStream, skip: int = 1) -> dict:
         "serving": serving_summary(rs),
         "efficiency": efficiency_summary(rs, skip=skip),
         "experts": experts_summary(rs),
+        "setup": setup_summary(rs),
         "events": dict(sorted(events_by_type.items())),
         # deployment transitions (serving/router.py, docs/serving.md
         # "Deployment lifecycle"): every swap/canary/promote/rollback of
@@ -1081,6 +1116,26 @@ def render_summary(summary: dict, manifest: Optional[dict] = None) -> str:
                     f"{row['latency_ms']:7.2f}ms  {dom_s:<22} "
                     f"{row.get('version') or '-'}"
                 )
+    setup = summary.get("setup")
+    if setup:
+        parts = []
+        for sp in setup["spans"]:
+            name = sp["name"].split("/", 1)[1]
+            if sp["name"] == "setup/first_step":
+                name += f"@{sp['step']}"
+            parts.append(f"{name} {sp['seconds']:.2f}s")
+        line = (f"setup: {', '.join(parts)} · {setup['compiled']} programs "
+                f"compiled, {setup['cached']} fetched, "
+                f"{setup['compile_s']:.2f}s making them")
+        if setup["slowest"]:
+            line += " · slowest " + ", ".join(
+                f"{f['fun_name']} {f['seconds']:.2f}s ({f['source'] or 'traced'})"
+                for f in setup["slowest"])
+        if setup["outside"]:
+            line += " · outside set-up: " + ", ".join(
+                f"{o['fun_name']} ({o['source']}) at step {o['step']}"
+                for o in setup["outside"])
+        lines.append(line)
     moe = summary.get("experts")
     if moe:
         line = (f"experts: {moe['pairs_per_layer']:.0f} pairs a layer a step"

@@ -38,8 +38,9 @@ from pytorch_distributed_nn_tpu.parallel import (
     num_workers,
     replicated_sharding,
 )
+from pytorch_distributed_nn_tpu.observability import compiles
 from pytorch_distributed_nn_tpu.observability import core as obs
-from pytorch_distributed_nn_tpu.observability.spans import span
+from pytorch_distributed_nn_tpu.observability.spans import SetupLog, span
 from pytorch_distributed_nn_tpu.resilience.faults import (
     FaultPlan,
     InjectedCrash,
@@ -54,7 +55,7 @@ from pytorch_distributed_nn_tpu.training.train_step import (
     run_eval_pass,
     tree_bytes,
 )
-from pytorch_distributed_nn_tpu.utils.timing import MetricsLogger, PhaseTimer
+from pytorch_distributed_nn_tpu.utils.timing import MetricsLogger
 
 logger = logging.getLogger(__name__)
 
@@ -82,6 +83,16 @@ class Trainer:
         return self.state
 
     def __init__(self, config: TrainConfig, devices=None):
+        # set-up's spans (observability/spans.py): the run's registry is
+        # made first, so the spans that close before the stream opens
+        # observe into it; the compile listener charges each program to
+        # the span that caused it
+        self._setup = SetupLog(obs.MetricRegistry())
+        compiles.install()
+        with self._setup.span("setup/init"):
+            self._init(config, devices)
+
+    def _init(self, config: TrainConfig, devices) -> None:
         self.config = c = config
         import jax.numpy as jnp
 
@@ -295,523 +306,526 @@ class Trainer:
             )
 
             model_kw["attn_fn"] = make_mesh_attn(self.mesh, c.seq_attn)
-        self.model = build_model(c.network, num_classes, **model_kw)
-        if self.use_spmd:
-            heads = self.model.config.num_heads
-            if heads % c.tensor_parallel:
-                raise ValueError(
-                    f"num_heads={heads} not divisible by "
-                    f"tensor_parallel={c.tensor_parallel} (heads shard "
-                    "over the model axis)"
-                )
-            if (
-                c.seq_parallel > 1
-                and c.seq_attn == "ulysses"
-                and (heads // c.tensor_parallel) % c.seq_parallel
-            ):
-                raise ValueError(
-                    f"ulysses needs heads/tp={heads // c.tensor_parallel} "
-                    f"divisible by seq_parallel={c.seq_parallel} "
-                    "(all-to-all re-shards seq->heads); use seq_attn='ring'"
-                )
-        if c.warmup_steps or c.lr_decay_steps:
-            # Linear warmup 0 -> lr over warmup_steps, then (optionally)
-            # step decay. The reference had NO schedule at all; decay came
-            # in round 2 for the CIFAR recipes, warmup in round 3 because
-            # large-vocab transformer runs need it (an un-warmed Adam at
-            # transformer-scale lr sits at the uniform plateau — measured
-            # on the BERT-base convergence runs, docs/artifacts).
-            warm = c.warmup_steps
-            decay_every = c.lr_decay_steps
-
-            def lr(count):
-                scale = 1.0
-                if warm:
-                    scale = jnp.minimum(1.0, (count + 1) / warm)
-                if decay_every:
-                    scale = scale * (
-                        c.lr_decay_factor ** (count // decay_every)
-                    )
-                return c.lr * scale
-        else:
-            lr = c.lr
-        self.optimizer = build_optimizer(
-            c.optimizer, lr, momentum=c.momentum,
-            weight_decay=c.weight_decay, nesterov=c.nesterov,
-        )
-        self.fault_plan = None
-        if c.faults:
-            self.fault_plan = FaultPlan.parse(c.faults, seed=c.seed)
-            bad_rank = self.fault_plan.max_rank_referenced()
-            if bad_rank >= self.n_workers:
-                raise ValueError(
-                    f"fault plan references rank p{bad_rank} but the mesh "
-                    f"has {self.n_workers} data-parallel workers"
-                )
-            if self.is_text and any(
-                e.kind == "nan_grad" for e in self.fault_plan.entries
-            ):
-                raise ValueError(
-                    "nan_grad faults poison the float image batch; text "
-                    "batches are integer token ids (no NaN representation)"
-                )
-            logger.info("Fault plan: %s", self.fault_plan.describe())
-        self._straggler_sim = None
-        if c.straggler_deadline is not None:
+        with self._setup.span("setup/model"):
+            self.model = build_model(c.network, num_classes, **model_kw)
             if self.use_spmd:
-                raise ValueError(
-                    "straggler simulation masks per-replica gradients "
-                    "inside the shard_map DP sync; the GSPMD (tp/sp) "
-                    "all-reduce has no per-replica contribution to drop"
-                )
-            from pytorch_distributed_nn_tpu.resilience.stragglers import (
-                make_straggler_sim,
-            )
-
-            self._straggler_sim = make_straggler_sim(
-                c.straggler_deadline,
-                min_keep=c.straggler_min_keep,
-                fault_plan=self.fault_plan,
-            )
-        if c.skip_nonfinite and self.use_spmd:
-            raise ValueError(
-                "skip_nonfinite guards the shard_map DP step; the GSPMD "
-                "(tp/sp) step has no non-finite guard yet"
-            )
-        self.grad_sync = make_grad_sync(
-            c.sync_mode,
-            num_aggregate=c.num_aggregate,
-            compression=c.compression,
-            topk_ratio=c.topk_ratio,
-            bucket_bytes=c.bucket_bytes,
-            kill_ranks=tuple(c.kill_ranks),
-            straggler=self._straggler_sim,
-        )
-        if self.is_text:
-            self.seq_len = c.seq_len or input_spec(c.network)[0]
-            self.vocab_size = c.vocab_size or self.model.config.vocab_size
-            in_shape, in_dtype = (self.seq_len,), jnp.int32
-            if self.seq_len % c.seq_parallel:
-                raise ValueError(
-                    f"seq_len {self.seq_len} not divisible by "
-                    f"seq_parallel={c.seq_parallel}"
-                )
-        else:
-            in_shape, in_dtype = input_spec(c.network), jnp.float32
-        if self.use_spmd:
-            from pytorch_distributed_nn_tpu.training.spmd import (
-                create_spmd_state,
-            )
-
-            self.state, self._spmd_shardings = create_spmd_state(
-                self.model, self.optimizer, jax.random.PRNGKey(c.seed),
-                (c.batch_size, self.seq_len), self.mesh,
-            )
-        else:
-            self.state = create_train_state(
-                self.model,
-                self.optimizer,
-                self.grad_sync,
-                jax.random.PRNGKey(c.seed),
-                in_shape,
-                num_replicas=self.n_workers,
-                input_dtype=in_dtype,
-            )
-        self.start_step = 0
-        if c.warm_start:
-            if c.resume:
-                raise ValueError(
-                    "warm_start and resume are mutually exclusive: resume "
-                    "restores this run's own checkpoints (same geometry + "
-                    "optimizer state); warm_start performs cross-geometry "
-                    "parameter surgery from another run's checkpoint"
-                )
-            from pytorch_distributed_nn_tpu.training.warm_start import (
-                warm_start_params,
-            )
-
-            tgt = self.state.params
-            if self.use_spmd and jax.process_count() > 1:
-                # GSPMD params span processes (non-addressable shards);
-                # np.asarray on them raises. Fetch the replicated global
-                # value on every host for the (host-side) merge surgery —
-                # tiled=True is the global-array mode of process_allgather.
-                from jax.experimental import multihost_utils
-
-                tgt = multihost_utils.process_allgather(tgt, tiled=True)
-            merged = warm_start_params(
-                c.warm_start, jax.tree.map(np.asarray, tgt)
-            )
-            if jax.process_count() > 1:
-                # The copied overlap comes from the shared file, but the
-                # fresh/resized-tail values come from each process's own
-                # model init — identical only while init stays seeded and
-                # process-independent. A divergent init would silently
-                # desync the "replicated" params across hosts, so verify
-                # the whole merged tree agrees before materializing it.
-                import hashlib
-
-                from jax.experimental import multihost_utils
-
-                h = hashlib.sha256()
-                for leaf in jax.tree.leaves(merged):
-                    h.update(np.ascontiguousarray(leaf).tobytes())
-                # int32 pair, not int64: x64-disabled JAX would silently
-                # truncate the device round-trip inside process_allgather
-                dig = np.frombuffer(h.digest()[:8], dtype=np.int32)
-                all_dig = multihost_utils.process_allgather(dig)
-                if not (all_dig == dig).all():
-                    raise RuntimeError(
-                        "warm_start produced different merged params on "
-                        "different processes (digests "
-                        f"{np.unique(all_dig).tolist()}); model init must "
-                        "be seeded identically on every host"
+                heads = self.model.config.num_heads
+                if heads % c.tensor_parallel:
+                    raise ValueError(
+                        f"num_heads={heads} not divisible by "
+                        f"tensor_parallel={c.tensor_parallel} (heads shard "
+                        "over the model axis)"
                     )
+                if (
+                    c.seq_parallel > 1
+                    and c.seq_attn == "ulysses"
+                    and (heads // c.tensor_parallel) % c.seq_parallel
+                ):
+                    raise ValueError(
+                        f"ulysses needs heads/tp={heads // c.tensor_parallel} "
+                        f"divisible by seq_parallel={c.seq_parallel} "
+                        "(all-to-all re-shards seq->heads); use seq_attn='ring'"
+                    )
+            if c.warmup_steps or c.lr_decay_steps:
+                # Linear warmup 0 -> lr over warmup_steps, then (optionally)
+                # step decay. The reference had NO schedule at all; decay came
+                # in round 2 for the CIFAR recipes, warmup in round 3 because
+                # large-vocab transformer runs need it (an un-warmed Adam at
+                # transformer-scale lr sits at the uniform plateau — measured
+                # on the BERT-base convergence runs, docs/artifacts).
+                warm = c.warmup_steps
+                decay_every = c.lr_decay_steps
 
-            def _put(a, old):
-                a = np.asarray(a, dtype=old.dtype)
+                def lr(count):
+                    scale = 1.0
+                    if warm:
+                        scale = jnp.minimum(1.0, (count + 1) / warm)
+                    if decay_every:
+                        scale = scale * (
+                            c.lr_decay_factor ** (count // decay_every)
+                        )
+                    return c.lr * scale
+            else:
+                lr = c.lr
+            self.optimizer = build_optimizer(
+                c.optimizer, lr, momentum=c.momentum,
+                weight_decay=c.weight_decay, nesterov=c.nesterov,
+            )
+            self.fault_plan = None
+            if c.faults:
+                self.fault_plan = FaultPlan.parse(c.faults, seed=c.seed)
+                bad_rank = self.fault_plan.max_rank_referenced()
+                if bad_rank >= self.n_workers:
+                    raise ValueError(
+                        f"fault plan references rank p{bad_rank} but the mesh "
+                        f"has {self.n_workers} data-parallel workers"
+                    )
+                if self.is_text and any(
+                    e.kind == "nan_grad" for e in self.fault_plan.entries
+                ):
+                    raise ValueError(
+                        "nan_grad faults poison the float image batch; text "
+                        "batches are integer token ids (no NaN representation)"
+                    )
+                logger.info("Fault plan: %s", self.fault_plan.describe())
+            self._straggler_sim = None
+            if c.straggler_deadline is not None:
                 if self.use_spmd:
-                    # create_spmd_state built real global shardings.
-                    target = old.sharding
-                else:
-                    # The shard_map path keeps params REPLICATED over the
-                    # mesh (state_spec P() in build_train_step). old's
-                    # arrays are uncommitted (SingleDeviceSharding), and
-                    # committing the merged params there would pin the
-                    # whole state to device 0 — fatal under multi-process
-                    # meshes ("incompatible devices" at the first step).
-                    target = jax.sharding.NamedSharding(
-                        self.mesh, jax.sharding.PartitionSpec()
+                    raise ValueError(
+                        "straggler simulation masks per-replica gradients "
+                        "inside the shard_map DP sync; the GSPMD (tp/sp) "
+                        "all-reduce has no per-replica contribution to drop"
                     )
-                if jax.process_count() > 1:
-                    # Multi-host: the merged tree is host-global and
-                    # deterministic (every process reads the same file),
-                    # so each process materializes just its addressable
-                    # shards. c.warm_start must be readable on all hosts
-                    # (same contract as the pod tooling's shared dirs).
-                    return jax.make_array_from_callback(
-                        a.shape, target, lambda idx, a=a: a[idx]
-                    )
-                return jax.device_put(jnp.asarray(a), target)
+                from pytorch_distributed_nn_tpu.resilience.stragglers import (
+                    make_straggler_sim,
+                )
 
-            self.state = self.state.replace(
-                params=jax.tree.map(_put, merged, self.state.params)
+                self._straggler_sim = make_straggler_sim(
+                    c.straggler_deadline,
+                    min_keep=c.straggler_min_keep,
+                    fault_plan=self.fault_plan,
+                )
+            if c.skip_nonfinite and self.use_spmd:
+                raise ValueError(
+                    "skip_nonfinite guards the shard_map DP step; the GSPMD "
+                    "(tp/sp) step has no non-finite guard yet"
+                )
+            self.grad_sync = make_grad_sync(
+                c.sync_mode,
+                num_aggregate=c.num_aggregate,
+                compression=c.compression,
+                topk_ratio=c.topk_ratio,
+                bucket_bytes=c.bucket_bytes,
+                kill_ranks=tuple(c.kill_ranks),
+                straggler=self._straggler_sim,
             )
-        if c.resume and self.use_spmd:
-            # Sharded resume: every process reads its OWN shards from the
-            # shared train_dir and the state lands on the mesh already
-            # partitioned — no host ever holds the full model. Elastic
-            # resumes route through restore_resharded (file-or-dir,
-            # reshard-on-load); exact-geometry resumes keep the direct
-            # restore_sharded path.
-            def _restore(path, template):
-                if self._elastic_plan is not None:
-                    return ckpt.restore_resharded(
+            if self.is_text:
+                self.seq_len = c.seq_len or input_spec(c.network)[0]
+                self.vocab_size = c.vocab_size or self.model.config.vocab_size
+                in_shape, in_dtype = (self.seq_len,), jnp.int32
+                if self.seq_len % c.seq_parallel:
+                    raise ValueError(
+                        f"seq_len {self.seq_len} not divisible by "
+                        f"seq_parallel={c.seq_parallel}"
+                    )
+            else:
+                in_shape, in_dtype = input_spec(c.network), jnp.float32
+            if self.use_spmd:
+                from pytorch_distributed_nn_tpu.training.spmd import (
+                    create_spmd_state,
+                )
+
+                self.state, self._spmd_shardings = create_spmd_state(
+                    self.model, self.optimizer, jax.random.PRNGKey(c.seed),
+                    (c.batch_size, self.seq_len), self.mesh,
+                )
+            else:
+                self.state = create_train_state(
+                    self.model,
+                    self.optimizer,
+                    self.grad_sync,
+                    jax.random.PRNGKey(c.seed),
+                    in_shape,
+                    num_replicas=self.n_workers,
+                    input_dtype=in_dtype,
+                )
+            self.start_step = 0
+            if c.warm_start:
+                if c.resume:
+                    raise ValueError(
+                        "warm_start and resume are mutually exclusive: resume "
+                        "restores this run's own checkpoints (same geometry + "
+                        "optimizer state); warm_start performs cross-geometry "
+                        "parameter surgery from another run's checkpoint"
+                    )
+                from pytorch_distributed_nn_tpu.training.warm_start import (
+                    warm_start_params,
+                )
+
+                tgt = self.state.params
+                if self.use_spmd and jax.process_count() > 1:
+                    # GSPMD params span processes (non-addressable shards);
+                    # np.asarray on them raises. Fetch the replicated global
+                    # value on every host for the (host-side) merge surgery —
+                    # tiled=True is the global-array mode of process_allgather.
+                    from jax.experimental import multihost_utils
+
+                    tgt = multihost_utils.process_allgather(tgt, tiled=True)
+                merged = warm_start_params(
+                    c.warm_start, jax.tree.map(np.asarray, tgt)
+                )
+                if jax.process_count() > 1:
+                    # The copied overlap comes from the shared file, but the
+                    # fresh/resized-tail values come from each process's own
+                    # model init — identical only while init stays seeded and
+                    # process-independent. A divergent init would silently
+                    # desync the "replicated" params across hosts, so verify
+                    # the whole merged tree agrees before materializing it.
+                    import hashlib
+
+                    from jax.experimental import multihost_utils
+
+                    h = hashlib.sha256()
+                    for leaf in jax.tree.leaves(merged):
+                        h.update(np.ascontiguousarray(leaf).tobytes())
+                    # int32 pair, not int64: x64-disabled JAX would silently
+                    # truncate the device round-trip inside process_allgather
+                    dig = np.frombuffer(h.digest()[:8], dtype=np.int32)
+                    all_dig = multihost_utils.process_allgather(dig)
+                    if not (all_dig == dig).all():
+                        raise RuntimeError(
+                            "warm_start produced different merged params on "
+                            "different processes (digests "
+                            f"{np.unique(all_dig).tolist()}); model init must "
+                            "be seeded identically on every host"
+                        )
+
+                def _put(a, old):
+                    a = np.asarray(a, dtype=old.dtype)
+                    if self.use_spmd:
+                        # create_spmd_state built real global shardings.
+                        target = old.sharding
+                    else:
+                        # The shard_map path keeps params REPLICATED over the
+                        # mesh (state_spec P() in build_train_step). old's
+                        # arrays are uncommitted (SingleDeviceSharding), and
+                        # committing the merged params there would pin the
+                        # whole state to device 0 — fatal under multi-process
+                        # meshes ("incompatible devices" at the first step).
+                        target = jax.sharding.NamedSharding(
+                            self.mesh, jax.sharding.PartitionSpec()
+                        )
+                    if jax.process_count() > 1:
+                        # Multi-host: the merged tree is host-global and
+                        # deterministic (every process reads the same file),
+                        # so each process materializes just its addressable
+                        # shards. c.warm_start must be readable on all hosts
+                        # (same contract as the pod tooling's shared dirs).
+                        return jax.make_array_from_callback(
+                            a.shape, target, lambda idx, a=a: a[idx]
+                        )
+                    return jax.device_put(jnp.asarray(a), target)
+
+                self.state = self.state.replace(
+                    params=jax.tree.map(_put, merged, self.state.params)
+                )
+            if c.resume and self.use_spmd:
+                # Sharded resume: every process reads its OWN shards from the
+                # shared train_dir and the state lands on the mesh already
+                # partitioned — no host ever holds the full model. Elastic
+                # resumes route through restore_resharded (file-or-dir,
+                # reshard-on-load); exact-geometry resumes keep the direct
+                # restore_sharded path.
+                def _restore(path, template):
+                    if self._elastic_plan is not None:
+                        return ckpt.restore_resharded(
+                            path, template, self._spmd_shardings
+                        )
+                    return ckpt.restore_sharded(
                         path, template, self._spmd_shardings
                     )
-                return ckpt.restore_sharded(
-                    path, template, self._spmd_shardings
-                )
 
-            if jax.process_count() > 1:
-                # the step to resume from is agreed via a tiny int
-                # broadcast (hosts could otherwise race a checkpoint
-                # being published); no quarantine walk — renames on a
-                # shared dir cannot be coordinated from here
-                from jax.experimental import multihost_utils
+                if jax.process_count() > 1:
+                    # the step to resume from is agreed via a tiny int
+                    # broadcast (hosts could otherwise race a checkpoint
+                    # being published); no quarantine walk — renames on a
+                    # shared dir cannot be coordinated from here
+                    from jax.experimental import multihost_utils
 
-                step = ckpt.latest_step(c.train_dir)
-                step = int(
-                    multihost_utils.broadcast_one_to_all(
-                        np.int64(-1 if step is None else step)
+                    step = ckpt.latest_step(c.train_dir)
+                    step = int(
+                        multihost_utils.broadcast_one_to_all(
+                            np.int64(-1 if step is None else step)
+                        )
                     )
-                )
-                step = None if step < 0 else step
-                if step is not None:
-                    self.state = _restore(
-                        ckpt.checkpoint_path(c.train_dir, step), self.state
+                    step = None if step < 0 else step
+                    if step is not None:
+                        self.state = _restore(
+                            ckpt.checkpoint_path(c.train_dir, step), self.state
+                        )
+                        self.start_step = step
+                        logger.info("Resumed from step %d (sharded)", step)
+                else:
+                    # single-controller: the VALIDATED scan — per-shard CRCs
+                    # are checked per candidate, corrupt steps (including one
+                    # convicted mid-reshard) are quarantined and the scan
+                    # falls back to the previous valid step
+                    from pytorch_distributed_nn_tpu.resilience.supervisor import (
+                        resume_latest_valid,
                     )
-                    self.start_step = step
-                    logger.info("Resumed from step %d (sharded)", step)
-            else:
-                # single-controller: the VALIDATED scan — per-shard CRCs
-                # are checked per candidate, corrupt steps (including one
-                # convicted mid-reshard) are quarantined and the scan
-                # falls back to the previous valid step
+
+                    restored = resume_latest_valid(
+                        c.train_dir, self.state, restore_fn=_restore
+                    )
+                    if restored is not None:
+                        self.state = restored
+                        self.start_step = int(jax.device_get(restored.step))
+                        logger.info(
+                            "Resumed from step %d (sharded)", self.start_step
+                        )
+            elif c.resume:
+                # only process 0 reads the checkpoint (it is the only writer);
+                # the others receive the state via the broadcast below rather
+                # than each pulling GBs from a shared train_dir. The scan is
+                # the VALIDATED one: each candidate is checked against its
+                # CRC32 manifest, corrupt entries are quarantined into
+                # <train_dir>/quarantine/, and the newest intact step wins —
+                # a torn checkpoint costs one interval, never the run.
                 from pytorch_distributed_nn_tpu.resilience.supervisor import (
                     resume_latest_valid,
                 )
 
-                restored = resume_latest_valid(
-                    c.train_dir, self.state, restore_fn=_restore
+                template = self._host_state()
+                # elastic: restore_resharded tolerates a geometry change (the
+                # replicated state is mesh-independent except the per-replica
+                # EF residuals, which it resets with a warning); exact-match
+                # resumes keep the existing restore_checkpoint path bitwise.
+                restore_fn = None
+                if self._elastic_plan is not None:
+                    restore_fn = lambda p, t: ckpt.restore_resharded(p, t, None)
+                restored = (
+                    resume_latest_valid(
+                        c.train_dir, template, restore_fn=restore_fn
+                    )
+                    if jax.process_index() == 0
+                    else None
                 )
+                if jax.process_count() > 1:
+                    # Only process 0 writes checkpoints, and train_dir may be
+                    # host-local: without a broadcast the other processes would
+                    # restore nothing, start at step 0 while process 0 starts at
+                    # step N, and the per-process step loops would issue
+                    # different numbers of collectives (desync/hang).
+                    from jax.experimental import multihost_utils
+
+                    found = bool(
+                        multihost_utils.broadcast_one_to_all(
+                            np.int32(1 if restored is not None else 0)
+                        )
+                    )
+                    if found:
+                        restored = multihost_utils.broadcast_one_to_all(
+                            restored if restored is not None else template
+                        )
+                    else:
+                        restored = None
                 if restored is not None:
                     self.state = restored
-                    self.start_step = int(jax.device_get(restored.step))
-                    logger.info(
-                        "Resumed from step %d (sharded)", self.start_step
-                    )
-        elif c.resume:
-            # only process 0 reads the checkpoint (it is the only writer);
-            # the others receive the state via the broadcast below rather
-            # than each pulling GBs from a shared train_dir. The scan is
-            # the VALIDATED one: each candidate is checked against its
-            # CRC32 manifest, corrupt entries are quarantined into
-            # <train_dir>/quarantine/, and the newest intact step wins —
-            # a torn checkpoint costs one interval, never the run.
-            from pytorch_distributed_nn_tpu.resilience.supervisor import (
-                resume_latest_valid,
-            )
+                    self.start_step = int(restored.step)
+                    logger.info("Resumed from step %d", self.start_step)
 
-            template = self._host_state()
-            # elastic: restore_resharded tolerates a geometry change (the
-            # replicated state is mesh-independent except the per-replica
-            # EF residuals, which it resets with a warning); exact-match
-            # resumes keep the existing restore_checkpoint path bitwise.
-            restore_fn = None
-            if self._elastic_plan is not None:
-                restore_fn = lambda p, t: ckpt.restore_resharded(p, t, None)
-            restored = (
-                resume_latest_valid(
-                    c.train_dir, template, restore_fn=restore_fn
+        with self._setup.span("setup/step_build"):
+            if self.use_spmd:
+                from pytorch_distributed_nn_tpu.training.spmd import (
+                    build_spmd_eval_step,
+                    build_spmd_train_step,
+                    text_batch_sharding,
                 )
-                if jax.process_index() == 0
-                else None
-            )
-            if jax.process_count() > 1:
-                # Only process 0 writes checkpoints, and train_dir may be
-                # host-local: without a broadcast the other processes would
-                # restore nothing, start at step 0 while process 0 starts at
-                # step N, and the per-process step loops would issue
-                # different numbers of collectives (desync/hang).
-                from jax.experimental import multihost_utils
 
-                found = bool(
-                    multihost_utils.broadcast_one_to_all(
-                        np.int32(1 if restored is not None else 0)
-                    )
+                # Under GSPMD jit the loss's masked mean is computed over the
+                # GLOBAL (unsharded) arrays — no per-replica normalization
+                # wrappers needed; the partitioner inserts the reductions.
+                self.train_step = build_spmd_train_step(
+                    self.model, self.optimizer, self.mesh, self._spmd_shardings,
+                    compression=c.compression, grad_accum=c.grad_accum,
                 )
-                if found:
-                    restored = multihost_utils.broadcast_one_to_all(
-                        restored if restored is not None else template
+                self.eval_step = build_spmd_eval_step(
+                    self.model, self.mesh, self._spmd_shardings
+                )
+                sharding = text_batch_sharding(self.mesh)
+            else:
+                step_fns = {}
+                if self.is_text:
+                    from pytorch_distributed_nn_tpu.parallel.mesh import DATA_AXIS
+
+                    step_fns = {
+                        # normalize by the GLOBAL masked-token count
+                        # (per-replica counts differ; see
+                        # make_global_masked_cross_entropy)
+                        "loss_fn": make_global_masked_cross_entropy(DATA_AXIS),
+                        "metrics_fn": make_global_mlm_metrics(DATA_AXIS),
+                    }
+                train_step_fns = step_fns
+                if self.is_text:
+                    from pytorch_distributed_nn_tpu.ops.metrics import mlm_sums
+
+                    # grad_accum>1: exact (Σ masked-xent, Σ count)
+                    # accumulation — the same global masked mean, never the
+                    # biased mean-of-masked-means (mlm_sums docstring).
+                    # Train-step only; eval never accumulates.
+                    train_step_fns = {**step_fns, "pair_accum_fn": mlm_sums}
+                self.train_step = build_train_step(
+                    self.model, self.optimizer, self.grad_sync, self.mesh,
+                    bn_stats_sync=c.bn_stats_sync, grad_accum=c.grad_accum,
+                    nonfinite_guard=c.skip_nonfinite,
+                    **train_step_fns,
+                )
+                self.eval_step = build_eval_step(self.model, self.mesh, **step_fns)
+                sharding = batch_sharding(self.mesh)
+                if jax.process_count() == 1:
+                    # A fresh or restored state is uncommitted, while the step
+                    # returns it committed to the mesh: left alone, step 2
+                    # misses the jit cache and the whole train step compiles
+                    # twice. Commit it to the step's own output shardings:
+                    # replicated, except the per-replica EF residuals, whose
+                    # leading axis is split over the data axis like a batch.
+                    rep = replicated_sharding(self.mesh)
+                    placed = jax.tree.map(lambda _: rep, self.state)
+                    if self.state.ef_state is not None:
+                        placed = placed.replace(ef_state=jax.tree.map(
+                            lambda _: sharding, self.state.ef_state
+                        ))
+                    self.state = jax.device_put(self.state, placed)
+        with self._setup.span("setup/data"):
+            stream_meta = None
+            if c.data_path:
+                from pytorch_distributed_nn_tpu.data.streaming import load_meta
+
+                stream_meta = load_meta(c.data_path)
+                want = "tokens" if self.is_text else "image"
+                if stream_meta["kind"] != want:
+                    raise ValueError(
+                        f"{c.data_path} holds {stream_meta['kind']!r} shards "
+                        f"but network {c.network!r} needs {want!r} data"
+                    )
+            if self.is_text:
+                if stream_meta is not None:
+                    from pytorch_distributed_nn_tpu.data.streaming import (
+                        StreamingLoader,
+                    )
+
+                    if int(stream_meta["vocab_size"]) > self.vocab_size:
+                        raise ValueError(
+                            f"shard corpus vocab {stream_meta['vocab_size']} "
+                            f"exceeds the model's vocab_size={self.vocab_size};"
+                            " pass --vocab-size >= the exported corpus's"
+                        )
+                    self.train_loader = StreamingLoader(
+                        c.data_path, c.batch_size, seq_len=self.seq_len,
+                        mask_prob=c.mask_prob, vocab_size=self.vocab_size,
+                        seed=c.seed, sharding=sharding,
+                        prefetch=c.stream_prefetch, workers=c.loader_workers,
                     )
                 else:
-                    restored = None
-            if restored is not None:
-                self.state = restored
-                self.start_step = int(restored.step)
-                logger.info("Resumed from step %d", self.start_step)
-
-        if self.use_spmd:
-            from pytorch_distributed_nn_tpu.training.spmd import (
-                build_spmd_eval_step,
-                build_spmd_train_step,
-                text_batch_sharding,
-            )
-
-            # Under GSPMD jit the loss's masked mean is computed over the
-            # GLOBAL (unsharded) arrays — no per-replica normalization
-            # wrappers needed; the partitioner inserts the reductions.
-            self.train_step = build_spmd_train_step(
-                self.model, self.optimizer, self.mesh, self._spmd_shardings,
-                compression=c.compression, grad_accum=c.grad_accum,
-            )
-            self.eval_step = build_spmd_eval_step(
-                self.model, self.mesh, self._spmd_shardings
-            )
-            sharding = text_batch_sharding(self.mesh)
-        else:
-            step_fns = {}
-            if self.is_text:
-                from pytorch_distributed_nn_tpu.parallel.mesh import DATA_AXIS
-
-                step_fns = {
-                    # normalize by the GLOBAL masked-token count
-                    # (per-replica counts differ; see
-                    # make_global_masked_cross_entropy)
-                    "loss_fn": make_global_masked_cross_entropy(DATA_AXIS),
-                    "metrics_fn": make_global_mlm_metrics(DATA_AXIS),
-                }
-            train_step_fns = step_fns
-            if self.is_text:
-                from pytorch_distributed_nn_tpu.ops.metrics import mlm_sums
-
-                # grad_accum>1: exact (Σ masked-xent, Σ count)
-                # accumulation — the same global masked mean, never the
-                # biased mean-of-masked-means (mlm_sums docstring).
-                # Train-step only; eval never accumulates.
-                train_step_fns = {**step_fns, "pair_accum_fn": mlm_sums}
-            self.train_step = build_train_step(
-                self.model, self.optimizer, self.grad_sync, self.mesh,
-                bn_stats_sync=c.bn_stats_sync, grad_accum=c.grad_accum,
-                nonfinite_guard=c.skip_nonfinite,
-                **train_step_fns,
-            )
-            self.eval_step = build_eval_step(self.model, self.mesh, **step_fns)
-            sharding = batch_sharding(self.mesh)
-            if jax.process_count() == 1:
-                # A fresh or restored state is uncommitted, while the step
-                # returns it committed to the mesh: left alone, step 2
-                # misses the jit cache and the whole train step compiles
-                # twice. Commit it to the step's own output shardings:
-                # replicated, except the per-replica EF residuals, whose
-                # leading axis is split over the data axis like a batch.
-                rep = replicated_sharding(self.mesh)
-                placed = jax.tree.map(lambda _: rep, self.state)
-                if self.state.ef_state is not None:
-                    placed = placed.replace(ef_state=jax.tree.map(
-                        lambda _: sharding, self.state.ef_state
-                    ))
-                self.state = jax.device_put(self.state, placed)
-        stream_meta = None
-        if c.data_path:
-            from pytorch_distributed_nn_tpu.data.streaming import load_meta
-
-            stream_meta = load_meta(c.data_path)
-            want = "tokens" if self.is_text else "image"
-            if stream_meta["kind"] != want:
-                raise ValueError(
-                    f"{c.data_path} holds {stream_meta['kind']!r} shards "
-                    f"but network {c.network!r} needs {want!r} data"
+                    self.train_loader = MLMLoader(
+                        TEXT_DATASETS[c.dataset](
+                            vocab_size=self.vocab_size, seq_len=self.seq_len,
+                            batch_size=c.batch_size, seed=c.seed,
+                            mask_prob=c.mask_prob, branching=c.corpus_branching,
+                        ),
+                        sharding=sharding,
+                    )
+                test_bs = max(
+                    self.n_workers,
+                    c.test_batch_size - c.test_batch_size % self.n_workers,
                 )
-        if self.is_text:
-            if stream_meta is not None:
+                self.test_loader = MLMLoader(
+                    TEXT_DATASETS[c.dataset](
+                        vocab_size=self.vocab_size, seq_len=self.seq_len,
+                        batch_size=test_bs, seed=c.seed + 10_000,
+                        mask_prob=c.mask_prob, branching=c.corpus_branching,
+                        corpus_seed=c.seed,  # same language as training
+                    ),
+                    sharding=sharding,
+                    eval_batches=c.eval_batches,
+                )
+            elif stream_meta is not None:
+                # Streaming image input: the training set never materializes
+                # in host RAM (per-host shard files + bounded prefetch); only
+                # the (small) test split stays in-memory for the eval pass.
                 from pytorch_distributed_nn_tpu.data.streaming import (
                     StreamingLoader,
                 )
 
-                if int(stream_meta["vocab_size"]) > self.vocab_size:
+                num_classes_meta = int(stream_meta.get("num_classes", 0))
+                if num_classes_meta and num_classes_meta != num_classes:
                     raise ValueError(
-                        f"shard corpus vocab {stream_meta['vocab_size']} "
-                        f"exceeds the model's vocab_size={self.vocab_size};"
-                        " pass --vocab-size >= the exported corpus's"
+                        f"{c.data_path} was exported from a "
+                        f"{num_classes_meta}-class dataset "
+                        f"({stream_meta.get('name')!r}) but --dataset "
+                        f"{c.dataset!r} has {num_classes} classes"
                     )
                 self.train_loader = StreamingLoader(
-                    c.data_path, c.batch_size, seq_len=self.seq_len,
-                    mask_prob=c.mask_prob, vocab_size=self.vocab_size,
-                    seed=c.seed, sharding=sharding,
+                    c.data_path, c.batch_size, seed=c.seed, sharding=sharding,
                     prefetch=c.stream_prefetch, workers=c.loader_workers,
                 )
-            else:
-                self.train_loader = MLMLoader(
-                    TEXT_DATASETS[c.dataset](
-                        vocab_size=self.vocab_size, seq_len=self.seq_len,
-                        batch_size=c.batch_size, seed=c.seed,
-                        mask_prob=c.mask_prob, branching=c.corpus_branching,
-                    ),
-                    sharding=sharding,
+                test_ds = load_dataset(c.dataset, train=False,
+                                       data_dir=c.data_dir,
+                                       synthetic_size=c.synthetic_size)
+                test_bs = min(
+                    c.test_batch_size,
+                    (len(test_ds) // self.n_workers) * self.n_workers,
                 )
-            test_bs = max(
-                self.n_workers,
-                c.test_batch_size - c.test_batch_size % self.n_workers,
-            )
-            self.test_loader = MLMLoader(
-                TEXT_DATASETS[c.dataset](
-                    vocab_size=self.vocab_size, seq_len=self.seq_len,
-                    batch_size=test_bs, seed=c.seed + 10_000,
-                    mask_prob=c.mask_prob, branching=c.corpus_branching,
-                    corpus_seed=c.seed,  # same language as training
-                ),
-                sharding=sharding,
-                eval_batches=c.eval_batches,
-            )
-        elif stream_meta is not None:
-            # Streaming image input: the training set never materializes
-            # in host RAM (per-host shard files + bounded prefetch); only
-            # the (small) test split stays in-memory for the eval pass.
-            from pytorch_distributed_nn_tpu.data.streaming import (
-                StreamingLoader,
-            )
-
-            num_classes_meta = int(stream_meta.get("num_classes", 0))
-            if num_classes_meta and num_classes_meta != num_classes:
-                raise ValueError(
-                    f"{c.data_path} was exported from a "
-                    f"{num_classes_meta}-class dataset "
-                    f"({stream_meta.get('name')!r}) but --dataset "
-                    f"{c.dataset!r} has {num_classes} classes"
-                )
-            self.train_loader = StreamingLoader(
-                c.data_path, c.batch_size, seed=c.seed, sharding=sharding,
-                prefetch=c.stream_prefetch, workers=c.loader_workers,
-            )
-            test_ds = load_dataset(c.dataset, train=False,
-                                   data_dir=c.data_dir,
-                                   synthetic_size=c.synthetic_size)
-            test_bs = min(
-                c.test_batch_size,
-                (len(test_ds) // self.n_workers) * self.n_workers,
-            )
-            test_bs = max(self.n_workers, test_bs - test_bs % self.n_workers)
-            self.test_loader = DataLoader(
-                test_ds, test_bs, shuffle=False, sharding=sharding,
-            )
-        else:
-            if c.data_layout not in ("auto", "device", "host"):
-                raise ValueError(f"unknown data_layout {c.data_layout!r}")
-            train_ds = load_dataset(c.dataset, train=True, data_dir=c.data_dir,
-                                    synthetic_size=c.synthetic_size)
-            test_ds = load_dataset(c.dataset, train=False, data_dir=c.data_dir,
-                                   synthetic_size=c.synthetic_size)
-            # auto: device-resident when the uint8 datasets fit a modest
-            # HBM budget (every reference dataset does — CIFAR 184 MB
-            # total); past that, the host prefetch loader.
-            data_bytes = train_ds.raw_images.nbytes + test_ds.raw_images.nbytes
-            use_device = c.data_layout == "device" or (
-                c.data_layout == "auto" and data_bytes < 2 << 30
-            )
-            test_bs = min(
-                c.test_batch_size,
-                (len(test_ds) // self.n_workers) * self.n_workers,
-            )
-            test_bs = max(self.n_workers, test_bs - test_bs % self.n_workers)
-            if use_device:
-                if c.loader_workers > 0:
-                    logger.warning(
-                        "--loader-workers %d ignored: data_layout resolved "
-                        "to 'device' (batches are built on-chip; there is "
-                        "no host loader to parallelize). Pass "
-                        "--data-layout host to use the worker threads.",
-                        c.loader_workers,
-                    )
-                from pytorch_distributed_nn_tpu.data.loader import (
-                    DeviceDataLoader,
-                )
-
-                self.train_loader = DeviceDataLoader(
-                    train_ds, c.batch_size, self.mesh, shuffle=True,
-                    seed=c.seed,
-                )
-                self.test_loader = DeviceDataLoader(
-                    test_ds, test_bs, self.mesh, shuffle=False,
-                )
-                # Fuse batch construction INTO the jitted train step: one
-                # program (and one dispatch) per step does gather + augment
-                # + normalize + fwd/bwd + sync + update. Rebuild the step
-                # WITHOUT donation (state donation moves to the fused
-                # wrapper) and keep exactly one step function around.
-                self.train_step = inner = build_train_step(
-                    self.model, self.optimizer, self.grad_sync, self.mesh,
-                    bn_stats_sync=c.bn_stats_sync, donate=False,
-                    grad_accum=c.grad_accum,
-                    nonfinite_guard=c.skip_nonfinite,
-                )
-                prep = self.train_loader.prep_fn
-
-                self._fused_step = jax.jit(
-                    lambda state, images, labels, idx, key, rng: inner(
-                        state, prep(images, labels, idx, key), rng
-                    ),
-                    donate_argnums=(0,),
-                )
-            else:
-                self.train_loader = DataLoader(
-                    train_ds, c.batch_size, shuffle=True, seed=c.seed,
-                    sharding=sharding, workers=c.loader_workers,
-                )
+                test_bs = max(self.n_workers, test_bs - test_bs % self.n_workers)
                 self.test_loader = DataLoader(
                     test_ds, test_bs, shuffle=False, sharding=sharding,
                 )
+            else:
+                if c.data_layout not in ("auto", "device", "host"):
+                    raise ValueError(f"unknown data_layout {c.data_layout!r}")
+                train_ds = load_dataset(c.dataset, train=True, data_dir=c.data_dir,
+                                        synthetic_size=c.synthetic_size)
+                test_ds = load_dataset(c.dataset, train=False, data_dir=c.data_dir,
+                                       synthetic_size=c.synthetic_size)
+                # auto: device-resident when the uint8 datasets fit a modest
+                # HBM budget (every reference dataset does — CIFAR 184 MB
+                # total); past that, the host prefetch loader.
+                data_bytes = train_ds.raw_images.nbytes + test_ds.raw_images.nbytes
+                use_device = c.data_layout == "device" or (
+                    c.data_layout == "auto" and data_bytes < 2 << 30
+                )
+                test_bs = min(
+                    c.test_batch_size,
+                    (len(test_ds) // self.n_workers) * self.n_workers,
+                )
+                test_bs = max(self.n_workers, test_bs - test_bs % self.n_workers)
+                if use_device:
+                    if c.loader_workers > 0:
+                        logger.warning(
+                            "--loader-workers %d ignored: data_layout resolved "
+                            "to 'device' (batches are built on-chip; there is "
+                            "no host loader to parallelize). Pass "
+                            "--data-layout host to use the worker threads.",
+                            c.loader_workers,
+                        )
+                    from pytorch_distributed_nn_tpu.data.loader import (
+                        DeviceDataLoader,
+                    )
+
+                    self.train_loader = DeviceDataLoader(
+                        train_ds, c.batch_size, self.mesh, shuffle=True,
+                        seed=c.seed,
+                    )
+                    self.test_loader = DeviceDataLoader(
+                        test_ds, test_bs, self.mesh, shuffle=False,
+                    )
+                    # Fuse batch construction INTO the jitted train step: one
+                    # program (and one dispatch) per step does gather + augment
+                    # + normalize + fwd/bwd + sync + update. Rebuild the step
+                    # WITHOUT donation (state donation moves to the fused
+                    # wrapper) and keep exactly one step function around.
+                    self.train_step = inner = build_train_step(
+                        self.model, self.optimizer, self.grad_sync, self.mesh,
+                        bn_stats_sync=c.bn_stats_sync, donate=False,
+                        grad_accum=c.grad_accum,
+                        nonfinite_guard=c.skip_nonfinite,
+                    )
+                    prep = self.train_loader.prep_fn
+
+                    self._fused_step = jax.jit(
+                        lambda state, images, labels, idx, key, rng: inner(
+                            state, prep(images, labels, idx, key), rng
+                        ),
+                        donate_argnums=(0,),
+                    )
+                else:
+                    self.train_loader = DataLoader(
+                        train_ds, c.batch_size, shuffle=True, seed=c.seed,
+                        sharding=sharding, workers=c.loader_workers,
+                    )
+                    self.test_loader = DataLoader(
+                        test_ds, test_bs, shuffle=False, sharding=sharding,
+                    )
         if (
             self.fault_plan is not None
             and self._fused_step is not None
@@ -852,7 +866,8 @@ class Trainer:
         step_cost = None
         if telemetry_path is not None:
             try:
-                step_cost = self._static_step_cost(sync_bytes)
+                with self._setup.span("setup/step_cost"):
+                    step_cost = self._static_step_cost(sync_bytes)
             except Exception:
                 # On an accelerator a run without efficiency telemetry is
                 # a run nobody can price: fail it. On the CPU (tests,
@@ -878,7 +893,10 @@ class Trainer:
             start_step=self.start_step,
             step_cost=step_cost,
         )
-        self.telemetry = obs.Telemetry.for_run(telemetry_path, manifest)
+        self.telemetry = obs.Telemetry.for_run(
+            telemetry_path, manifest, registry=self._setup.registry
+        )
+        self._setup.telemetry = self.telemetry
         reg = self.telemetry.registry
         reg.gauge("num_workers", help="data-parallel degree").set(
             self.n_workers
@@ -891,6 +909,7 @@ class Trainer:
         # process default for the run: retry/checkpoint/fault/eval emitters
         # land their events in THIS run's stream
         self._prev_telemetry = obs.install(self.telemetry)
+        self._compiles = compiles.route(self.telemetry)
 
         if self._elastic_plan is not None:
             # typed record of the geometry transition — first event of the
@@ -1131,7 +1150,6 @@ class Trainer:
             else steps_per_epoch * c.epochs
         )
         history = []
-        timer = PhaseTimer(registry=self.telemetry.registry)
         pending = []  # records whose metric values are still device futures
         window_t0 = time.perf_counter()
         window_data = 0.0
@@ -1302,12 +1320,21 @@ class Trainer:
 
         ok = False  # set only when the loop body completes
         step = self.start_step - 1  # last completed step when the loop is empty
+        # the call's first iteration is set-up too: its trace, lower and
+        # compile or cache fetch, and the snapshot warm-up
+        first_step = self._setup.span(
+            "setup/first_step", parent="train/step", step=self.start_step + 1
+        )
+        not_first = contextlib.nullcontext()
         wall_t0 = time.perf_counter()  # the first window's wall_ms starts here
         dispatched_at = time.monotonic()  # the first step's dispatch_gap_ms
         try:
           with (sup if sup is not None else contextlib.nullcontext()):
             for step in range(self.start_step, total_steps):
-                with span("train/step"):
+                self._compiles.step = step + 1
+                with span("train/step"), (
+                    first_step if step == self.start_step else not_first
+                ):
                     if plan is not None:
                         # 1-indexed fault steps; delay entries become real
                         # host sleeps only when no straggler simulator is
@@ -1326,20 +1353,19 @@ class Trainer:
                             "Profiling steps %d..%d to %s",
                             step + 1, profile_stop, pdir,
                         )
-                    timer.reset()
                     if self._fused_step is not None:
-                        with timer.phase("train/data"):
+                        with span("train/data") as data:
                             idx, key = self.train_loader.next_indices()
-                        window_data += timer.durations["train/data"]
+                        window_data += data.seconds
                         with span("train/dispatch"):
                             self.state, m = self._fused_step(
                                 self.state, self.train_loader.images,
                                 self.train_loader.labels, idx, key, rng,
                             )
                     else:
-                        with timer.phase("train/data"):
+                        with span("train/data") as data:
                             batch = self.train_loader.next_batch()
-                        window_data += timer.durations["train/data"]
+                        window_data += data.seconds
                         if plan is not None:
                             batch = plan.poison_batch(step + 1, batch)
                         with span("train/dispatch"):
@@ -1359,7 +1385,7 @@ class Trainer:
                     # input/produce span, without the dispatch to the
                     # device — near zero when prefetch kept up); loaders
                     # without the attribute bill the whole data phase.
-                    data_time = timer.durations.get("train/data", 0.0)
+                    data_time = data.seconds
                     wait_ms = getattr(self.train_loader, "last_wait_ms", None)
                     if wait_ms is None:
                         wait_ms = data_time * 1000.0
@@ -1431,6 +1457,7 @@ class Trainer:
             # its chance. `ok` (not sys.exc_info(), which also reports a
             # CALLER's in-flight exception) distinguishes the paths.
             cleanup_error = None
+            self._compiles.step = None
             # Flight recorder first: an in-flight capture stops its trace
             # and writes its report NOW (a crashed run is exactly when the
             # bundle matters), before the user-profile stop_trace below
@@ -1695,4 +1722,5 @@ class Trainer:
         self.test_loader.close()
         self.metrics.close()
         self.telemetry.close()
+        compiles.unroute(self.telemetry)
         obs.uninstall(self.telemetry, self._prev_telemetry)

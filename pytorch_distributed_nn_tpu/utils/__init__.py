@@ -1,5 +1,5 @@
-"""Utilities: timing/metrics instrumentation."""
+"""Utilities: metrics instrumentation."""
 
-from pytorch_distributed_nn_tpu.utils.timing import MetricsLogger, PhaseTimer
+from pytorch_distributed_nn_tpu.utils.timing import MetricsLogger
 
-__all__ = ["MetricsLogger", "PhaseTimer"]
+__all__ = ["MetricsLogger"]

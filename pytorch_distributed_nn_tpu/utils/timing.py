@@ -1,53 +1,19 @@
-"""Per-phase timing + step metrics — now a thin shim over observability/.
+"""Step metrics — a thin shim over observability/.
 
-Kept for API compatibility: ``PhaseTimer`` and ``MetricsLogger`` are the
-surface the trainer (and downstream scripts) always used, but since the
-unified telemetry layer landed they are veneers over
-``observability.core``:
-
-- :class:`PhaseTimer` still accumulates named wall-clock phases per
-  iteration (reference: src/distributed_worker.py:146-173 — fetch-weights /
-  forward / backward / comm). Each phase is a span
-  (``observability/spans.py``): an event in a profiler trace while one is
-  being collected, and an observation of the
-  ``phase_seconds{phase=...}`` histogram, so phases show up in the
-  Prometheus exposition without a second timing source.
-- :class:`MetricsLogger` still appends one JSONL record per step, but the
-  stream is now a telemetry stream: a run-manifest header record first,
-  ``kind``-tagged records after (observability/core.TelemetrySink). Passing
-  an existing :class:`~..observability.core.Telemetry` routes records into
-  that run's stream instead of opening a second file.
+Kept for API compatibility: ``MetricsLogger`` is the surface the trainer
+(and downstream scripts) always used, but since the unified telemetry
+layer landed it is a veneer over ``observability.core``: it still
+appends one JSONL record per step, but the stream is a telemetry stream:
+a run-manifest header record first, ``kind``-tagged records after
+(observability/core.TelemetrySink). Passing an existing
+:class:`~..observability.core.Telemetry` routes records into that run's
+stream instead of opening a second file. Timing is
+``observability/spans.py``'s ``span()``.
 """
 
 from __future__ import annotations
 
-from contextlib import contextmanager
-from typing import Dict, Optional
-
-from pytorch_distributed_nn_tpu.observability.spans import Span
-
-
-class PhaseTimer:
-    """Accumulates named wall-clock phases for one iteration.
-
-    ``registry`` receives the ``phase_seconds`` observations; without one
-    they go to the installed telemetry's."""
-
-    def __init__(self, registry=None):
-        self.durations: Dict[str, float] = {}
-        self._registry = registry
-
-    @contextmanager
-    def phase(self, name: str):
-        s = Span(name, self._registry)
-        try:
-            with s:
-                yield
-        finally:
-            self.durations[name] = self.durations.get(name, 0.0) + s.seconds
-
-    def reset(self):
-        self.durations = {}
+from typing import Optional
 
 
 class MetricsLogger:

@@ -1,7 +1,8 @@
 """The training spans (observability/spans.py): the primitive, the
 catalogue's sites in the step loop, the loaders and the checkpoint writer
-as a profiler trace shows them, and the clocks that were repaired with it
-(``wall_ms``, the efficiency gauges, ``last_wait_ms``)."""
+as a profiler trace shows them, the clocks that were repaired with it
+(``wall_ms``, the efficiency gauges, ``last_wait_ms``), and set-up's spans
+with the compile listener that charges them (observability/compiles.py)."""
 
 import glob
 import threading
@@ -53,7 +54,12 @@ def test_span_nests_and_observes_from_two_threads(telemetry):
 def test_a_name_outside_the_catalogue_raises():
     with pytest.raises(ValueError, match="catalogue"):
         spans.span("train/setp")
-    assert len(spans.NAMES) == len(spans.CATALOGUE) == 16
+    assert len(spans.NAMES) == len(spans.CATALOGUE) == 22
+    assert len(spans.SETUP_NAMES) == 6
+    with pytest.raises(ValueError, match="SetupLog"):
+        spans.span("setup/init")            # a run's SetupLog opens those
+    with pytest.raises(ValueError, match="setup/"):
+        spans.SetupLog(core.MetricRegistry()).span("train/step")
 
 
 def test_span_costs_microseconds_when_no_trace_runs(telemetry):
@@ -225,10 +231,13 @@ def test_trainer_trace_holds_the_catalogue_and_records_carry_wall_ms(
     loop = max(closed, key=lambda i: sum(e[0] == "train/step" for e in closed[i]))
     writer = next(i for i in closed if "ckpt/write" in by_thread[i])
     assert writer != loop
+    # set-up precedes the traced window: every other name is in it
     for name, thread, _ in spans.CATALOGUE:
+        if name in spans.SETUP_NAMES:
+            continue
         where = loop if thread == "loop" else writer
         assert name in by_thread[where], (name, thread)
-    assert by_thread[loop] | by_thread[writer] == spans.NAMES
+    assert by_thread[loop] | by_thread[writer] == spans.NAMES - spans.SETUP_NAMES
 
     # children inside parents: nesting is lexical on each thread (the
     # iteration that starts the trace opened its train/step before it)
@@ -250,3 +259,190 @@ def test_trainer_trace_holds_the_catalogue_and_records_carry_wall_ms(
     for i, name in ((loop, "train/step"), (writer, "ckpt/write")):
         begun = sum(e[0] == name + spans.BEGIN for e in threads[i])
         assert begun == sum(e[0] == name for e in closed[i]) + 1, name
+
+
+# -- set-up: setup/* spans and the compile listener ---------------------------
+
+def _lenet(tmp_path, name="stream.jsonl", **kw):
+    return Trainer(TrainConfig(**{
+        "network": "LeNet", "dataset": "MNIST", "batch_size": 16,
+        "test_batch_size": 16, "lr": 0.01, "max_steps": 4, "num_workers": 2,
+        "synthetic_size": 64, "log_every": 2, "data_layout": "device",
+        "train_dir": str(tmp_path), "metrics_path": str(tmp_path / name),
+        **kw}))
+
+
+def _events(path, etype):
+    return [e for e in read_stream(str(path)).events if e["type"] == etype]
+
+
+def _compiled(events):
+    return sum(s["programs"]["compiled"] for e in events for s in e["spans"])
+
+
+def _programs(registry, source="compiled"):
+    counter = registry.get("programs_total", labels={"source": source})
+    return counter.value if counter else 0
+
+
+def test_setup_spans_nest_inside_init_and_go_out_as_events(tmp_path, telemetry):
+    trainer = _lenet(tmp_path)
+    try:
+        [init_event] = _events(tmp_path / "stream.jsonl", "setup")
+        trainer.train()
+        trainer.start_step, trainer.config.max_steps = 4, 6
+        trainer.train()
+    finally:
+        trainer.close()
+    events = _events(tmp_path / "stream.jsonl", "setup")
+    assert len(events) == 3 and events[0] == init_event
+    by_name = {s["name"]: s for s in init_event["spans"]}
+    assert set(by_name) == {"setup/init", "setup/model", "setup/step_build",
+                            "setup/data", "setup/step_cost"}
+    init = by_name.pop("setup/init")
+    assert init["parent"] is None and init_event.get("step") is None
+    for s in by_name.values():
+        assert s["parent"] == "setup/init"
+        assert init["mono0"] <= s["mono0"] <= s["mono1"] <= init["mono1"]
+    children = sorted(by_name.values(), key=lambda s: s["mono0"])
+    assert all(a["mono1"] <= b["mono0"] for a, b in zip(children, children[1:]))
+    assert sum(s["seconds"] for s in children) <= init["seconds"]
+    # one more event per train() call, when its first iteration closes
+    assert [e["step"] for e in events[1:]] == [1, 5]
+    for e in events[1:]:
+        [first] = e["spans"]
+        assert first["name"] == "setup/first_step"
+        assert first["parent"] == "train/step"
+        assert first["mono0"] >= init["mono1"]
+    # the spans fed the run's registry, not the default installed before it
+    assert _phase(telemetry, "setup/model") is None
+    assert trainer.telemetry.registry.get(
+        "phase_seconds", labels={"phase": "setup/model"}).count == 1
+    assert trainer.telemetry.registry.get(
+        "phase_seconds", labels={"phase": "setup/first_step"}).count == 2
+
+
+def test_a_cold_first_step_compiles_and_a_second_call_compiles_nothing(tmp_path):
+    trainer = _lenet(tmp_path)
+    try:
+        trainer.train()
+        trainer.start_step, trainer.config.max_steps = 4, 6
+        trainer.train()
+        registry = trainer.telemetry.registry
+    finally:
+        trainer.close()
+    stream = tmp_path / "stream.jsonl"
+    _, call1, call2 = _events(stream, "setup")
+    [first] = call1["spans"]
+    assert first["programs"]["compiled"] >= 1
+    assert first["compile_s"]["backend"] > 0
+    assert any(f["span"] == "setup/first_step" and f["source"] == "compiled"
+               for f in call1["slowest"])
+    [again] = call2["spans"]
+    assert again["programs"] == {"compiled": 0, "cached": 0}
+    assert sum(again["compile_s"].values()) == 0
+    # the steady loop made no program: no compile event in the stream
+    assert _events(stream, "compile") == []
+    # the registry holds what the stream does
+    events = _events(stream, "setup")
+    assert _programs(registry) == _compiled(events)
+    backend = sum(s["compile_s"]["backend"] for e in events for s in e["spans"])
+    assert registry.get("compile_seconds", labels={"stage": "backend"}
+                        ).sum == pytest.approx(backend)
+
+
+def _plant(trainer, at_call: int):
+    """Make the loader's ``at_call``-th draw run a jitted function no one
+    has called yet: one program compiled inside the step loop."""
+    import numpy as np
+
+    def planted(x):
+        return x * 3 + 1
+
+    real = trainer.train_loader.next_indices
+    calls = []
+
+    def next_indices():
+        calls.append(1)
+        if len(calls) == at_call:
+            jax.block_until_ready(jax.jit(planted)(np.ones(7, np.float32)))
+        return real()
+
+    trainer.train_loader.next_indices = next_indices
+
+
+def test_a_recompile_in_the_loop_is_one_compile_event_at_its_step(tmp_path):
+    trainer = _lenet(tmp_path)
+    try:
+        assert trainer._fused_step is not None
+        _plant(trainer, at_call=3)       # the draw of the third step
+        trainer.train()
+    finally:
+        trainer.close()
+    [event] = _events(tmp_path / "stream.jsonl", "compile")
+    assert event["step"] == 3
+    assert event["fun_name"] == "planted"
+    assert event["source"] == "compiled"
+    assert set(event["compile_s"]) == {"trace", "lower", "backend"}
+    assert event["compile_s"]["backend"] > 0
+
+
+def test_two_trainers_in_one_process_count_each_program_once(tmp_path):
+    a = _lenet(tmp_path / "a")
+    try:
+        before = _programs(a.telemetry.registry)
+        b = _lenet(tmp_path / "b")
+        try:
+            _plant(b, at_call=2)
+            b.train()
+        finally:
+            b.close()
+        # b's set-up and b's loop went to b alone
+        assert _programs(a.telemetry.registry) == before
+        assert len(_events(tmp_path / "b" / "stream.jsonl", "compile")) == 1
+        a.train()
+        registry = a.telemetry.registry
+    finally:
+        a.close()
+    events = _events(tmp_path / "a" / "stream.jsonl", "setup")
+    assert _events(tmp_path / "a" / "stream.jsonl", "compile") == []
+    assert _programs(registry) == _compiled(events)
+    assert len(events) == 2
+
+
+def test_a_nested_trace_is_counted_once_and_a_fetch_is_cached():
+    from pytorch_distributed_nn_tpu.observability import compiles
+
+    import numpy as np
+
+    compiles.install()
+    compiles.install()                   # once a process, however asked
+
+    def inner(x):
+        time.sleep(0.05)                 # while being traced
+        return x * 2
+
+    def outer(x):
+        return jax.jit(inner)(x) + 1
+
+    log = spans.SetupLog(core.MetricRegistry())
+    with log.span("setup/model") as s:
+        jax.jit(outer)(np.ones(3, np.float32))
+    tally = s.compiles
+    assert tally.programs == {"compiled": 1, "cached": 0}
+    assert tally.funs["outer"][1] == "compiled"
+    # inner's trace ran inside outer's: its 50 ms are counted once
+    assert 0.05 <= tally.seconds["trace"] < 0.09
+    assert sum(tally.seconds.values()) <= s.seconds
+
+    # a backend stage with a cache hit inside is a fetch
+    backend = next(k for k, v in compiles.STAGE.items() if v == "backend")
+    with log.span("setup/data") as s:
+        compiles._opened(backend, 0.0)
+        compiles._event(compiles.HIT)
+        compiles._duration(compiles.FETCH, 0.25)
+        compiles._closed(backend, 10.0, 10.5, fun_name="jit(step)")
+    assert s.compiles.programs == {"compiled": 0, "cached": 1}
+    assert s.compiles.fetch_s == 0.25
+    assert s.compiles.funs == {"step": [0.5, "cached"]}
+    assert _programs(log.registry, "cached") == 1
