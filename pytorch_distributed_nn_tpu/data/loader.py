@@ -408,6 +408,19 @@ class DeviceDataLoader(_IndexedLoader):
         with span("input/put"):
             return self._idx_key(idx)
 
+    def indices_spec(self):
+        """What ``next_indices`` returns, as abstract values: the index
+        batch on the data axis and the key, uncommitted as ``fold_in``
+        leaves it."""
+        import jax
+
+        return (
+            jax.ShapeDtypeStruct(
+                (self.batch_size,), np.int32, sharding=self._idx_sharding
+            ),
+            jax.ShapeDtypeStruct(self._key.shape, self._key.dtype),
+        )
+
     def _batch_for(self, idx: np.ndarray) -> Batch:
         import jax
 

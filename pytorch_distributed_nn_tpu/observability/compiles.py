@@ -18,10 +18,11 @@ backend stage without a hit compiled, cache or no cache).
 
 Each stage is charged its exclusive seconds (a nested trace is not
 counted twice) to the innermost open ``setup/*`` span on its thread
-(``spans.SetupLog``), which writes them out in its ``setup`` event.
-Outside every ``setup/*`` span a program's stages go out, when its backend
-stage closes, as one ``compile`` event of the routed run, under the step
-its loop is on (``None`` before the loop): the answer to "which step
+(``spans.SetupLog``), which writes them out in its ``setup`` event; each
+``lower`` stage also counts one program ``lowered``. Outside every
+``setup/*`` span a program's stages go out, when its backend stage closes,
+as one ``compile`` event of the routed run, under the step its loop is on
+(``None`` before the loop), with its lowerings: the answer to "which step
 recompiled". Either way they feed ``compile_seconds{stage=}`` and
 ``programs_total{source=compiled|cached}`` of the run's registry. jax
 stamps its spans with ``time.time()``; a ``compile`` event is stamped on
@@ -149,7 +150,8 @@ def _closed(event: str, start: float, end: float, fun_name: str = "?",
             tally, t.pending = t.pending, CompileTally()
             telemetry.emit(
                 "compile", step=r.step, fun_name=name, source=source,
-                compile_s=tally.seconds, fetch_s=tally.fetch_s)
+                compile_s=tally.seconds, fetch_s=tally.fetch_s,
+                lowered=tally.programs["lowered"])
     registry.histogram(
         "compile_seconds", help="seconds a stage of making a program took",
         labels={"stage": stage}).observe(seconds)

@@ -585,16 +585,20 @@ def _selftest() -> int:
         # program made in the loop; a stream without them has no line
         from pytorch_distributed_nn_tpu.observability.core import Telemetry
 
-        def span(name, parent, seconds, compiled, cached):
+        def span(name, parent, seconds, compiled, cached, lowered=0):
             return {"name": name, "parent": parent, "mono0": 0.0,
                     "mono1": seconds, "seconds": seconds, "fetch_s": 0.0,
                     "compile_s": {"trace": 0.0, "lower": 0.0,
                                   "backend": seconds / 2},
-                    "programs": {"compiled": compiled, "cached": cached}}
+                    "programs": {"compiled": compiled, "cached": cached,
+                                 "lowered": lowered}}
 
         setup_run = os.path.join(d, "setup")
         t = Telemetry.for_run(os.path.join(setup_run, "telemetry.jsonl"))
-        t.emit("setup", spans=[span("setup/model", "setup/init", 4.0, 0, 120),
+        t.emit("setup", spans=[span("setup/model", "setup/init", 4.0, 0, 120,
+                                    120),
+                               span("setup/step_cost", "setup/init", 3.0, 0,
+                                    0, 1),
                                span("setup/init", None, 5.0, 0, 0)],
                slowest=[{"fun_name": "init", "seconds": 2.0,
                          "source": "cached", "span": "setup/model"}])
@@ -611,7 +615,8 @@ def _selftest() -> int:
         text = reader.render_summary(reader.summarize_run(rs_setup))
         check("setup line: seconds per span, programs, slowest, recompiles",
               su.get("compiled") == 1 and su.get("cached") == 120
-              and "first_step@1 8.00s" in text
+              and "step_cost 3.00s (1 lowered)" in text
+              and "first_step@1 8.00s (0 lowered)" in text
               and "1 programs compiled, 120 fetched" in text
               and "slowest train_step 4.00s (compiled)" in text
               and "planted (compiled) at step 7" in text,

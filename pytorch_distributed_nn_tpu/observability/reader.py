@@ -651,8 +651,9 @@ def experts_summary(rs: RunStream) -> Optional[dict]:
 
 
 def setup_summary(rs: RunStream) -> Optional[dict]:
-    """The set-up section of ``obs summary``: seconds and programs per
-    ``setup/*`` span (the ``setup`` events, observability/spans.py), the
+    """The set-up section of ``obs summary``: seconds, programs and
+    lowerings per ``setup/*`` span (the ``setup`` events,
+    observability/spans.py), the
     slowest programs, and the programs made outside every span
     (``compile`` events, observability/compiles.py). ``None`` for streams
     without them — the absent-family contract."""
@@ -670,6 +671,7 @@ def setup_summary(rs: RunStream) -> Optional[dict]:
                 "compile_s": sum((s.get("compile_s") or {}).values()),
                 "compiled": programs.get("compiled", 0),
                 "cached": programs.get("cached", 0),
+                "lowered": programs.get("lowered"),  # None: an older stream
             })
     slowest = sorted((f for e in events for f in e.get("slowest", [])),
                      key=lambda f: f["seconds"], reverse=True)
@@ -1123,7 +1125,10 @@ def render_summary(summary: dict, manifest: Optional[dict] = None) -> str:
             name = sp["name"].split("/", 1)[1]
             if sp["name"] == "setup/first_step":
                 name += f"@{sp['step']}"
-            parts.append(f"{name} {sp['seconds']:.2f}s")
+            part = f"{name} {sp['seconds']:.2f}s"
+            if sp["lowered"] is not None:
+                part += f" ({sp['lowered']} lowered)"
+            parts.append(part)
         line = (f"setup: {', '.join(parts)} · {setup['compiled']} programs "
                 f"compiled, {setup['cached']} fetched, "
                 f"{setup['compile_s']:.2f}s making them")

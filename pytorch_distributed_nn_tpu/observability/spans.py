@@ -76,10 +76,11 @@ CATALOGUE = (
      "construction, the state's device_put onto the mesh"),
     ("setup/data", "loop", "the train and test loaders, synthetic-set "
      "generation included"),
-    ("setup/step_cost", "loop", "_static_step_cost's lowering of the step"),
+    ("setup/step_cost", "loop", "the step program's one trace and lowering, "
+     "and _static_step_cost's reading of it"),
     ("setup/first_step", "loop", "the first iteration of a train() call: "
-     "data, dispatch (trace, lower, compile or cache fetch), snapshot "
-     "warm-up"),
+     "data, dispatch (the compile or cache fetch of the lowered step; its "
+     "trace and lowering too on a run without a stream), snapshot warm-up"),
 )
 NAMES = frozenset(name for name, _, _ in CATALOGUE)
 SETUP_NAMES = frozenset(n for n in NAMES if n.startswith("setup/"))
@@ -154,15 +155,16 @@ def open_setup_spans() -> List["SetupSpan"]:
 class CompileTally:
     """Compile work charged to one span, or to one program outside every
     span: exclusive seconds by stage, the persistent cache's fetch
-    seconds (inside ``backend``), programs by source, and seconds and
-    source by ``fun_name``."""
+    seconds (inside ``backend``), programs by source and the programs
+    ``lowered`` (one a ``lower`` stage), and seconds and source by
+    ``fun_name``."""
 
     __slots__ = ("seconds", "fetch_s", "programs", "funs")
 
     def __init__(self):
         self.seconds: Dict[str, float] = dict.fromkeys(STAGES, 0.0)
         self.fetch_s = 0.0
-        self.programs: Dict[str, int] = dict.fromkeys(SOURCES, 0)
+        self.programs: Dict[str, int] = dict.fromkeys((*SOURCES, "lowered"), 0)
         self.funs: Dict[str, list] = {}  # fun_name -> [seconds, source]
 
     def add(self, stage: str, seconds: float, fun_name: str,
@@ -170,6 +172,8 @@ class CompileTally:
         self.seconds[stage] += seconds
         fun = self.funs.setdefault(fun_name, [0.0, None])
         fun[0] += seconds
+        if stage == "lower":
+            self.programs["lowered"] += 1
         if source is not None:         # a backend stage: one program
             self.programs[source] += 1
             fun[1] = source

@@ -66,6 +66,45 @@ logger = logging.getLogger(__name__)
 INPUT_WAIT_EVENT_MS = 100.0
 
 
+def _abstract(a) -> jax.ShapeDtypeStruct:
+    """``a`` as jit sees it at a call: shape, dtype, weak type, and the
+    sharding of a committed array (an uncommitted one, or a host array,
+    leaves its placement to the program)."""
+    aval = jax.typeof(a)
+    return jax.ShapeDtypeStruct(
+        aval.shape, aval.dtype, weak_type=aval.weak_type,
+        sharding=a.sharding if getattr(a, "committed", False) else None,
+    )
+
+
+class _StepProgram:
+    """The program the step loop dispatches, traced and lowered once a
+    Trainer and compiled (or fetched from the persistent cache) from that
+    same ``Lowered`` at the first dispatch.
+
+    ``args()`` gives the abstract arguments, each taken from the array it
+    stands for, so the module is the one jit would make at that call.
+    From the first dispatch on the loop calls the ``Compiled``, which
+    refuses arguments whose avals or shardings differ: an argument the
+    lowering did not foresee fails loudly instead of recompiling.
+    """
+
+    def __init__(self, fn, args):
+        self._fn, self._args = fn, args
+        self._lowered = self._compiled = None
+
+    def lowered(self):
+        if self._lowered is None:
+            self._lowered = self._fn.lower(*self._args())
+        return self._lowered
+
+    def __call__(self, *args):
+        if self._compiled is None:
+            self._compiled = self.lowered().compile()
+            self._lowered = None  # the executable is all that runs now
+        return self._compiled(*args)
+
+
 # TrainConfig lives in training/config.py (jax-free — the sweep/fleet
 # orchestrators import it without backend startup); re-exported here so
 # `from ...training.trainer import TrainConfig` keeps working everywhere.
@@ -676,6 +715,7 @@ class Trainer:
                             lambda _: sharding, self.state.ef_state
                         ))
                     self.state = jax.device_put(self.state, placed)
+        self._batch_sharding = sharding  # what the train loader places
         with self._setup.span("setup/data"):
             stream_meta = None
             if c.data_path:
@@ -837,6 +877,14 @@ class Trainer:
                 "never pass through the host); run with "
                 "data_layout='host' to use nan_grad injection"
             )
+        # The program the loop dispatches: the fused step where the batch
+        # is built on the device, the train step otherwise. Lowered once,
+        # for the manifest's step cost below or else at the first dispatch,
+        # and compiled from that same Lowered.
+        self._step = _StepProgram(
+            self.train_step if self._fused_step is None else self._fused_step,
+            self._step_args,
+        )
         # --- unified telemetry (observability/, docs/observability.md) ---
         # One self-describing JSONL stream per run: explicit --metrics-path
         # wins; otherwise any run that already owns a train_dir (supervised
@@ -861,13 +909,16 @@ class Trainer:
         # stamp the step's FLOPs/bytes + backend peaks into the manifest so
         # every consumer — the live MFU gauges (core._derive_efficiency),
         # `obs summary`'s efficiency section, incident reports — derives
-        # utilization from ONE recorded cost. Sink-less runs (unit tests,
-        # sweeps) skip it: the lowering costs a step trace.
+        # utilization from ONE recorded cost, read from the Lowered that the
+        # first dispatch compiles. Sink-less runs (unit tests, sweeps) skip
+        # it: their step is lowered at its first dispatch instead.
         step_cost = None
         if telemetry_path is not None:
             try:
                 with self._setup.span("setup/step_cost"):
-                    step_cost = self._static_step_cost(sync_bytes)
+                    step_cost = self._static_step_cost(
+                        self._step.lowered(), sync_bytes
+                    )
             except Exception:
                 # On an accelerator a run without efficiency telemetry is
                 # a run nobody can price: fail it. On the CPU (tests,
@@ -1036,22 +1087,50 @@ class Trainer:
                 self.train_loader.skip(self.start_step)
         self.metrics = MetricsLogger(telemetry=self.telemetry)
 
-    def _static_step_cost(self, sync_bytes) -> Optional[dict]:
+    def _step_args(self) -> tuple:
+        """The loop's arguments to its step as abstract values, each taken
+        from the array it stands for (``_abstract``): the state; the batch
+        as the train loader places it, or, fused, the resident set and what
+        ``next_indices()`` returns; and the rng ``train()`` makes."""
+        import jax.numpy as jnp
+
+        c = self.config
+        state = jax.tree.map(_abstract, self.state)
+        rng = _abstract(jax.random.PRNGKey(0))
+        if self._fused_step is not None:
+            loader = self.train_loader
+            return (state, _abstract(loader.images), _abstract(loader.labels),
+                    *loader.indices_spec(), rng)
+        if self.is_text:
+            x = y = jax.ShapeDtypeStruct(
+                (c.batch_size, self.seq_len), jnp.int32,
+                sharding=self._batch_sharding,
+            )
+        else:
+            x = jax.ShapeDtypeStruct(
+                (c.batch_size, *input_spec(c.network)), jnp.float32,
+                sharding=self._batch_sharding,
+            )
+            y = jax.ShapeDtypeStruct(
+                (c.batch_size,), jnp.int32, sharding=self._batch_sharding
+            )
+        return state, (x, y), rng
+
+    def _static_step_cost(self, lowered, sync_bytes) -> Optional[dict]:
         """Static FLOPs/bytes of one training step, as the run manifest's
         ``step_cost`` record (docs/observability.md "Efficiency").
 
-        Uses ``lower()`` WITHOUT ``compile()`` — a step trace (~100s of
-        ms), never a second XLA compilation — so the numbers come from
-        unoptimized HLO: FLOP totals are corrected by XLA's own
-        ``cost_analysis`` (exact counting), the family split is coarse
-        (no fusions yet) and HBM bytes are a pre-fusion UPPER bound;
-        ``source: "lowered"`` records the flavor, and ``cli analyze
-        --cost`` is the optimized-HLO twin when exact bytes matter.
+        Read from ``lowered``, the step program's one lowering, which the
+        first dispatch then compiles: the numbers come from unoptimized
+        HLO, so FLOP totals are corrected by XLA's own ``cost_analysis``
+        (exact counting), the family split is coarse (no fusions yet) and
+        HBM bytes are a pre-fusion UPPER bound; ``source: "lowered"``
+        records the flavor, and ``cli analyze --cost`` is the
+        optimized-HLO twin when exact bytes matter. On the device data
+        layout the program is the fused one, batch construction included.
         All quantities are GLOBAL per step except ``ici_bytes``
         (per-device link traffic, the ring estimate).
         """
-        import jax.numpy as jnp
-
         from pytorch_distributed_nn_tpu.analysis import costmodel
         from pytorch_distributed_nn_tpu.analysis.calibration import (
             default_profile,
@@ -1059,25 +1138,6 @@ class Trainer:
             predict_step_ms,
         )
 
-        c = self.config
-
-        def struct(a):
-            return jax.ShapeDtypeStruct(a.shape, a.dtype)
-
-        state_s = jax.tree.map(struct, self.state)
-        rng_s = struct(jax.random.PRNGKey(0))
-        if self.is_text:
-            tok = jax.ShapeDtypeStruct(
-                (c.batch_size, self.seq_len), jnp.int32
-            )
-            args = (state_s, (tok, tok), rng_s)
-        else:
-            x = jax.ShapeDtypeStruct(
-                (c.batch_size, *input_spec(c.network)), jnp.float32
-            )
-            y = jax.ShapeDtypeStruct((c.batch_size,), jnp.int32)
-            args = (state_s, (x, y), rng_s)
-        lowered = self.train_step.lower(*args)
         xla_flops = None
         try:
             ca = lowered.cost_analysis()
@@ -1320,8 +1380,9 @@ class Trainer:
 
         ok = False  # set only when the loop body completes
         step = self.start_step - 1  # last completed step when the loop is empty
-        # the call's first iteration is set-up too: its trace, lower and
-        # compile or cache fetch, and the snapshot warm-up
+        # the call's first iteration is set-up too: the step's compile or
+        # cache fetch (and its lowering, on a run without a stream), and
+        # the snapshot warm-up
         first_step = self._setup.span(
             "setup/first_step", parent="train/step", step=self.start_step + 1
         )
@@ -1358,7 +1419,7 @@ class Trainer:
                             idx, key = self.train_loader.next_indices()
                         window_data += data.seconds
                         with span("train/dispatch"):
-                            self.state, m = self._fused_step(
+                            self.state, m = self._step(
                                 self.state, self.train_loader.images,
                                 self.train_loader.labels, idx, key, rng,
                             )
@@ -1369,9 +1430,7 @@ class Trainer:
                         if plan is not None:
                             batch = plan.poison_batch(step + 1, batch)
                         with span("train/dispatch"):
-                            self.state, m = self.train_step(
-                                self.state, batch, rng
-                            )
+                            self.state, m = self._step(self.state, batch, rng)
                     if step == self.start_step and self._async_ckpt is not None:
                         # Warm the snapshot clone on the POST-step state: its
                         # avals/shardings are what every save sees (the init

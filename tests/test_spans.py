@@ -339,7 +339,7 @@ def test_a_cold_first_step_compiles_and_a_second_call_compiles_nothing(tmp_path)
     assert any(f["span"] == "setup/first_step" and f["source"] == "compiled"
                for f in call1["slowest"])
     [again] = call2["spans"]
-    assert again["programs"] == {"compiled": 0, "cached": 0}
+    assert again["programs"] == {"compiled": 0, "cached": 0, "lowered": 0}
     assert sum(again["compile_s"].values()) == 0
     # the steady loop made no program: no compile event in the stream
     assert _events(stream, "compile") == []
@@ -384,6 +384,7 @@ def test_a_recompile_in_the_loop_is_one_compile_event_at_its_step(tmp_path):
     assert event["fun_name"] == "planted"
     assert event["source"] == "compiled"
     assert set(event["compile_s"]) == {"trace", "lower", "backend"}
+    assert event["lowered"] == 1
     assert event["compile_s"]["backend"] > 0
 
 
@@ -429,7 +430,7 @@ def test_a_nested_trace_is_counted_once_and_a_fetch_is_cached():
     with log.span("setup/model") as s:
         jax.jit(outer)(np.ones(3, np.float32))
     tally = s.compiles
-    assert tally.programs == {"compiled": 1, "cached": 0}
+    assert tally.programs == {"compiled": 1, "cached": 0, "lowered": 1}
     assert tally.funs["outer"][1] == "compiled"
     # inner's trace ran inside outer's: its 50 ms are counted once
     assert 0.05 <= tally.seconds["trace"] < 0.09
@@ -442,7 +443,7 @@ def test_a_nested_trace_is_counted_once_and_a_fetch_is_cached():
         compiles._event(compiles.HIT)
         compiles._duration(compiles.FETCH, 0.25)
         compiles._closed(backend, 10.0, 10.5, fun_name="jit(step)")
-    assert s.compiles.programs == {"compiled": 0, "cached": 1}
+    assert s.compiles.programs == {"compiled": 0, "cached": 1, "lowered": 0}
     assert s.compiles.fetch_s == 0.25
     assert s.compiles.funs == {"step": [0.5, "cached"]}
     assert _programs(log.registry, "cached") == 1
