@@ -223,12 +223,17 @@ class MLMBatches:
         ]
 
 
-def next_token_labels(tokens: np.ndarray) -> np.ndarray:
+def next_token_labels(tokens: np.ndarray, depth: int = 1) -> np.ndarray:
     """Labels of a causal LM: position t predicts token t + 1; the last
-    position has nothing to predict (``IGNORE_INDEX``)."""
-    labels = np.full_like(tokens, IGNORE_INDEX)
-    labels[:, :-1] = tokens[:, 1:]
-    return labels
+    position has nothing to predict (``IGNORE_INDEX``). With ``depth`` > 1
+    (a model with next-token prediction modules) the labels gain a last
+    axis: ``[..., j]`` is token t + 1 + j, ``IGNORE_INDEX`` where that lies
+    past the sequence."""
+    length = tokens.shape[1]
+    labels = np.full(tokens.shape + (depth,), IGNORE_INDEX, tokens.dtype)
+    for j in range(depth):
+        labels[:, :length - 1 - j, j] = tokens[:, 1 + j:]
+    return labels[..., 0] if depth == 1 else labels
 
 
 class NextTokenBatches(MLMBatches):
@@ -237,11 +242,16 @@ class NextTokenBatches(MLMBatches):
     inputs are the walk itself (nothing masked), the labels the tokens
     shifted by one with ``IGNORE_INDEX`` last — so the MLM loss and
     metrics functions are the causal LM's too, over every position but
-    the last. ``dataset='NextTokenSynth'``."""
+    the last. ``depth`` > 1 gives the labels of a model that also predicts
+    further ahead (``next_token_labels``). ``dataset='NextTokenSynth'``."""
+
+    def __init__(self, *args, depth: int = 1, **kw):
+        super().__init__(*args, **kw)
+        self.depth = depth
 
     def _pair(self, rng: np.random.RandomState, batch: int):
         toks = self.corpus.sample_walks(rng, batch, self.seq_len)
-        return toks, next_token_labels(toks)
+        return toks, next_token_labels(toks, self.depth)
 
     def __next__(self) -> Tuple[np.ndarray, np.ndarray]:
         rng = self._stream_rng(self._counter)

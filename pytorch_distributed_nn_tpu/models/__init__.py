@@ -13,6 +13,12 @@ from __future__ import annotations
 
 from typing import Any, Dict
 
+from pytorch_distributed_nn_tpu.models.glm47_flash import (
+    Glm47Flash,
+    Glm47FlashConfig,
+    glm47_flash_ep8,
+    glm47_flash_tiny,
+)
 from pytorch_distributed_nn_tpu.models.lenet import LeNet
 from pytorch_distributed_nn_tpu.models.lfm2 import (
     Lfm2Config,
@@ -103,6 +109,13 @@ _REGISTRY = {
     # published widths, and a toy of the same shape. Training only.
     "SmallThinker_21B_A3B_EP8": smallthinker_21b_a3b_ep8,
     "SmallThinkerTiny": smallthinker_tiny,
+    # GLM-4.7-Flash sparse-expert decoder (multi-head latent attention, a
+    # shared expert beside the routed ones, a scaled sigmoid router, one
+    # next-token prediction module in the loss): one chip's share of
+    # GLM-4.7-Flash under eight-way expert parallelism at published
+    # widths, and a toy of the same shape. Training only.
+    "GLM47_Flash_EP8": glm47_flash_ep8,
+    "GLM47FlashTiny": glm47_flash_tiny,
     "VGG11NoBN": vgg11,
     "VGG13NoBN": vgg13,
     "VGG16NoBN": vgg16,
@@ -119,7 +132,8 @@ _DEFAULT_INPUT_SPEC = (32, 32, 3)
 # on membership here (e.g. the trainer and __graft_entry__).
 TEXT_MODELS = {"BertBase", "BertTiny", "GptTiny", "GptMini",
                "Lfm2_8B_A1B_EP4", "Lfm2Tiny",
-               "SmallThinker_21B_A3B_EP8", "SmallThinkerTiny"}
+               "SmallThinker_21B_A3B_EP8", "SmallThinkerTiny",
+               "GLM47_Flash_EP8", "GLM47FlashTiny"}
 INPUT_SPECS["BertBase"] = (512,)
 INPUT_SPECS["BertTiny"] = (128,)
 INPUT_SPECS["GptTiny"] = (64,)
@@ -128,6 +142,8 @@ INPUT_SPECS["Lfm2_8B_A1B_EP4"] = (8192,)
 INPUT_SPECS["Lfm2Tiny"] = (64,)
 INPUT_SPECS["SmallThinker_21B_A3B_EP8"] = (16384,)
 INPUT_SPECS["SmallThinkerTiny"] = (64,)
+INPUT_SPECS["GLM47_Flash_EP8"] = (4096,)
+INPUT_SPECS["GLM47FlashTiny"] = (64,)
 
 # Causal decoders: artifacts of these networks serve the generative path
 # (serving/generate/) — POST /v1/generate instead of /v1/infer.

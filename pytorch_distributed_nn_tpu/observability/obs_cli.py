@@ -90,7 +90,8 @@ def _fmt_record(rec: dict) -> str:
         return f"event {rec.get('type')}{step} {json.dumps(extra, default=str)}"
     # step records (and legacy kind-less ones)
     parts = [f"step={rec.get('step')}"]
-    for k in ("loss", "acc1", "step_time", "data_time"):
+    for k in ("loss", "loss_main", "loss_mtp", "acc1", "step_time",
+              "data_time"):
         if k in rec:
             parts.append(f"{k}={rec[k]:.4f}")
     return "step " + " ".join(parts)

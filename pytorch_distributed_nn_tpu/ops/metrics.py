@@ -112,6 +112,33 @@ def make_global_masked_cross_entropy(axis_name: str):
     return loss
 
 
+def depth_loss_names(depth: int) -> Tuple[str, ...]:
+    """Metric names of each prediction depth's loss: ``loss_main`` (the
+    next token) and ``loss_mtp`` (one prediction module, the only depth a
+    model has yet)."""
+    if depth != 2:
+        raise ValueError(f"no metric names for {depth} prediction depths: "
+                         "a model has one prediction module or none")
+    return ("loss_main", "loss_mtp")
+
+
+def make_global_depth_losses(axis_name: str, depth: int):
+    """Metrics of a model with next-token prediction modules: logits
+    ``(..., depth, V)`` against labels ``(..., depth)``. Each depth's
+    masked cross-entropy over that depth's global count
+    (`make_global_masked_cross_entropy` on its slice), under the names of
+    `depth_loss_names`. Must run inside shard_map with ``axis_name``
+    bound."""
+    loss = make_global_masked_cross_entropy(axis_name)
+    names = depth_loss_names(depth)
+
+    def metrics(logits, labels, ignore_index: int = IGNORE_INDEX):
+        return {name: loss(logits[..., j, :], labels[..., j], ignore_index)
+                for j, name in enumerate(names)}
+
+    return metrics
+
+
 def mlm_sums(
     logits: jnp.ndarray, labels: jnp.ndarray, ignore_index: int = IGNORE_INDEX
 ) -> dict:

@@ -27,6 +27,7 @@ from pytorch_distributed_nn_tpu.models import (
     is_text_model,
 )
 from pytorch_distributed_nn_tpu.ops.metrics import (
+    make_global_depth_losses,
     make_global_masked_cross_entropy,
     make_global_mlm_metrics,
 )
@@ -441,6 +442,19 @@ class Trainer:
             if self.is_text:
                 self.seq_len = c.seq_len or input_spec(c.network)[0]
                 self.vocab_size = c.vocab_size or self.model.config.vocab_size
+                # targets a position is trained on: a model with next-token
+                # prediction modules is trained on tokens further ahead too
+                # (labels and logits gain a depth axis)
+                self.label_depth = getattr(
+                    self.model.config, "label_depth", 1)
+                if self.label_depth > 1 and (
+                        c.dataset != "NextTokenSynth" or c.data_path
+                        or self.use_spmd):
+                    raise ValueError(
+                        f"{c.network!r} predicts {self.label_depth} tokens "
+                        "a position: it trains on dataset='NextTokenSynth' "
+                        "(synthetic, not streamed from --data-path) on the "
+                        "data-parallel path (tp = sp = 1)")
                 in_shape, in_dtype = (self.seq_len,), jnp.int32
                 if self.seq_len % c.seq_parallel:
                     raise ValueError(
@@ -468,6 +482,14 @@ class Trainer:
                     num_replicas=self.n_workers,
                     input_dtype=in_dtype,
                 )
+                # a model whose expert bias is a balancing buffer starts
+                # where its rule settles (Glm47Flash.balance_routing)
+                balance = getattr(self.model, "balance_routing", None)
+                if balance is not None:
+                    self.state = self.state.replace(params=balance(
+                        self.state.params,
+                        jax.random.fold_in(jax.random.PRNGKey(c.seed), 1),
+                        self.seq_len))
             self.start_step = 0
             if c.warm_start:
                 if c.resume:
@@ -684,6 +706,14 @@ class Trainer:
                         "loss_fn": make_global_masked_cross_entropy(DATA_AXIS),
                         "metrics_fn": make_global_mlm_metrics(DATA_AXIS),
                     }
+                    if self.label_depth > 1:
+                        # beside the loss over every depth: each depth's own
+                        # (loss_main, loss_mtp)
+                        mlm = step_fns["metrics_fn"]
+                        depths = make_global_depth_losses(
+                            DATA_AXIS, self.label_depth)
+                        step_fns["metrics_fn"] = lambda logits, labels: {
+                            **mlm(logits, labels), **depths(logits, labels)}
                 train_step_fns = step_fns
                 if self.is_text:
                     from pytorch_distributed_nn_tpu.ops.metrics import mlm_sums
@@ -752,6 +782,7 @@ class Trainer:
                             vocab_size=self.vocab_size, seq_len=self.seq_len,
                             batch_size=c.batch_size, seed=c.seed,
                             mask_prob=c.mask_prob, branching=c.corpus_branching,
+                            **self._depth_kw(),
                         ),
                         sharding=sharding,
                     )
@@ -765,6 +796,7 @@ class Trainer:
                         batch_size=test_bs, seed=c.seed + 10_000,
                         mask_prob=c.mask_prob, branching=c.corpus_branching,
                         corpus_seed=c.seed,  # same language as training
+                        **self._depth_kw(),
                     ),
                     sharding=sharding,
                     eval_batches=c.eval_batches,
@@ -1087,6 +1119,11 @@ class Trainer:
                 self.train_loader.skip(self.start_step)
         self.metrics = MetricsLogger(telemetry=self.telemetry)
 
+    def _depth_kw(self) -> dict:
+        """The next-token batches' ``depth`` where the model predicts
+        further ahead; nothing for every other text model."""
+        return {"depth": self.label_depth} if self.label_depth > 1 else {}
+
     def _step_args(self) -> tuple:
         """The loop's arguments to its step as abstract values, each taken
         from the array it stands for (``_abstract``): the state; the batch
@@ -1106,6 +1143,11 @@ class Trainer:
                 (c.batch_size, self.seq_len), jnp.int32,
                 sharding=self._batch_sharding,
             )
+            if self.label_depth > 1:
+                y = jax.ShapeDtypeStruct(
+                    (*x.shape, self.label_depth), jnp.int32,
+                    sharding=self._batch_sharding,
+                )
         else:
             x = jax.ShapeDtypeStruct(
                 (c.batch_size, *input_spec(c.network)), jnp.float32,

@@ -392,3 +392,37 @@ def test_streamed_flash_compiles_for_v5e_at_the_smallthinker_cells_shape(
              for line in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert sorted(kinds) == sorted(spec["kinds"])           # one of each
+
+
+def test_streamed_flash_compiles_for_v5e_at_the_glm47_cells_head_width(
+        topo, monkeypatch):
+    """The latent-attention layers of cell glm47_flash_ep8_b1_L4096 (1 x
+    4096 x 20 up-projected heads of 256, bfloat16, causal): the widest
+    head any cell gives the kernels, past the resident limit, so the
+    streamed family with its blocks of 512 x 256 in VMEM. The three calls
+    are told apart as the benchmark does."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark import trace
+    from pytorch_distributed_nn_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    assert not pk._resident(4096, 256)
+    x = jax.ShapeDtypeStruct((1, 4096, 20, 256), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+
+    def loss(q, k, v):
+        out = pk.pallas_attention(q, k, v, None, causal=True)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(x, x, x).compile()
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                           "configs", "glm47_flash_ep8.json")) as f:
+        spec = json.load(f)["kernels"]["mla_attention"]
+    kernels = {"mla_attention": {**spec, "match": ""}}
+    kinds = [trace.classify_kernel(trace.parse_op(line.strip()), kernels)[1]
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(kinds) == sorted(spec["kinds"])           # one of each
