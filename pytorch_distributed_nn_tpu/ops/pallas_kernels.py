@@ -25,7 +25,8 @@ zoo. These kernels target the two places where hand-fusion beats stock XLA:
   grids are as long as the longest sweep and their index maps place each
   step's block, so they neither fetch nor compute a block outside it.
   Only the blocks the diagonal or the band's edge crosses compute scores
-  they then mask.
+  they then mask. The streamed forward keeps its running max and sum
+  lane-replicated in (bq, 128) scratch.
   Registered as a model attention impl (``attn_fn=pallas_attention``).
 - **Int8 stochastic-rounding quantization**: `quantize_int8_scaled` is the
   quantize step of the int8 gradient collective — ops/compression.py calls
@@ -163,6 +164,20 @@ def _streamed_sweep(causal: bool, n_own: int, own: int, swept: int, n: int,
     return max(hi - lo for lo, hi in map(sweep, range(n_own))), at
 
 
+# Lanes of the streamed forward's running max and sum: each row's value
+# is held replicated across one lane tile, (BQ, 128), so that it meets a
+# (BQ, BK) score panel or a (BQ, D) accumulator as a tile repeat and not
+# as a broadcast out of a one-lane (BQ, 1) layout.
+_STAT_LANES = 128
+
+
+def _lanes(x, n: int):
+    """(BQ, _STAT_LANES) lane-replicated statistics -> (BQ, n): whole lane
+    tiles repeated, the last one cut to ``n`` (a no-op where ``n`` is a
+    multiple of 128, as the cells' block and head widths are)."""
+    return jnp.tile(x, (1, pl.cdiv(n, _STAT_LANES)))[:, :n]
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *,
                       block_k: int, causal: bool, q_block: int,
@@ -177,6 +192,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
     program whose own sweep is shorter idles through the rest. Also
     emits the per-row log-sum-exp (m + log l) — the residual the
     blockwise backward needs.
+
+    The running max ``m`` and sum ``l`` live lane-replicated in
+    (BQ, _STAT_LANES) scratch; ``lse`` takes lane 0 once, at the end.
     """
     j = pl.program_id(1)
     t = pl.program_id(2)
@@ -204,12 +222,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         # kernels.)
         s = _block_scores(q, k_blk, mask_ref[0], causal,
                           j * q_block, kb * block_k, scale, window)
-        m = m_ref[:]  # (BQ, 1)
+        m = m_ref[:]  # (BQ, 128), each row's max in every lane
         m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
         corr = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
+        p = jnp.exp(s - _lanes(m_new, block_k))
         l_ref[:] = l_ref[:] * corr + p.sum(axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+        acc_ref[:] = acc_ref[:] * _lanes(corr, D) + jax.lax.dot_general(
             p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -226,14 +244,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
     @pl.when(t == nt - 1)
     def _finalize():
         l = jnp.maximum(l_ref[:], 1e-30)
-        o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:] / _lanes(l, D)).astype(o_ref.dtype)
         # Fully-masked rows: m stays at ~_NEG_INF so lse bottoms out
         # there too. The backward recomputes p = exp(s + bias - lse); for
         # rows with at least one valid key the -1e30 bias makes masked
         # entries underflow to 0, while fully-masked rows degenerate to
         # an ordinary softmax over masked keys — same
         # garbage-in-garbage-out as stock XLA attention.
-        lse_ref[0] = m_ref[:] + jnp.log(l)
+        lse_ref[0] = (m_ref[:] + jnp.log(l))[:, :1]
 
 
 def _to_bh(x):
@@ -337,8 +355,8 @@ def _flash_forward(q, k, v, mask, causal: bool, block_q: int, block_k: int,
         ),
         scratch_shapes=[
             pltpu.VMEM((bq, D), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, _STAT_LANES), jnp.float32),
+            pltpu.VMEM((bq, _STAT_LANES), jnp.float32),
         ],
         compiler_params=_STREAM_PARAMS,
         interpret=_interpret(),

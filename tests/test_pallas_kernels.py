@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from pytorch_distributed_nn_tpu.models.transformer import full_attention
+from pytorch_distributed_nn_tpu.ops import pallas_kernels as pk
 from pytorch_distributed_nn_tpu.ops.pallas_kernels import (
     _causal_sweep,
     dequantize_int8,
@@ -169,6 +170,53 @@ class TestFlashAttention:
                 np.testing.assert_allclose(a, b, rtol=5e-4, atol=5e-4)
         finally:
             pk._FLASH_CACHE.clear()
+
+    @pytest.mark.parametrize("L,D,block,causal,window,padded", [
+        (256, 32, 64, True, None, False), (256, 32, 64, True, 128, False),
+        (256, 32, 64, True, 100, False), (256, 32, 64, False, None, False),
+        (256, 32, 64, True, None, True), (256, 32, 64, True, 100, True),
+        (256, 32, 64, False, None, True),
+        (512, 128, 128, True, None, False),
+        (512, 128, 128, True, 256, False),
+        (512, 128, 128, True, 200, True),
+        (512, 128, 128, False, None, True)],
+        ids=["causal", "window_of_blocks", "window_not_blocks", "no_mask",
+             "pad_causal", "pad_window", "pad_full", "w128_causal",
+             "w128_window_of_blocks", "w128_pad_window_not_blocks",
+             "w128_pad_full"])
+    def test_streamed_forward_out_and_lse(self, L, D, block, causal, window,
+                                          padded, monkeypatch):
+        """The streamed forward alone on a 4 x 4 block grid: its output
+        and its log-sum-exp residual against dense attention. Its running
+        statistics are lane-replicated (128 lanes); at width 32 and blocks
+        of 64 they are cut to fewer lanes, at width 128 and blocks of 128
+        they are repeated whole, as at the cells' shapes."""
+        monkeypatch.setattr(pk, "_RESIDENT_MAX_L", 64)
+        B, H = 2, 2
+        assert not pk._resident(L, D)
+        q, k, v = _qkv(B=B, L=L, H=H, D=D, seed=21)
+        mask = (jnp.asarray(np.arange(L)[None, :]
+                            < np.array([L - 56, L])[:, None])
+                if padded else None)
+        with jax.default_matmul_precision("highest"):
+            out, lse = pk._flash_forward(q, k, v, mask, causal, block,
+                                         block, window)
+            want = full_attention(q, k, v, mask, causal=causal,
+                                  window=window)
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(D)
+        keep = np.ones((B, 1, L, L), bool)
+        if padded:
+            keep = keep & np.asarray(mask)[:, None, None, :]
+        if causal:
+            pos = np.arange(L)
+            seen = pos[:, None] >= pos[None, :]
+            if window is not None:
+                seen = seen & (pos[:, None] - pos[None, :] < window)
+            keep = keep & seen
+        want_lse = jax.nn.logsumexp(jnp.where(keep, s, -1e30), axis=-1)
+        np.testing.assert_allclose(out, want, atol=2e-5)
+        np.testing.assert_allclose(
+            lse, want_lse.reshape(B * H, L, 1), atol=2e-5)
 
     def test_backward_has_no_quadratic_intermediate(self):
         """Training memory is sub-quadratic: no L×L array anywhere in the
