@@ -19,6 +19,10 @@ from pytorch_distributed_nn_tpu.models.glm47_flash import (
     glm47_flash_ep8,
     glm47_flash_tiny,
 )
+from pytorch_distributed_nn_tpu.models.granite4_hybrid import (
+    granite4_h_micro_pp4,
+    granite4_tiny,
+)
 from pytorch_distributed_nn_tpu.models.lenet import LeNet
 from pytorch_distributed_nn_tpu.models.lfm2 import (
     Lfm2Config,
@@ -116,6 +120,13 @@ _REGISTRY = {
     # widths, and a toy of the same shape. Training only.
     "GLM47_Flash_EP8": glm47_flash_ep8,
     "GLM47FlashTiny": glm47_flash_tiny,
+    # Granite-4.0-H hybrid decoder (Mamba-2 layers through the chunked
+    # state-space scan kernel, ops/ssd.py, beside NoPE grouped-query
+    # attention; Granite's multipliers, a tied head): one chip's share of
+    # pipeline stage 1 of Granite-4.0-H-Micro at published widths, and a
+    # toy of the same shape. Training only.
+    "Granite4_H_Micro_PP4": granite4_h_micro_pp4,
+    "Granite4Tiny": granite4_tiny,
     "VGG11NoBN": vgg11,
     "VGG13NoBN": vgg13,
     "VGG16NoBN": vgg16,
@@ -133,7 +144,8 @@ _DEFAULT_INPUT_SPEC = (32, 32, 3)
 TEXT_MODELS = {"BertBase", "BertTiny", "GptTiny", "GptMini",
                "Lfm2_8B_A1B_EP4", "Lfm2Tiny",
                "SmallThinker_21B_A3B_EP8", "SmallThinkerTiny",
-               "GLM47_Flash_EP8", "GLM47FlashTiny"}
+               "GLM47_Flash_EP8", "GLM47FlashTiny",
+               "Granite4_H_Micro_PP4", "Granite4Tiny"}
 INPUT_SPECS["BertBase"] = (512,)
 INPUT_SPECS["BertTiny"] = (128,)
 INPUT_SPECS["GptTiny"] = (64,)
@@ -144,6 +156,8 @@ INPUT_SPECS["SmallThinker_21B_A3B_EP8"] = (16384,)
 INPUT_SPECS["SmallThinkerTiny"] = (64,)
 INPUT_SPECS["GLM47_Flash_EP8"] = (4096,)
 INPUT_SPECS["GLM47FlashTiny"] = (64,)
+INPUT_SPECS["Granite4_H_Micro_PP4"] = (4096,)
+INPUT_SPECS["Granite4Tiny"] = (64,)
 
 # Causal decoders: artifacts of these networks serve the generative path
 # (serving/generate/) — POST /v1/generate instead of /v1/infer.

@@ -428,6 +428,46 @@ def test_streamed_flash_compiles_for_v5e_at_the_glm47_cells_head_width(
     assert sorted(kinds) == sorted(spec["kinds"])           # one of each
 
 
+def test_the_state_space_scan_compiles_for_v5e_at_the_granite_cells_shapes(
+        topo, monkeypatch):
+    """The Mamba layers of cell granite4_h_micro_pp4_b1_L4096 (1 x 4096, 64
+    heads of 64, a 64 x 128 state, chunks of 256, bfloat16 operands): Mosaic
+    takes the forward and the backward kernel with their blocks and scratch
+    in the scoped VMEM, and the two calls are told apart as the benchmark
+    does."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark import trace
+    from pytorch_distributed_nn_tpu.ops import pallas_kernels as pk
+    from pytorch_distributed_nn_tpu.ops import ssd
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def loss(x, dt, a, b, c):
+        y, absmax = ssd.ssd_scan(x, dt, a, b, c, 256)
+        return jnp.sum(y.astype(jnp.float32) ** 2) + absmax
+
+    compiled = jax.jit(jax.grad(loss, range(5))).lower(
+        shaped((1, 4096, 64, 64), jnp.bfloat16),
+        shaped((1, 4096, 64), jnp.float32), shaped((64,), jnp.float32),
+        shaped((1, 4096, 1, 128), jnp.bfloat16),
+        shaped((1, 4096, 1, 128), jnp.bfloat16)).compile()
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                           "configs", "granite4_h_micro_pp4.json")) as f:
+        spec = json.load(f)["kernels"]["ssd_scan"]
+    kernels = {"ssd_scan": {**spec, "match": ""}}
+    kinds = [trace.classify_kernel(trace.parse_op(line.strip()), kernels)[1]
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(kinds) == sorted(spec["kinds"])           # one of each
+
+
 @pytest.mark.parametrize("shape, window", [
     ((1, 16384, 28, 128), 4096), ((1, 16384, 28, 128), None),
     ((1, 4096, 20, 256), None), ((1, 16384, 4, 64), None)],
